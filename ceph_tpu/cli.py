@@ -28,6 +28,7 @@ import sys
 
 from ceph_tpu.client.rados import Rados, RadosError
 from ceph_tpu.common.config import ConfigProxy
+from ceph_tpu.ec.profiler import roofline_text
 
 
 def _load_conf(path: str) -> tuple[dict, ConfigProxy]:
@@ -1163,7 +1164,7 @@ def _render_top(d: dict, kernels: bool) -> str:
         lines.append(
             "  device: "
             f"{util.get('device_gibps', 0.0):g} GiB/s "
-            f"({util.get('roofline_pct', 0.0):g}% of roofline)  "
+            f"(roofline {roofline_text(util.get('roofline_pct'))})  "
             f"occupancy {util.get('coalesce_occupancy', 0.0):g}  "
             f"resident hit {util.get('resident_hit_rate', 0.0):g}")
         lines.append(
@@ -1211,7 +1212,7 @@ def _render_top(d: dict, kernels: bool) -> str:
                 f"{rec.get('wall_us', 0.0) / 1e3:>9.1f} ms  "
                 f"{rec.get('hbm_bytes', 0) / (1 << 20):>9.1f} MiB  "
                 f"{rec.get('gibps', 0.0):>7.2f} GiB/s  "
-                f"{rec.get('roofline_pct', 0.0):>5.1f}%")
+                f"{roofline_text(rec.get('roofline_pct')):>12}")
     return "\n".join(lines)
 
 

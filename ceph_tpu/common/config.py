@@ -258,8 +258,6 @@ def global_options() -> list[Option]:
                "(0 = off)", Level.ADVANCED, min=0.0),
         Option("ec_stripe_batch", int, 1024,
                "stripes per device encode launch", min=1),
-        Option("ec_use_pallas", bool, True,
-               "use fused Pallas kernels on TPU"),
         Option("osd_ec_coalesce", bool, True,
                "coalesce concurrent in-flight EC ops' encode/decode "
                "batches into shared device launches (cross-op "
@@ -283,13 +281,11 @@ def global_options() -> list[Option]:
                "cross-chip CLAY/LRC sub-chunk degraded reads"),
         Option("ec_pallas_encode_variant", str, "auto",
                "Pallas encode kernel formulation ('' = production "
-               "kernel; 'auto' = the perf-lab winner enc_u8_expand on "
-               "a TPU backend, production elsewhere; variants are "
-               "bit-identical, promoted from the round-5 perf lab for "
-               "on-chip timing)", Level.ADVANCED,
-               enum_values=("", "auto", "enc_cmp_expand",
-                            "enc_u8_expand", "enc_split2",
-                            "enc_u8_split2")),
+               "kernel; 'auto' = the formulation chip_smoke.py checks "
+               "on the chip, pallas_kernels.AUTO_VARIANT; the others "
+               "are bit-identical alternatives, untimed on a chip)",
+               Level.ADVANCED,
+               enum_values=("", "auto", "enc_cmp_expand", "enc_split2")),
         Option("osd_ec_resident", bool, True,
                "keep EC shard streams device-resident in a shared "
                "DeviceShardCache so repeated ops feed the kernel "
@@ -519,11 +515,6 @@ def global_options() -> list[Option]:
                "racing an overwrite of the same key never hits a "
                "removed-object window (0 = delete inline)",
                Level.ADVANCED, min=0.0),
-        Option("ec_hbm_peak_gibps", float, 763.0,
-               "accelerator HBM peak bandwidth in GiB/s (v5e ~819 GB/s "
-               "= 763 GiB/s) — the roofline the utilization telemetry "
-               "reports achieved device GiB/s against", Level.ADVANCED,
-               min=1.0),
         Option("log_to_memory_ring", bool, True, "keep crash ring buffer"),
         Option("debug_default", int, 1, "default subsystem debug level",
                min=0, max=20),

@@ -1,8 +1,9 @@
 """crc32c (Castagnoli) with native C fast path.
 
-Loads ceph_tpu/native/libceph_tpu_native.so via ctypes (auto-built with
-make on first use; g++/gcc are in the image), falling back to a pure-Python
-table loop. Semantics match ceph_crc32c(seed, buf, len)
+Loads ceph_tpu/native/libceph_tpu_native.so via ctypes, built with make
+on first use from the committed sources (_SOURCES).  A build that fails
+raises; the pure-Python table loop is the reference the tests compare
+against. Semantics match ceph_crc32c(seed, buf, len)
 (reference src/common/crc32c.h): callers chain seeds; ECUtil HashInfo uses
 the previous cumulative crc as the seed for each appended shard extent.
 """
@@ -16,28 +17,23 @@ import subprocess
 _NATIVE_DIR = pathlib.Path(__file__).resolve().parents[1] / "native"
 _SO = _NATIVE_DIR / "libceph_tpu_native.so"
 
+# the committed sources the .so is built from (the binary is not)
+_SOURCES = ("Makefile", "crc32c.c", "wal_engine.cc")
+
 _native = None
 
 
 def _stale() -> bool:
-    """The .so is rebuilt when missing OR older than any source (the
-    binary is NOT committed — CI and first use build it from the
-    in-tree C/C++ sources via the Makefile)."""
+    """The .so is rebuilt when missing OR older than any source."""
     try:
-        if not _SO.exists():
-            return True
         so_mtime = _SO.stat().st_mtime
-        for src in _NATIVE_DIR.iterdir():
-            if src.suffix in (".c", ".cc", ".h") \
-                    or src.name == "Makefile":
-                if src.stat().st_mtime > so_mtime:
-                    return True
-        return False
-    except OSError:
-        return True        # racing build/cleanup: (re)build to be sure
+    except FileNotFoundError:
+        return True
+    return any((_NATIVE_DIR / src).stat().st_mtime > so_mtime
+               for src in _SOURCES)
 
 
-def _build() -> bool:
+def _build() -> None:
     """Build in a scratch dir and publish with an atomic rename:
     concurrent first-use builds (parallel test workers, several
     daemons in one checkout) each produce a complete .so and the last
@@ -46,37 +42,30 @@ def _build() -> bool:
     import shutil
     import tempfile
 
-    try:
-        with tempfile.TemporaryDirectory(dir=_NATIVE_DIR) as td:
-            for src in _NATIVE_DIR.iterdir():
-                if src.suffix in (".c", ".cc", ".h") \
-                        or src.name == "Makefile":
-                    shutil.copy(src, td)
-            subprocess.run(["make", "-C", td, "-s"], check=True,
-                           capture_output=True, timeout=120)
-            os.replace(os.path.join(td, "libceph_tpu_native.so"),
-                       _SO)
-        return True
-    except Exception:
-        return False
+    with tempfile.TemporaryDirectory(dir=_NATIVE_DIR) as td:
+        for src in _SOURCES:
+            shutil.copy(_NATIVE_DIR / src, td)
+        res = subprocess.run(["make", "-C", td, "-s"],
+                             capture_output=True, text=True, timeout=120)
+        if res.returncode != 0:
+            raise RuntimeError(
+                f"native build failed (rc={res.returncode}):\n"
+                f"{res.stdout}{res.stderr}")
+        os.replace(os.path.join(td, "libceph_tpu_native.so"), _SO)
 
 
 def _load_native():
     global _native
     if _native is not None:
         return _native
-    if _stale() and not _build():
-        _native = False
-        return False
-    try:
-        lib = ctypes.CDLL(str(_SO))
-        lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
-        lib.ceph_tpu_crc32c.argtypes = (
-            ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t,
-        )
-        _native = lib
-    except OSError:
-        _native = False
+    if _stale():
+        _build()
+    lib = ctypes.CDLL(str(_SO))
+    lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
+    lib.ceph_tpu_crc32c.argtypes = (
+        ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t,
+    )
+    _native = lib
     return _native
 
 

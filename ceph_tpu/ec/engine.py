@@ -153,16 +153,9 @@ def pad_batch_to(arr, target: int):
 
 
 def _default_use_pallas() -> bool:
-    """Fused Pallas kernel on real TPU; XLA einsum elsewhere (CPU tests,
+    """Fused Pallas kernel on a TPU; XLA einsum elsewhere (CPU tests,
     interpret-mode covers the Pallas math there)."""
-    import os
-
-    if os.environ.get("CEPH_TPU_NO_PALLAS"):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class BitplaneEngine:

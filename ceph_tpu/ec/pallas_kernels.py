@@ -56,32 +56,27 @@ def shard_kernel_supported(kin: int, mout: int) -> bool:
     return _pick_kblk(kin, mout) > 0
 
 
-# -- encode-variant selection (promoted from testing/perf_lab round 5) ----
+# -- encode-variant selection ---------------------------------------
 #
 # Alternative kernel formulations of the same GF(2) contraction, all
-# bit-identical to the production kernel (interpret-mode corpus check in
-# CI; perf_lab timed them on-chip).  Selected process-wide via conf
-# ``ec_pallas_encode_variant`` so the chip waiter can flip the default
-# the moment a grant lands.  Variants assume an unblocked contraction
-# (kblocks == 1); matrices big enough to need contraction blocking keep
-# the production kernel.
-ENCODE_VARIANTS = ("", "enc_cmp_expand", "enc_u8_expand",
-                   "enc_split2", "enc_u8_split2")
+# bit-identical to the production kernel: interpret mode checks them in
+# tests/test_pallas.py and tests/test_tpu_compile.py compiles each for a
+# described v5e.  None has been timed against another on a chip yet.
+# Selected process-wide via conf ``ec_pallas_encode_variant``.  Variants
+# assume an unblocked contraction (kblocks == 1); matrices big enough
+# to need contraction blocking keep the production kernel.
+ENCODE_VARIANTS = ("", "enc_cmp_expand", "enc_split2")
+# What "auto" selects: the kernel chip_smoke.py has checked on the chip.
+AUTO_VARIANT = ""
 _encode_variant = ""
 
 
 def set_encode_variant(name: str) -> None:
-    """Select the Pallas encode kernel formulation ("" = production).
-
-    "auto" resolves at set time to the perf-lab round-5 winner
-    (enc_u8_expand, whose slot layout also fuses the int8->int32 lane
-    pack into the kernel prologue via apply_bytes) when a TPU backend
-    is attached, and to the production kernel elsewhere — interpret
-    mode exercises the variants explicitly in tests instead.
-    """
+    """Select the Pallas encode kernel formulation ("" = production,
+    "auto" = AUTO_VARIANT)."""
     global _encode_variant
     if name == "auto":
-        name = "enc_u8_expand" if jax.default_backend() == "tpu" else ""
+        name = AUTO_VARIANT
     if name not in ENCODE_VARIANTS:
         raise ValueError(
             f"unknown encode variant {name!r}; one of {ENCODE_VARIANTS}"
@@ -196,48 +191,9 @@ def _kernel_split2(bm_ref, data_ref, out_ref, *, mout):
             jnp.sum(accb << shift, axis=1)
 
 
-def _kernel_u8(bm_ref, data_ref, out_ref, *, mout):
-    """Variant enc_u8_expand: uint8-native formulation.  Input rides as
-    (k, 4, N/4) uint8 (slot q = contiguous quarter of the byte stream;
-    the slot plays the lane-expansion byte position, so the production
-    bitmatrix applies unchanged).  Expansion and output are int8-width
-    VPU ops."""
-    d = data_ref[:]                               # (kin, 4, T) uint8
-    kin, _, T = d.shape
-    shift8 = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8, 1), 2)
-    bits = ((d[:, :, None, :] >> shift8) & 1) \
-        .reshape(kin * 32, T).astype(jnp.int8)
-    acc = jnp.dot(bm_ref[:], bits, preferred_element_type=jnp.int32)
-    accb = (acc & 1).reshape(mout, 4, 8, T)
-    s32 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
-    out_ref[:] = jnp.sum(accb << s32, axis=2).astype(jnp.uint8)
-
-
-def _kernel_u8_split2(bm_ref, data_ref, out_ref, *, mout):
-    """Variant enc_u8_split2: uint8-native expansion AND pipelined
-    halves."""
-    kin, _, T = data_ref.shape
-    half = T // 2
-    B = bm_ref[:]
-    shift8 = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8, 1), 2)
-    s32 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
-    for h in range(2):
-        d = data_ref[:, :, h * half:(h + 1) * half]
-        bits = ((d[:, :, None, :] >> shift8) & 1) \
-            .reshape(kin * 32, half).astype(jnp.int8)
-        acc = jnp.dot(B, bits, preferred_element_type=jnp.int32)
-        accb = (acc & 1).reshape(mout, 4, 8, half)
-        out_ref[:, :, h * half:(h + 1) * half] = \
-            jnp.sum(accb << s32, axis=2).astype(jnp.uint8)
-
-
 _WORD_VARIANT_KERNELS = {
     "enc_cmp_expand": _kernel_cmp_expand,
     "enc_split2": _kernel_split2,
-}
-_U8_VARIANT_KERNELS = {
-    "enc_u8_expand": _kernel_u8,
-    "enc_u8_split2": _kernel_u8_split2,
 }
 
 
@@ -262,30 +218,6 @@ def _pallas_apply_words_variant(bm32, words, *, tile, variant,
         out_shape=jax.ShapeDtypeStruct((mout, n4), jnp.int32),
         interpret=interpret,
     )(bm32, words)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("tile", "variant", "interpret"))
-def _pallas_apply_u8_variant(bm32, x8, *, tile, variant,
-                             interpret=False):
-    """u8-slot-layout variant launch: (kin, 4, nq) uint8 in,
-    (mout, 4, nq) uint8 out (slot q = quarter q of the byte stream)."""
-    kin, _, nq = x8.shape
-    mout = bm32.shape[0] // 32
-    return pl.pallas_call(
-        functools.partial(_U8_VARIANT_KERNELS[variant], mout=mout),
-        grid=(nq // tile,),
-        in_specs=[
-            pl.BlockSpec(bm32.shape, lambda t: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((kin, 4, tile), lambda t: (0, 0, t),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((mout, 4, tile), lambda t: (0, 0, t),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((mout, 4, nq), jnp.uint8),
-        interpret=interpret,
-    )(bm32, x8)
 
 
 def _device_cached(np_arr: np.ndarray, slot):
@@ -316,20 +248,39 @@ def _pick_gtile(n4: int, cmax: int, grp: int) -> int:
     return t
 
 
+# Byte <-> lane conversion by shifts and strided slices, not by a
+# (..., N/4, 4) uint8 reshape + bitcast: for some shapes (4 or 12 rows of
+# 512 KiB, the parity of a 4 MiB object) the TPU compiler takes 90-170 s
+# over that uint8 relayout, against a few seconds for this form.
+@jax.jit
+def _bytes_to_words(data):
+    b = [data[..., j::LANE_BYTES].astype(jnp.int32)
+         for j in range(LANE_BYTES)]
+    return b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
+
+
+@jax.jit
+def _words_to_bytes(words):
+    out = jnp.zeros((*words.shape[:-1], words.shape[-1] * LANE_BYTES),
+                    jnp.uint8)
+    for j in range(LANE_BYTES):
+        out = out.at[..., j::LANE_BYTES].set(
+            ((words >> (8 * j)) & 0xFF).astype(jnp.uint8))
+    return out
+
+
 def bytes_to_words(data) -> jax.Array:
-    """(..., N) uint8 -> (..., N/4) int32 lane view (N % 4 == 0)."""
+    """(..., N) uint8 -> (..., N/4) int32 lanes, little-endian (byte 0 =
+    bits 0..7); N % 4 == 0."""
     data = jnp.asarray(data, jnp.uint8)
     if data.shape[-1] % LANE_BYTES:
         raise ValueError(f"byte count {data.shape[-1]} not a multiple of 4")
-    shaped = data.reshape(*data.shape[:-1], data.shape[-1] // LANE_BYTES,
-                          LANE_BYTES)
-    return jax.lax.bitcast_convert_type(shaped, jnp.int32)
+    return _bytes_to_words(data)
 
 
 def words_to_bytes(words) -> jax.Array:
     """(..., N4) int32 -> (..., 4*N4) uint8, inverse of bytes_to_words."""
-    by = jax.lax.bitcast_convert_type(words, jnp.uint8)
-    return by.reshape(*words.shape[:-1], words.shape[-1] * LANE_BYTES)
+    return _words_to_bytes(jnp.asarray(words, jnp.int32))
 
 
 def _greedy_groups(nz: np.ndarray, grp_rows: int) -> list[list[int]]:
@@ -645,24 +596,10 @@ class PallasShardApply:
         # the production kernel
         variant = _encode_variant
         if variant and self.kblk == self.kin:
-            tile = _pick_tile(n4 + pad, self.mout)
-            if variant in _WORD_VARIANT_KERNELS:
-                out = _pallas_apply_words_variant(
-                    self._bm32_arg(), words, tile=tile,
-                    variant=variant, interpret=self.interpret,
-                )
-            else:
-                # u8 slot layout: quarter q of each row's byte stream
-                # rides slot q; invert by flattening slots back into the
-                # byte stream and repacking little-endian lanes
-                x8 = words_to_bytes(words).reshape(kin, 4, n4 + pad)
-                out8 = _pallas_apply_u8_variant(
-                    self._bm32_arg(), x8, tile=tile,
-                    variant=variant, interpret=self.interpret,
-                )
-                out = bytes_to_words(
-                    out8.reshape(self.mout, 4 * (n4 + pad))
-                )
+            out = _pallas_apply_words_variant(
+                self._bm32_arg(), words, tile=_pick_tile(n4 + pad, self.mout),
+                variant=variant, interpret=self.interpret,
+            )
             return out[:, :n4] if pad else out
         out = _pallas_apply_words(
             self._bm32_arg(), words, tile=_pick_tile(n4 + pad, self.mout),
@@ -671,35 +608,11 @@ class PallasShardApply:
         return out[:, :n4] if pad else out
 
     def apply_bytes(self, data) -> jax.Array:
-        """(k, N) uint8 byte streams -> (m, N) uint8 parity streams.
-
-        For the u8-slot variants the stream reshapes straight into the
-        kernel's slot layout, fusing the int8->int32 lane pack (and its
-        inverse) into the kernel prologue: no bitcast relayout touches
-        the data on either side of the launch.  Every stream byte is
-        transformed independently (the lane-expanded bitmatrix is
-        block-diagonal per byte), so zero tail padding only yields zero
-        tail parity and slices back off without affecting identity.
-        """
+        """(k, N) uint8 byte streams -> (m, N) uint8 parity streams."""
         data = jnp.asarray(data, jnp.uint8)
-        kin, n = data.shape
-        if kin != self.kin:
-            raise ValueError(f"expected {self.kin} chunk rows, got {kin}")
-        if n % LANE_BYTES:
-            raise ValueError(f"byte count {n} not a multiple of 4")
-        variant = _encode_variant
-        if variant in _U8_VARIANT_KERNELS and self.kblk == self.kin:
-            pad = (-n) % (4 * LANE)
-            if pad:
-                data = jnp.pad(data, ((0, 0), (0, pad)))
-            nq = (n + pad) // 4
-            out8 = _pallas_apply_u8_variant(
-                self._bm32_arg(), data.reshape(kin, 4, nq),
-                tile=_pick_tile(nq, self.mout), variant=variant,
-                interpret=self.interpret,
-            )
-            out = out8.reshape(self.mout, n + pad)
-            return out[:, :n] if pad else out
+        if data.shape[0] != self.kin:
+            raise ValueError(
+                f"expected {self.kin} chunk rows, got {data.shape[0]}")
         return words_to_bytes(self.apply_words(bytes_to_words(data)))
 
     def __call__(self, data) -> jax.Array:

@@ -17,7 +17,8 @@ decode, mesh repair, host-mesh flush — to its codec signature
   ``ec_launch_bytes``), so per-signature byte totals reconcile with
   the existing counters EXACTLY — the profiler is an attribution of
   the counters, never a second opinion;
-- derived ``gibps`` and (given a peak) ``roofline_pct``.
+- derived ``gibps`` and, on a device in :data:`HBM_PEAK_BYTES_S`,
+  ``roofline_pct``.
 
 The dump rides the OSD's perf_dump under the ``ec_kernels`` key, the
 mgr persists per-signature series into the TSDB, and
@@ -35,6 +36,30 @@ import threading
 import weakref
 
 _GIB = float(1 << 30)
+
+# Published HBM bandwidth per jax ``device_kind``, bytes/s.  Source:
+# Google Cloud documentation, "TPU v5e": 16 GB of HBM at 819 GB/s.
+HBM_PEAK_BYTES_S = {"TPU v5 lite": 819e9}
+
+
+def hbm_peak_gibps() -> float | None:
+    """HBM peak of the local device in GiB/s, the roofline every
+    ``roofline_pct`` divides by.  None off a TPU: there the share is
+    not measured.  A TPU kind missing from the table raises."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    if dev.device_kind not in HBM_PEAK_BYTES_S:
+        raise KeyError(f"no published HBM peak for device_kind "
+                       f"{dev.device_kind!r}; add it to HBM_PEAK_BYTES_S")
+    return HBM_PEAK_BYTES_S[dev.device_kind] / _GIB
+
+
+def roofline_text(pct: float | None) -> str:
+    """Render a roofline share for humans ("not measured" off a TPU)."""
+    return "not measured" if pct is None else f"{pct:g}%"
 
 
 class KernelProfiler:
@@ -67,7 +92,7 @@ class KernelProfiler:
                     t[k] += rec[k]
             return t
 
-    def dump(self, peak_gibps: float = 0.0) -> dict:
+    def dump(self, peak_gibps: float | None = None) -> dict:
         """JSON-friendly per-signature table with derived bandwidth
         (and roofline % when a peak is known)."""
         out: dict[str, dict] = {}
@@ -80,7 +105,7 @@ class KernelProfiler:
                 if wall_s > 0 else 0.0
             rec["wall_us"] = round(rec["wall_us"], 1)
             rec["gibps"] = round(gibps, 3)
-            if peak_gibps > 0:
+            if peak_gibps:
                 rec["roofline_pct"] = round(
                     100.0 * gibps / peak_gibps, 3)
             out[sig] = rec

@@ -2,7 +2,7 @@
 
 The reference defines 163 C++ message classes (src/messages/) over a common
 Message base (src/msg/Message.h). Here one generic envelope — a string type
-tag plus a codec-encodable payload — replaces the class-per-type taxonomy;
+tag plus a codec-encodable payload — replaces the class-per-type hierarchy;
 subsystems define their type tags next to their handlers (mon, osd, client).
 Priority mirrors CEPH_MSG_PRIO_*; seq/ack live in the frame header, not here.
 """

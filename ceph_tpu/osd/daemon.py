@@ -447,9 +447,8 @@ class OSDDaemon:
         # launch attribution with derived roofline % — nested dict, not
         # a counter; the mgr's tsdb/top surfaces consume it and the
         # Prometheus renderer skips it
-        from ceph_tpu.ec.profiler import profiler_for
-        kernels = profiler_for(self.perf).dump(
-            peak_gibps=float(self.conf["ec_hbm_peak_gibps"] or 0.0))
+        from ceph_tpu.ec.profiler import hbm_peak_gibps, profiler_for
+        kernels = profiler_for(self.perf).dump(peak_gibps=hbm_peak_gibps())
         if kernels:
             out["ec_kernels"] = kernels
         return out
@@ -1792,9 +1791,9 @@ class OSDDaemon:
     def _ec_mesh(self):
         """Distributed EC data-plane mesh (osd_ec_mesh_cs > 0): one
         ('dp','cs') mesh over all local jax devices, built once per
-        process (OSDs in one process share the devices).  Invalid
-        geometry degrades to the single-device plane with a warning —
-        a config typo must not keep PGs from going active."""
+        process (OSDs in one process share the devices).  A cs that
+        does not divide the local device count is a configuration error
+        and raises: the single-device plane would hide it."""
         cs = int(self.conf["osd_ec_mesh_cs"])
         if cs <= 0:
             return None
@@ -1806,10 +1805,9 @@ class OSDDaemon:
 
             devs = jax.devices()
             if len(devs) < cs or len(devs) % cs:
-                log.derr("osd.%d: osd_ec_mesh_cs=%d does not divide "
-                         "the %d local devices; using single-device "
-                         "EC", self.osd_id, cs, len(devs))
-                return None
+                raise ValueError(
+                    f"osd.{self.osd_id}: osd_ec_mesh_cs={cs} does not "
+                    f"divide the {len(devs)} local devices")
             mesh = make_ec_mesh(devs, cs=cs)
             _EC_MESH_CACHE[cs] = mesh
         return mesh
@@ -1880,6 +1878,9 @@ class OSDDaemon:
                 resident.drop_ns(resident_ns)
             pg.backend = ECBackend(
                 codec, shards, log_hook=log_hook,
+                # the profile's stripe_unit (Ceph's per-pool knob);
+                # unset = the codec's alignment
+                stripe_unit=int(profile.get("stripe_unit", 0)) or None,
                 mesh=self._ec_mesh(),
                 hedge_timeout=hedge or None,
                 perf=self.perf,

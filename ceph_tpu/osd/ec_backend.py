@@ -1095,7 +1095,7 @@ class ECBackend:
         Returns None whenever the geometry doesn't fit — multi-chunk
         loss, helpers unavailable, device-resident payloads, or a pool
         the repair meshes can't tile — and the caller falls back to the
-        classic decode path."""
+        classic decode path.  An operator or compile failure raises."""
         ec = self.ec
         is_clay = hasattr(ec, "sub_chunk_no") and hasattr(ec, "q")
         is_lrc = hasattr(ec, "layers")
@@ -1109,46 +1109,41 @@ class ECBackend:
         lost = todo[0]
         b = next(iter(avail.values())).shape[0]
         C = self.sinfo.chunk_size
-        try:
-            if is_clay:
-                if C % ec.sub_chunk_no:
-                    return None
-                mesh = self._mesh_host.clay_repair_mesh(self.n)
-                if mesh is None:
-                    return None
-                from ceph_tpu.ec.repair_operator import \
-                    clay_repair_operator
-                from ceph_tpu.parallel.clay_sharding import (
-                    clay_repair_ici_bytes, sharded_clay_repair)
+        if is_clay:
+            if C % ec.sub_chunk_no:
+                return None
+            mesh = self._mesh_host.clay_repair_mesh(self.n)
+            if mesh is None:
+                return None
+            from ceph_tpu.ec.repair_operator import \
+                clay_repair_operator
+            from ceph_tpu.parallel.clay_sharding import (
+                clay_repair_ici_bytes, sharded_clay_repair)
 
-                _, helpers, _ = clay_repair_operator(ec, lost)
-                if any(h not in avail for h in helpers):
-                    return None
-                moved, whole = clay_repair_ici_bytes(
-                    ec, len(helpers), b, C)
-                repair = sharded_clay_repair
-                dp = mesh.shape["dp"]
-            else:
-                groups = len(ec.layers) - 1
-                mesh = self._mesh_host.lrc_repair_mesh(groups)
-                if mesh is None:
-                    return None
-                from ceph_tpu.ec.repair_operator import \
-                    lrc_repair_operator
-                from ceph_tpu.parallel.lrc_sharding import (
-                    lrc_repair_ici_bytes, sharded_lrc_repair)
+            _, helpers, _ = clay_repair_operator(ec, lost)
+            if any(h not in avail for h in helpers):
+                return None
+            moved, whole = clay_repair_ici_bytes(
+                ec, len(helpers), b, C)
+            repair = sharded_clay_repair
+            dp = mesh.shape["dp"]
+        else:
+            groups = len(ec.layers) - 1
+            mesh = self._mesh_host.lrc_repair_mesh(groups)
+            if mesh is None:
+                return None
+            from ceph_tpu.ec.repair_operator import \
+                lrc_repair_operator
+            from ceph_tpu.parallel.lrc_sharding import (
+                lrc_repair_ici_bytes, sharded_lrc_repair)
 
-                _, minimum = lrc_repair_operator(ec, lost)
-                if any(h not in avail for h in minimum):
-                    return None
-                moved, whole = lrc_repair_ici_bytes(
-                    ec, len(minimum), b, C)
-                repair = sharded_lrc_repair
-                dp = mesh.shape["dp"]
-        except Exception:
-            # geometry probe failed (profile the operator can't serve
-            # locally, etc) — the classic decode path handles it
-            return None
+            _, minimum = lrc_repair_operator(ec, lost)
+            if any(h not in avail for h in minimum):
+                return None
+            moved, whole = lrc_repair_ici_bytes(
+                ec, len(minimum), b, C)
+            repair = sharded_lrc_repair
+            dp = mesh.shape["dp"]
         # dp must divide the launched batch; zero stripes pad (rows are
         # independent) and the pad slices off below
         bp = -(-b // dp) * dp

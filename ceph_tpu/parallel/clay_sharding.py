@@ -21,9 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ceph_tpu.ec.engine import default_engine
 from ceph_tpu.ec.repair_operator import clay_repair_operator
 
-from ceph_tpu.common.jaxutil import resolve_shard_map
-
-shard_map = resolve_shard_map()
+from jax import shard_map
 
 
 def sharded_clay_repair(mesh, ec, chunks, lost: int) -> jax.Array:

@@ -25,9 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ceph_tpu.common.jaxutil import resolve_shard_map
-
-shard_map = resolve_shard_map()
+from jax import shard_map
 
 from ceph_tpu.ec import reference
 from ceph_tpu.ec.engine import default_engine
@@ -64,8 +62,11 @@ def sharded_encode(mesh: Mesh, generator: np.ndarray, data) -> jax.Array:
             parity = eng.apply(parity_coeff, d_blk)
             return jnp.concatenate([d_blk, parity], axis=1)
 
+        # check_vma=False: a pallas_call's out_shape carries no vma, so
+        # the replication check refuses the Pallas kernel on a TPU
         return shard_map(
-            local, mesh=mesh, in_specs=batch_spec, out_specs=batch_spec
+            local, mesh=mesh, in_specs=batch_spec, out_specs=batch_spec,
+            check_vma=False,
         )(d)
 
     return step(data)
@@ -96,7 +97,7 @@ class ShardedApplier:
         def step(d):
             return shard_map(
                 lambda blk: eng.apply(coeff, blk),
-                mesh=mesh, in_specs=spec, out_specs=spec,
+                mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
             )(d)
 
         self._step = step

@@ -27,9 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ceph_tpu.ec.engine import default_engine
 from ceph_tpu.ec.repair_operator import lrc_repair_operator
 
-from ceph_tpu.common.jaxutil import resolve_shard_map
-
-shard_map = resolve_shard_map()
+from jax import shard_map
 
 # Profile used by sharded_lrc_repair_check (and the dryrun gate): 4 local
 # groups of l+1 = 5 chunks.  Callers needing the device-count constraint

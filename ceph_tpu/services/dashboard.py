@@ -55,6 +55,7 @@ import json
 import time
 
 from ceph_tpu.common.log import Dout
+from ceph_tpu.ec.profiler import roofline_text
 
 log = Dout("dashboard")
 
@@ -497,7 +498,7 @@ class Dashboard:
                 ["device GiB/s (EC launches)",
                  esc(f"{util.get('device_gibps', 0.0):g}")],
                 ["HBM roofline %",
-                 esc(f"{util.get('roofline_pct', 0.0):g}%")],
+                 esc(roofline_text(util.get("roofline_pct")))],
                 ["coalesce occupancy (ops/launch)",
                  esc(f"{util.get('coalesce_occupancy', 0.0):g}")],
                 ["coalesce wait p50/p99 µs",

@@ -27,6 +27,7 @@ from __future__ import annotations
 import time
 
 from ceph_tpu.common.perf import hist_quantile
+from ceph_tpu.ec.profiler import hbm_peak_gibps
 from ceph_tpu.common.slo import (
     MultiWindowBurn,
     SLOEngine,
@@ -189,7 +190,7 @@ class SLOMonitor(MgrModule):
         gib = float(1 << 30)
         win = eng.snapshot_window()
         span = win.span
-        peak = float(self.mgr.conf["ec_hbm_peak_gibps"] or 1.0)
+        peak = hbm_peak_gibps()
 
         launch_bytes, _ = win.scalar("ec_launch_bytes")
         enc_h, _ = win.hist("ec_encode_launch_us")
@@ -213,9 +214,11 @@ class SLOMonitor(MgrModule):
         return {
             "window_s": round(span, 3),
             # device roofline: achieved GiB/s through EC launches vs
-            # the conf'd HBM peak — the % of hardware we actually use
+            # the device's published HBM peak (None off a TPU: not
+            # measured)
             "device_gibps": round(device_gibps, 3),
-            "roofline_pct": round(100.0 * device_gibps / peak, 3),
+            "roofline_pct": round(100.0 * device_gibps / peak, 3)
+            if peak else None,
             "launch_bytes": int(launch_bytes),
             "launch_seconds": round(launch_s, 6),
             # coalescer: how full each shared launch ran, and what the
@@ -332,7 +335,7 @@ class SLOMonitor(MgrModule):
         for key, help_ in (
                 ("device_gibps", "achieved EC device throughput GiB/s"),
                 ("roofline_pct", "achieved device GiB/s as % of the "
-                                 "HBM roofline (ec_hbm_peak_gibps)"),
+                                 "device's published HBM peak"),
                 ("coalesce_occupancy", "ops per coalesced launch over "
                                        "the window"),
                 ("coalesce_wait_p99_us", "coalescer window-wait p99 us"),
@@ -343,6 +346,8 @@ class SLOMonitor(MgrModule):
                 ("client_p99_ms", "cluster client op p99 ms over the "
                                   "window"),
         ):
+            if u.get(key, 0.0) is None:
+                continue                # not measured on this device
             out[f"ceph_util_{key}"] = {
                 "help": help_,
                 "samples": [("", float(u.get(key, 0.0)))]}

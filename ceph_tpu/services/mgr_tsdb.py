@@ -26,6 +26,7 @@ from __future__ import annotations
 import time
 
 from ceph_tpu.common.perf import hist_quantile
+from ceph_tpu.ec.profiler import hbm_peak_gibps
 from ceph_tpu.services.mgr_modules import MgrModule
 
 # series namespaces a forensic bundle attaches (the burn-rate /
@@ -126,14 +127,14 @@ class TSDBMonitor(MgrModule):
             "orphan_spans": int(orphans),
             "eviction_rate": round(rate, 4),
         }
-        peak = float(self.mgr.conf["ec_hbm_peak_gibps"] or 0.0)
+        peak = hbm_peak_gibps()
         for sig, agg in kernels.items():
             wall_s = agg["wall_us"] / 1e6
             agg["gibps"] = round(
                 agg["hbm_bytes"] / (1 << 30) / wall_s, 3) \
                 if wall_s > 0 else 0.0
-            agg["roofline_pct"] = round(
-                100.0 * agg["gibps"] / peak, 3) if peak > 0 else 0.0
+            if peak:
+                agg["roofline_pct"] = round(100.0 * agg["gibps"] / peak, 3)
             feed[f"kernel.{sig}.wall_us"] = agg["wall_us"]
             feed[f"kernel.{sig}.launches"] = agg["launches"]
             feed[f"kernel.{sig}.hbm_bytes"] = agg["hbm_bytes"]

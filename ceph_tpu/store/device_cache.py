@@ -97,15 +97,12 @@ class DeviceShardCache:
         if self.sharding is None or isinstance(
                 arr, (np.ndarray, bytes, bytearray, memoryview)):
             return arr
-        try:
-            import jax
+        import jax
 
-            ndev = len(self.sharding.device_set)
-            if arr.ndim >= 1 and arr.shape[0] % max(1, ndev) == 0:
-                arr = jax.device_put(arr, self.sharding)
-                self.reshards += 1
-        except Exception:
-            pass
+        ndev = len(self.sharding.device_set)
+        if arr.ndim >= 1 and arr.shape[0] % max(1, ndev) == 0:
+            arr = jax.device_put(arr, self.sharding)
+            self.reshards += 1
         return arr
 
     # -- lookup / install -------------------------------------------------

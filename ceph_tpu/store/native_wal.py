@@ -1,9 +1,9 @@
 """ctypes bindings for the native WAL engine (wal_engine.cc).
 
-Loads the same libceph_tpu_native.so as the crc32c fast path; absent or
-unbuildable native code degrades to the pure-Python file path in
-walstore.py (identical on-disk format, so the two interoperate on the
-same files).
+Loads the same libceph_tpu_native.so as the crc32c fast path (a build
+that fails raises there).  walstore.py keeps a pure-Python file path
+with the identical on-disk format, so the two interoperate on the same
+files.
 """
 
 from __future__ import annotations

@@ -69,10 +69,10 @@ def test_config_register_subsystem_options():
 
 def test_config_bool_parse():
     cfg = ConfigProxy()
-    cfg.set("ec_use_pallas", "false")
-    assert cfg.get("ec_use_pallas") is False
-    cfg.set("ec_use_pallas", "yes")
-    assert cfg.get("ec_use_pallas") is True
+    cfg.set("osd_ec_coalesce", "false")
+    assert cfg.get("osd_ec_coalesce") is False
+    cfg.set("osd_ec_coalesce", "yes")
+    assert cfg.get("osd_ec_coalesce") is True
 
 
 # -- perf ----------------------------------------------------------------

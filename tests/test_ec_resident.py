@@ -277,33 +277,7 @@ def test_resident_and_classic_backends_concurrent():
     _run(run())
 
 
-# -- fused u8 prologue (interpret mode) -----------------------------------
-
-
-def test_apply_bytes_u8_variant_interpret():
-    """The fused int8 lane-pack prologue (apply_bytes with the promoted
-    enc_u8_expand variant) is bit-identical to the word-path oracle in
-    interpret mode, including the quarter-pad tail."""
-    from ceph_tpu.ec import matrix, reference
-    from ceph_tpu.ec.pallas_kernels import (
-        PallasShardApply, bytes_to_words, set_encode_variant,
-        words_to_bytes)
-
-    k, m = 8, 4
-    G = matrix.generator_matrix("cauchy_good", k, m)
-    ap = PallasShardApply(G[k:], interpret=True)
-    rng = np.random.default_rng(41)
-    for n in (4096, 4096 + 512, 1028):     # 1028 % (4*LANE) != 0
-        data = np.asarray(rng.integers(0, 256, (k, n)), np.uint8)
-        base = np.asarray(
-            words_to_bytes(ap.apply_words(bytes_to_words(data))))
-        set_encode_variant("enc_u8_expand")
-        try:
-            got = np.asarray(ap.apply_bytes(data))
-        finally:
-            set_encode_variant("")
-        assert np.array_equal(got, base), f"n={n}"
-        assert np.array_equal(got, reference.encode(G, data)[k:])
+# -- apply_bytes / encode variant selection ------------------------------
 
 
 def test_apply_bytes_rejects_unaligned():
@@ -316,19 +290,16 @@ def test_apply_bytes_rejects_unaligned():
         ap.apply_bytes(np.zeros((4, 1026), np.uint8))
 
 
-def test_auto_variant_resolves_by_backend():
-    """The config default "auto" resolves to the promoted u8 kernel on
-    TPU and the production path elsewhere, at set time."""
-    import jax
-
+def test_auto_variant_resolves_to_the_chip_checked_kernel():
+    """The config default "auto" resolves at set time to AUTO_VARIANT,
+    the formulation chip_smoke.py checks on the chip."""
     from ceph_tpu.ec.pallas_kernels import (
-        get_encode_variant, set_encode_variant)
+        AUTO_VARIANT, ENCODE_VARIANTS, get_encode_variant,
+        set_encode_variant)
 
     set_encode_variant("auto")
     try:
-        if jax.default_backend() == "tpu":
-            assert get_encode_variant() == "enc_u8_expand"
-        else:
-            assert get_encode_variant() == ""
+        assert get_encode_variant() == AUTO_VARIANT
+        assert AUTO_VARIANT in ENCODE_VARIANTS
     finally:
         set_encode_variant("")

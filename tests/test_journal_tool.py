@@ -93,7 +93,7 @@ def test_journal_tool_lifecycle(tmp_path):
 
 
 def test_walk_frames_pure():
-    """Frame walker damage taxonomy without a cluster."""
+    """Frame walker damage classes without a cluster."""
     from ceph_tpu.msg.codec import encode
     ev = encode({"op": "mkdir", "ino": 5})
     clean = _FRAME.pack(len(ev)) + ev

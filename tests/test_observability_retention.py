@@ -275,6 +275,31 @@ def test_kernel_profiler_totals_and_registry():
     assert prof.totals()["launches"] == 0
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", None),
+    ("tpu", "TPU v5 lite", 819e9 / (1 << 30)),
+    ("tpu", "TPU v99", KeyError),
+])
+def test_hbm_peak_by_device_kind(monkeypatch, platform, kind, want):
+    """The roofline peak comes from the device kind: none off a TPU
+    (share not measured), the published value for a v5e, and an error
+    for a TPU the table does not know."""
+    import types
+
+    import jax
+
+    from ceph_tpu.ec.profiler import hbm_peak_gibps, roofline_text
+
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda: [dev])
+    if want is KeyError:
+        with pytest.raises(KeyError, match="no published HBM peak"):
+            hbm_peak_gibps()
+        return
+    assert hbm_peak_gibps() == want
+    assert roofline_text(None) == "not measured"
+
+
 def test_profiler_attribution_matches_launch_counters():
     """The acceptance reconciliation: drive a real ECBackend and the
     profiler's per-signature totals must equal the byte counter

@@ -350,7 +350,9 @@ def test_slo_and_utilization_gauges_in_scrape():
             text = mgr.prometheus_text(snap, mgr.prometheus_extra())
             assert 'ceph_slo_burn_rate{objective="put_p99_ms"}' in text
             assert 'ceph_slo_ok{objective="put_p99_ms"} 1' in text
-            assert "ceph_util_roofline_pct" in text
+            # CPU backend: no published HBM peak, share not measured
+            assert "ceph_util_roofline_pct" not in text
+            assert "ceph_util_device_gibps" in text
             assert "ceph_util_rebuild_gibps" in text
             assert "ceph_util_client_p99_ms" in text
             # per-daemon histogram series feed the same scrape
