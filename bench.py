@@ -237,10 +237,6 @@ def _cfg6_coalesce_ab(n_writes: int = 64, write_bytes: int = 4096) -> dict:
         if coalesce:
             st = be.coalescer.stats()
             out["occupancy"] = round(st["occupancy"], 2)
-            wait = dump.get("ec_coalesce_wait_us", {})
-            if isinstance(wait, dict) and wait.get("avgcount"):
-                out["mean_wait_us"] = round(
-                    wait["sum"] / wait["avgcount"], 1)
             out["pad_waste_stripes"] = float(
                 dump.get("ec_coalesce_pad_waste", 0.0))
     out["launch_reduction"] = round(

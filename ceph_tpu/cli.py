@@ -1211,8 +1211,9 @@ def _render_top(d: dict, kernels: bool) -> str:
                 f"{rec.get('stripes', 0):>8} stripes  "
                 f"{rec.get('wall_us', 0.0) / 1e3:>9.1f} ms  "
                 f"{rec.get('hbm_bytes', 0) / (1 << 20):>9.1f} MiB  "
-                f"{rec.get('gibps', 0.0):>7.2f} GiB/s  "
-                f"{roofline_text(rec.get('roofline_pct')):>12}")
+                + (f"{rec['gibps']:>7.2f} GiB/s  " if "gibps" in rec
+                   else f"{'-':>7} GiB/s  ")
+                + f"{roofline_text(rec.get('roofline_pct')):>12}")
     return "\n".join(lines)
 
 

@@ -198,18 +198,23 @@ class Objecter:
             timeout = float(self.monc.conf["client_op_deadline"])
         parent = current_span()
         prob = float(self.monc.conf["trace_probability"] or 0.0)
+        sampled = parent is not None or bool(
+            prob and random.random() < prob)
+        # one reqid for the whole retry loop: a resend after a session
+        # reset is the SAME logical op, so the OSD can answer from its
+        # completed-op cache instead of re-executing (reference replays
+        # are deduped via osd_reqid_t in the PG log)
+        self._reqid_seq += 1
+        reqid = f"{self._reqid_name}:{self._reqid_seq}"
         t0 = time.monotonic()
         try:
-            if parent is not None or (prob and random.random() < prob):
-                with self.tracer.span("objecter:op_submit",
-                                      parent=parent, oid=oid,
-                                      pool=pool_id) as tctx:
-                    ret = await self._op_submit_impl(
-                        pool_id, oid, ops, timeout, extra, tctx
-                    )
-            else:
-                ret = await self._op_submit_impl(pool_id, oid, ops,
-                                                 timeout, extra, None)
+            with self.tracer.span(
+                    "objecter:op_submit", parent, root=sampled,
+                    reqid=reqid, oid=oid,
+                    tags={"pool": pool_id} if sampled else None) as tctx:
+                ret = await self._op_submit_impl(
+                    pool_id, oid, ops, timeout, extra, tctx, reqid
+                )
         except Exception:
             # cancellation is the caller's doing, not an op failure
             self.perf.inc("op_error")
@@ -220,15 +225,10 @@ class Objecter:
 
     async def _op_submit_impl(self, pool_id: int, oid: str,
                               ops: list[dict], timeout: float,
-                              extra: dict | None, tctx) -> dict:
+                              extra: dict | None, tctx,
+                              reqid: str) -> dict:
         loop = asyncio.get_running_loop()
         deadline = loop.time() + timeout
-        # one reqid for the whole retry loop: a resend after a session
-        # reset is the SAME logical op, so the OSD can answer from its
-        # completed-op cache instead of re-executing (reference replays
-        # are deduped via osd_reqid_t in the PG log)
-        self._reqid_seq += 1
-        reqid = f"{self._reqid_name}:{self._reqid_seq}"
         # capped exponential backoff between resends, jitter seeded from
         # the reqid so a run replays the exact sleep schedule
         backoff = ExpBackoff(

@@ -1,22 +1,45 @@
-"""Zipkin-lite distributed tracing for the op path.
+"""Op-path tracing: one span API, two sinks.
 
-The role of reference src/common/zipkin_trace.h (:24 ZTracer wrappers)
-+ the OpRequest trace hooks (src/osd/OpRequest.h): a sampled client op
-carries a trace context on the wire; every hop (objecter submit, OSD
-op execution, sub-op fan-out, replica apply) records a timed span
-linked by (trace_id, parent span id).  Spans land in a bounded
-per-process ring inspectable via the admin socket / ``dump_traces``
-message, keyed so a cross-daemon trace tree can be reassembled.
+**Sampled ring** (the role of reference src/common/zipkin_trace.h:24
+ZTracer wrappers + the OpRequest trace hooks, src/osd/OpRequest.h): a
+sampled client op carries a trace context on the wire; every hop
+(objecter submit, OSD op execution, sub-op fan-out, replica apply)
+records a timed span linked by (trace_id, parent span id).  Spans land
+in a bounded per-process ring inspectable via the admin socket /
+``dump_traces`` message, keyed so a cross-daemon trace tree can be
+reassembled.  The root decides (``trace_probability`` config);
+everything downstream of a sampled op traces unconditionally, so a
+trace is always complete.
 
-Sampling: the root decides (``trace_probability`` config); everything
-downstream of a sampled op traces unconditionally, so a trace is
-always complete.
+**Profiler capture**: while a JAX profiler capture runs in this process
+(``jax.profiler.start_trace`` with ``host_tracer_level >= 1``, or an
+operator's capture over the profiler server), every span — sampled or
+not — is also emitted into it as a ``TraceAnnotation`` on the thread
+that runs the code, with its identifiers (``reqid``, ``oid``,
+``shard``) as metadata.  The spans then share the device trace's clock:
+what the host did while the chip idled is read off one timeline.
+
+Layer spans name where the op path spends the host's time.  A *cpu*
+span encloses a synchronous section (no ``await`` inside), so on the
+event loop's thread it is what the loop was doing: ``msgr:encode``,
+``msgr:frame_out``, ``msgr:frame_in``, ``ec:prep``, ``ec:h2d``,
+``ec:d2h``, ``ec:hinfo``, ``store:apply``, ``store:read``.  A *wait*
+span encloses awaits:
+``osd:queue``, ``osd:fanout``, ``ec:coalesce_wait``, and ``ec:launch``
+on the worker thread that runs the codec call.
+
+With no capture running and the op not sampled, a span site costs one
+flag check and returns the shared no-op ``NULL_SPAN``: no dict, no id,
+no string is built.  Span sites therefore pass identifiers as plain
+keyword arguments (never a built dict or f-string) and let the span
+format them only when it records.
 """
 
 from __future__ import annotations
 
 import contextvars
 import secrets
+import sys
 import time
 from collections import deque
 from contextlib import contextmanager
@@ -65,6 +88,136 @@ def use_span(ctx: SpanCtx | None):
         _ACTIVE.reset(tok)
 
 
+# -- the profiler sink ----------------------------------------------------
+
+_Note = None            # jaxlib's TraceMe with end(), once JAX is loaded
+
+
+def _probe() -> bool:
+    """``capturing`` until JAX is loaded: without JAX in the process no
+    capture can run, and nothing is imported.  Once it is, the module
+    name ``capturing`` is rebound to jaxlib's own flag check, so a span
+    site costs that one call — callers look it up as
+    ``tracing.capturing``, never by a from-import."""
+    global capturing, _Note
+    lib = sys.modules.get("jax._src.lib")
+    profiler = getattr(lib, "_profiler", None)
+    if profiler is None:
+        return False
+
+    class Note(profiler.TraceMe):
+        """An annotation in the running capture: it starts when made and
+        stops on leaving its ``with`` block or at ``end()``, once.  Its
+        ``with`` yields None: no context to propagate."""
+
+        __slots__ = ()
+
+        def __enter__(self):
+            super().__enter__()
+
+        def end(self) -> None:
+            self.__exit__(None, None, None)
+
+    _Note = Note
+    capturing = profiler.TraceMe.is_enabled
+    return capturing()
+
+
+capturing = _probe
+
+
+def _meta(reqid, oid, shard, tags) -> dict:
+    """A span's tags: ``tags`` and its identifiers that are set."""
+    meta = dict(tags) if tags else {}
+    if reqid is not None:
+        meta["reqid"] = reqid
+    if oid is not None:
+        meta["oid"] = oid
+    if shard is not None:
+        meta["shard"] = shard
+    return meta
+
+
+def _note(name, reqid, oid, shard, tags):
+    """The capture's annotation of one span, its tags as metadata."""
+    if reqid is None and oid is None and shard is None and not tags:
+        return _Note(name)
+    return _Note(name, **_meta(reqid, oid, shard, tags))
+
+
+class _NullSpan:
+    """The span of an op that is neither sampled nor captured."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+    def end(self) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """A span of a sampled op: its ring record, and its annotation while
+    a capture runs.  Starts when made; ``end()`` (or leaving the
+    ``with`` block) stops it, once — so a wait span may start at one
+    site and end at another on the same thread."""
+
+    __slots__ = ("ctx", "_note", "_tracer", "_rec", "_t0")
+
+    def __init__(self, name, tracer, parent, reqid, oid, shard, tags):
+        meta = _meta(reqid, oid, shard, tags)
+        ctx = SpanCtx(
+            parent.trace_id if parent else secrets.token_hex(8),
+            secrets.token_hex(4),
+        )
+        self.ctx, self._tracer = ctx, tracer
+        # wall-clock start for cross-daemon ordering, monotonic clock
+        # for the duration (an NTP step must not yield negative spans)
+        self._rec = {
+            "trace_id": ctx.trace_id,
+            "span_id": ctx.span_id,
+            "parent": parent.span_id if parent else "",
+            "name": name,
+            "entity": tracer.entity,
+            "start": time.time(),
+            **({"tags": meta} if meta else {}),
+        }
+        self._t0 = time.perf_counter()
+        self._note = _Note(name, **meta) if capturing() else None
+
+    def __enter__(self):
+        return self.ctx
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+    def end(self) -> None:
+        note, self._note = self._note, None
+        if note is not None:
+            note.__exit__(None, None, None)
+        rec, self._rec = self._rec, None
+        if rec is not None:
+            rec["duration_ms"] = round(
+                (time.perf_counter() - self._t0) * 1e3, 3)
+            self._tracer._append(rec)
+
+
+def span(name: str, *, reqid=None, oid=None, shard=None):
+    """A layer span into the running capture (no ring: layer spans are
+    for the profiler's timeline); ``NULL_SPAN`` when none runs."""
+    if not capturing():
+        return NULL_SPAN
+    return _note(name, reqid, oid, shard, None)
+
+
 class Tracer:
     """Per-process span collector (one per daemon entity)."""
 
@@ -82,39 +235,33 @@ class Tracer:
             self.ring_evictions += 1
         self.spans.append(span)
 
-    @contextmanager
-    def span(self, name: str, parent: SpanCtx | None = None, **tags):
-        """Record a timed span; yields the child SpanCtx to propagate.
-        Works around both sync and async code (it only stamps clocks)."""
-        ctx = SpanCtx(
-            parent.trace_id if parent else secrets.token_hex(8),
-            secrets.token_hex(4),
-        )
-        # wall-clock start for cross-daemon ordering, monotonic clock
-        # for the duration (an NTP step must not yield negative spans)
-        start = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield ctx
-        finally:
-            self._append({
-                "trace_id": ctx.trace_id,
-                "span_id": ctx.span_id,
-                "parent": parent.span_id if parent else "",
-                "name": name,
-                "entity": self.entity,
-                "start": start,
-                "duration_ms": round(
-                    (time.perf_counter() - t0) * 1e3, 3),
-                **({"tags": tags} if tags else {}),
-            })
+    def span(self, name: str, parent: SpanCtx | None = None, *,
+             root: bool = True, reqid=None, oid=None, shard=None,
+             tags: dict | None = None):
+        """Time the enclosed block (sync or async: it only stamps
+        clocks) as span ``name``; ``with`` yields the child SpanCtx to
+        propagate, or None when the ring does not record it.
+
+        The ring records it under ``parent``; with no parent it opens a
+        new trace, unless ``root=False``: an op-path site, which spans
+        every op, passes the op's sampled context or None.  The running
+        capture, if any, gets the span either way.
+        ``reqid``/``oid``/``shard`` and ``tags`` ride both as the span's
+        tags; build ``tags`` only where the ring records."""
+        if parent is None and not root:
+            if not capturing():
+                return NULL_SPAN
+            return _note(name, reqid, oid, shard, tags)
+        return _Span(name, self, parent, reqid, oid, shard, tags)
 
     def record(self, name: str, parent: SpanCtx, start: float,
                duration_ms: float, **tags) -> SpanCtx:
-        """Append a pre-measured span (no context manager).  For work
-        shared across ops — a coalesced device launch serves many
-        traces at once, so the one measured interval is recorded once
-        per interested parent."""
+        """Append a pre-measured span to the ring (no context manager).
+        For work shared across ops — a coalesced device launch serves
+        many traces at once, so the one measured interval is recorded
+        once per interested parent.  A capture cannot take an interval
+        after the fact: the site that measured it runs it inside a live
+        ``span`` for the profiler."""
         ctx = SpanCtx(parent.trace_id, secrets.token_hex(4))
         self._append({
             "trace_id": ctx.trace_id,

@@ -17,8 +17,13 @@ decode, mesh repair, host-mesh flush — to its codec signature
   ``ec_launch_bytes``), so per-signature byte totals reconcile with
   the existing counters EXACTLY — the profiler is an attribution of
   the counters, never a second opinion;
+- ``enqueue_only``: launches whose interval ends when the work is
+  enqueued, not when the device has run it (a device-resident launch
+  returns device arrays before the kernel finishes);
 - derived ``gibps`` and, on a device in :data:`HBM_PEAK_BYTES_S`,
-  ``roofline_pct``.
+  ``roofline_pct`` — only for a signature with no enqueue-only
+  launch: an enqueue is not a transfer rate, so such a signature
+  reads "not measured".
 
 The dump rides the OSD's perf_dump under the ``ec_kernels`` key, the
 mgr persists per-signature series into the TSDB, and
@@ -71,17 +76,21 @@ class KernelProfiler:
         self._lock = threading.Lock()
 
     def record(self, signature: str, wall_us: float,
-               stripes: int = 0, hbm_bytes: int = 0) -> None:
+               stripes: int = 0, hbm_bytes: int = 0,
+               enqueue_only: bool = False) -> None:
+        """One launch; ``enqueue_only`` when ``wall_us`` stops at the
+        enqueue."""
         with self._lock:
             rec = self.kernels.get(signature)
             if rec is None:
                 rec = self.kernels[signature] = {
                     "launches": 0, "wall_us": 0.0,
-                    "stripes": 0, "hbm_bytes": 0}
+                    "stripes": 0, "hbm_bytes": 0, "enqueue_only": 0}
             rec["launches"] += 1
             rec["wall_us"] += float(wall_us)
             rec["stripes"] += int(stripes)
             rec["hbm_bytes"] += int(hbm_bytes)
+            rec["enqueue_only"] += int(enqueue_only)
 
     def totals(self) -> dict:
         with self._lock:
@@ -94,20 +103,22 @@ class KernelProfiler:
 
     def dump(self, peak_gibps: float | None = None) -> dict:
         """JSON-friendly per-signature table with derived bandwidth
-        (and roofline % when a peak is known)."""
+        (and roofline % when a peak is known), both left out for a
+        signature with an enqueue-only launch."""
         out: dict[str, dict] = {}
         with self._lock:
             items = sorted((sig, dict(rec))
                            for sig, rec in self.kernels.items())
         for sig, rec in items:
             wall_s = rec["wall_us"] / 1e6
-            gibps = (rec["hbm_bytes"] / _GIB / wall_s) \
-                if wall_s > 0 else 0.0
             rec["wall_us"] = round(rec["wall_us"], 1)
-            rec["gibps"] = round(gibps, 3)
-            if peak_gibps:
-                rec["roofline_pct"] = round(
-                    100.0 * gibps / peak_gibps, 3)
+            if not rec["enqueue_only"]:
+                gibps = (rec["hbm_bytes"] / _GIB / wall_s) \
+                    if wall_s > 0 else 0.0
+                rec["gibps"] = round(gibps, 3)
+                if peak_gibps:
+                    rec["roofline_pct"] = round(
+                        100.0 * gibps / peak_gibps, 3)
             out[sig] = rec
         return out
 

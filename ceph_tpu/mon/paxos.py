@@ -345,9 +345,8 @@ class Paxos:
         await self._maybe_propose()
 
     def _commit(self, v: int, raw: bytes) -> None:
-        span = (self.tracer.span("mon:paxos_commit",
-                                 parent=current_span(), v=v,
-                                 bytes=len(raw))
+        span = (self.tracer.span("mon:paxos_commit", current_span(),
+                                 tags={"v": v, "bytes": len(raw)})
                 if self.tracer is not None else nullcontext())
         with span:
             if fp.ACTIVE:

@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from typing import Optional, Protocol
 
 from ceph_tpu.common import failpoint as fp
+from ceph_tpu.common import tracing
 from ceph_tpu.common.crc32c import crc32c
 from ceph_tpu.common.log import Dout
 from ceph_tpu.common.perf import CounterType, PerfCounters
@@ -230,7 +231,8 @@ class Connection:
         if self._closed:
             raise MessengerError(f"connection to {self.peer_addr} closed")
         self.out_seq += 1
-        payload = encode(msg.to_wire())
+        with tracing.span("msgr:encode"):
+            payload = encode(msg.to_wire())
         if not self.policy.lossy:
             self._sent_unacked.append((self.out_seq, payload))
         self._out.put_nowait((self.out_seq, payload))
@@ -296,28 +298,32 @@ class Connection:
                     self._ready.clear()
                     continue
                 try:
-                    self.msgr._maybe_inject_failure()
-                    wire = payload
-                    if self._onwire is not None:
-                        # AES-GCM per frame, nonce = direction prefix +
-                        # seq.  The header (seq, ack, length) rides as
-                        # AAD: CRC alone would let an active attacker
-                        # rewrite the ack and silently purge unreplayed
-                        # messages from a lossless session.
-                        ack = self.in_seq
-                        aad = _AAD.pack(seq, ack, len(payload) + 16)
-                        wire = self._onwire[0].encrypt(
-                            self._onwire[1] + seq.to_bytes(8, "little"),
-                            payload, aad,
-                        )
-                        hdr = _FRAME_HDR.pack(seq, ack, len(wire),
-                                              crc32c(0xFFFFFFFF, wire))
-                    else:
-                        hdr = _FRAME_HDR.pack(
-                            seq, self.in_seq, len(wire),
-                            crc32c(0xFFFFFFFF, wire),
-                        )
-                    stream.write(hdr + wire)
+                    with tracing.span("msgr:frame_out"):
+                        self.msgr._maybe_inject_failure()
+                        wire = payload
+                        if self._onwire is not None:
+                            # AES-GCM per frame, nonce = direction
+                            # prefix + seq.  The header (seq, ack,
+                            # length) rides as AAD: CRC alone would let
+                            # an active attacker rewrite the ack and
+                            # silently purge unreplayed messages from a
+                            # lossless session.
+                            ack = self.in_seq
+                            aad = _AAD.pack(seq, ack, len(payload) + 16)
+                            wire = self._onwire[0].encrypt(
+                                self._onwire[1]
+                                + seq.to_bytes(8, "little"),
+                                payload, aad,
+                            )
+                            hdr = _FRAME_HDR.pack(
+                                seq, ack, len(wire),
+                                crc32c(0xFFFFFFFF, wire))
+                        else:
+                            hdr = _FRAME_HDR.pack(
+                                seq, self.in_seq, len(wire),
+                                crc32c(0xFFFFFFFF, wire),
+                            )
+                        stream.write(hdr + wire)
                     await stream.drain()
                 except MessengerError as e:
                     self._out.put_nowait((seq, payload))
@@ -340,38 +346,42 @@ class Connection:
                 except MessengerError as e:
                     self._on_stream_failure(e)
                     continue
-                if crc32c(0xFFFFFFFF, payload) != crc:
-                    self._on_stream_failure(MessengerError("bad frame crc"))
-                    continue
-                if self._onwire is not None:
-                    try:
-                        payload = self._onwire[0].decrypt(
-                            self._onwire[2]
-                            + seq.to_bytes(8, "little"),
-                            payload, _AAD.pack(seq, ack, length),
-                        )
-                    except Exception:
-                        # InvalidTag: tampered frame OR tampered header
-                        # (aad covers seq/ack/length) or key mismatch
+                with tracing.span("msgr:frame_in"):
+                    if crc32c(0xFFFFFFFF, payload) != crc:
                         self._on_stream_failure(
-                            MessengerError("onwire auth failed")
+                            MessengerError("bad frame crc"))
+                        continue
+                    if self._onwire is not None:
+                        try:
+                            payload = self._onwire[0].decrypt(
+                                self._onwire[2]
+                                + seq.to_bytes(8, "little"),
+                                payload, _AAD.pack(seq, ack, length),
+                            )
+                        except Exception:
+                            # InvalidTag: tampered frame OR tampered
+                            # header (aad covers seq/ack/length) or key
+                            # mismatch
+                            self._on_stream_failure(
+                                MessengerError("onwire auth failed")
+                            )
+                            continue
+                    while (self._sent_unacked
+                           and self._sent_unacked[0][0] <= ack):
+                        self._sent_unacked.popleft()
+                    if seq <= self.in_seq:
+                        continue                  # replayed duplicate
+                    try:
+                        msg = Message.from_wire(decode(payload), seq)
+                    except (ValueError, TypeError, KeyError, IndexError,
+                            struct.error) as e:
+                        # crc-valid but malformed payload: treat as a
+                        # stream failure, not a reader-task crash
+                        self._on_stream_failure(
+                            MessengerError(f"bad payload: {e}")
                         )
                         continue
-                while self._sent_unacked and self._sent_unacked[0][0] <= ack:
-                    self._sent_unacked.popleft()
-                if seq <= self.in_seq:
-                    continue                      # replayed duplicate
-                try:
-                    msg = Message.from_wire(decode(payload), seq)
-                except (ValueError, TypeError, KeyError, IndexError,
-                        struct.error) as e:
-                    # crc-valid but malformed payload: treat as a stream
-                    # failure, not a reader-task crash
-                    self._on_stream_failure(
-                        MessengerError(f"bad payload: {e}")
-                    )
-                    continue
-                self.in_seq = seq
+                    self.in_seq = seq
                 throttle = self.msgr._dispatch_throttle(self)
                 if throttle is not None:
                     # Backpressure while the message is in DISPATCH
@@ -838,11 +848,9 @@ class Messenger:
                 if isinstance(msg.data, dict) else None)
         t0 = time.perf_counter()
         try:
-            if tctx is not None:
-                with self.tracer.span("msgr:dispatch", parent=tctx,
-                                      type=msg.type):
-                    await self.dispatcher.ms_dispatch(conn, msg)
-            else:
+            with self.tracer.span(
+                    "msgr:dispatch", tctx, root=False,
+                    tags={"type": msg.type} if tctx else None):
                 await self.dispatcher.ms_dispatch(conn, msg)
         except Exception:
             log.derr("%s: dispatch of %s failed", self.name, msg.type)

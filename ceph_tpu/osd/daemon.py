@@ -28,6 +28,7 @@ import hmac as hmac_mod
 import secrets as secrets_mod
 
 from ceph_tpu.common import failpoint as fp
+from ceph_tpu.common import tracing
 from ceph_tpu.common.events import EventJournal
 from ceph_tpu.common.lockdep import DLock
 from ceph_tpu.common.config import ConfigProxy
@@ -129,6 +130,22 @@ _MON_TYPES = {
     "auth_challenge", "auth_reply", "auth_bad", "mon_command_reply",
     "osd_map", "config", "mon_map",
 }
+
+
+# sub-op span names by kind, formatted once: a span site builds no string
+_SUB_OP_SEND: dict[str, str] = {}
+_SUB_OP_RECV: dict[str, str] = {}
+
+
+def _sub_op_span(kind: str, send: bool) -> str:
+    """``osd:sub_op:<kind>:send`` on the sender, ``osd:sub_op:<kind>``
+    on the receiver."""
+    names = _SUB_OP_SEND if send else _SUB_OP_RECV
+    name = names.get(kind)
+    if name is None:
+        name = names[kind] = (f"osd:sub_op:{kind}:send" if send
+                              else f"osd:sub_op:{kind}")
+    return name
 
 
 class DeadShard:
@@ -4227,6 +4244,10 @@ class OSDDaemon:
 
     # -- client ops ----------------------------------------------------------
     async def _handle_osd_op(self, conn: Connection, d: dict) -> None:
+        # admission: the throttle here and the mClock acquire in
+        # _handle_osd_op_inner, up to the op's "dispatched" mark
+        queue = tracing.span("osd:queue", reqid=d.get("reqid"),
+                             oid=d.get("oid"))
         # op-lifetime payload budget: acquired before any work, released
         # when the op (including its fan-out and reply) is done
         cost = 256 + sum(
@@ -4235,32 +4256,34 @@ class OSDDaemon:
         )
         await self.client_throttle.acquire(cost)
         try:
-            await self._handle_osd_op_traced(conn, d)
+            await self._handle_osd_op_traced(conn, d, queue)
         finally:
+            queue.end()
             self.client_throttle.release(cost)
 
-    async def _handle_osd_op_traced(self, conn: Connection,
-                                    d: dict) -> None:
+    async def _handle_osd_op_traced(self, conn: Connection, d: dict,
+                                    queue) -> None:
         tctx = SpanCtx.from_wire(d.get("tctx"))
-        if tctx is not None:
-            # sampled op: the span covers the full primary-side life,
-            # and the contextvar hands the context to sub-op fan-out
-            with self.tracer.span("osd:do_op", parent=tctx,
-                                  oid=str(d.get("oid", "?"))) as ctx:
-                with use_span(ctx):
-                    await self._handle_osd_op_inner(conn, d)
-            # the do_op span itself only lands in the ring here; if
-            # the op was slow enough to be retained, (re)attach the
-            # now-complete span tree to its forensic record
-            if self.op_tracker.has_slow_trace(ctx.trace_id):
-                self.op_tracker.attach_spans(
-                    ctx.trace_id, self.tracer.dump(ctx.trace_id)
-                )
-            return
-        await self._handle_osd_op_inner(conn, d)
+        # a sampled op's span covers the full primary-side life, and the
+        # contextvar hands the context to sub-op fan-out
+        with self.tracer.span("osd:do_op", tctx, root=False,
+                              reqid=d.get("reqid"),
+                              oid=d.get("oid")) as ctx:
+            if ctx is None:
+                await self._handle_osd_op_inner(conn, d, queue)
+                return
+            with use_span(ctx):
+                await self._handle_osd_op_inner(conn, d, queue)
+        # the do_op span itself only lands in the ring here; if the op
+        # was slow enough to be retained, (re)attach the now-complete
+        # span tree to its forensic record
+        if self.op_tracker.has_slow_trace(ctx.trace_id):
+            self.op_tracker.attach_spans(
+                ctx.trace_id, self.tracer.dump(ctx.trace_id)
+            )
 
-    async def _handle_osd_op_inner(self, conn: Connection,
-                                   d: dict) -> None:
+    async def _handle_osd_op_inner(self, conn: Connection, d: dict,
+                                   queue) -> None:
         tid = d.get("tid", 0)
         op_start = time.monotonic()
         top = None
@@ -4321,6 +4344,7 @@ class OSDDaemon:
             if self._use_mclock:
                 await self.op_scheduler.acquire("client")
             top.mark("dispatched")
+            queue.end()
             self._hitset_record(pg, str(d.get("oid", "")))
             special = [op for op in ops
                        if op.get("op") in ("watch", "unwatch", "notify",
@@ -4634,7 +4658,7 @@ class OSDDaemon:
                     results.append({})
                 elif kind == "read":
                     data = await be.read(oid, int(op.get("off", 0)),
-                                         op.get("len"))
+                                         op.get("len"), batch_reqid)
                     results.append({"data": data})
                 elif kind == "stat":
                     meta = await be._read_meta(oid)
@@ -5151,13 +5175,15 @@ class OSDDaemon:
     # -- sub ops (shard/replica server side) -----------------------------------
     async def send_sub_op(self, osd: int, kind: str, **args):
         ctx = current_span()
-        if ctx is not None and "tctx" not in args:
-            with self.tracer.span(f"osd:sub_op:{kind}:send",
-                                  parent=ctx, to=osd) as child:
-                return await self._send_sub_op_impl(
-                    osd, kind, tctx=child.to_wire(), **args
-                )
-        return await self._send_sub_op_impl(osd, kind, **args)
+        if "tctx" in args:
+            ctx = None              # the caller carries its own context
+        with self.tracer.span(_sub_op_span(kind, True), ctx, root=False,
+                              tags={"to": osd} if ctx else None) as child:
+            if child is None:
+                return await self._send_sub_op_impl(osd, kind, **args)
+            return await self._send_sub_op_impl(
+                osd, kind, tctx=child.to_wire(), **args
+            )
 
     async def _send_sub_op_impl(self, osd: int, kind: str, **args):
         """Send one sub-op and await its reply (tid-correlated). Every
@@ -5231,14 +5257,9 @@ class OSDDaemon:
         return int(d.get("iepoch", 0)) < pg.epoch
 
     async def _handle_sub_op(self, conn: Connection, d: dict) -> None:
-        tctx = SpanCtx.from_wire(d.get("tctx"))
-        if tctx is not None:
-            with self.tracer.span(
-                f"osd:sub_op:{d.get('kind', '?')}", parent=tctx,
-            ):
-                await self._handle_sub_op_inner(conn, d)
-            return
-        await self._handle_sub_op_inner(conn, d)
+        with self.tracer.span(_sub_op_span(d.get("kind", "?"), False),
+                              SpanCtx.from_wire(d.get("tctx")), root=False):
+            await self._handle_sub_op_inner(conn, d)
 
     async def _handle_sub_op_inner(self, conn: Connection,
                                    d: dict) -> None:

@@ -36,6 +36,7 @@ from typing import Mapping, Protocol, Sequence
 import numpy as np
 
 from ceph_tpu.common import failpoint as fp
+from ceph_tpu.common import tracing
 from ceph_tpu.common.crc32c import crc32c
 from ceph_tpu.common.perf import CounterType, PerfCounters
 from ceph_tpu.common.tracing import current_span
@@ -258,9 +259,10 @@ class ExtentCache:
 class _CoalesceItem:
     """One op's parked launch request (payload + result future).
     ``span``: the submitting op's ambient SpanCtx (if the op is
-    sampled) — the shared launch is recorded under it at flush."""
+    sampled) — the shared launch is recorded under it at flush.
+    ``wait``: its ``ec:coalesce_wait`` span, parked to flush."""
 
-    __slots__ = ("payload", "nstripes", "fut", "t0", "span")
+    __slots__ = ("payload", "nstripes", "fut", "t0", "span", "wait")
 
     def __init__(self, payload, nstripes, fut, t0, span=None):
         self.payload = payload
@@ -268,6 +270,7 @@ class _CoalesceItem:
         self.fut = fut
         self.t0 = t0
         self.span = span
+        self.wait = tracing.span("ec:coalesce_wait")
 
 
 class CoalescedLauncher:
@@ -358,6 +361,8 @@ class CoalescedLauncher:
         except asyncio.CancelledError:
             self.cancelled_waiters += 1
             raise
+        finally:
+            item.wait.end()
 
     async def _run_flusher(self) -> None:
         loop = self._loop
@@ -406,14 +411,14 @@ class CoalescedLauncher:
             return
         now = self._loop.time()
         for it in live:
-            wait_us = (now - it.t0) * 1e6
-            be.perf.tinc("ec_coalesce_wait_us", wait_us)
-            be.perf.hinc("ec_coalesce_wait_hist_us", wait_us)
+            it.wait.end()
+            be.perf.hinc("ec_coalesce_wait_hist_us", (now - it.t0) * 1e6)
         wall0 = time.time()
         t0 = time.perf_counter()
         try:
-            outs = await be._coalesce_launch(
-                key, [it.payload for it in live])
+            with tracing.span("osd:ec:launch"):
+                outs = await be._coalesce_launch(
+                    key, [it.payload for it in live])
         except asyncio.CancelledError:
             raise
         except BaseException as exc:
@@ -606,8 +611,7 @@ class ECBackend:
                    "ec_mesh_launches", "ec_mesh_ops",
                    "ec_mesh_ici_bytes", "ec_mesh_ici_whole_bytes"):
             self.perf.add(_k, CounterType.U64)
-        for _k in ("ec_coalesce_occupancy", "ec_coalesce_wait_us",
-                   "ec_mesh_occupancy"):
+        for _k in ("ec_coalesce_occupancy", "ec_mesh_occupancy"):
             self.perf.add(_k, CounterType.LONGRUNAVG)
         for _k in ("ec_encode_launch_us", "ec_decode_launch_us",
                    "ec_coalesce_wait_hist_us", "ec_mesh_launch_us",
@@ -766,21 +770,40 @@ class ECBackend:
             arr, (np.ndarray, bytes, bytearray, memoryview))
 
     def _to_host(self, arr) -> np.ndarray:
-        """Materialize on host, counting the transfer when it crosses."""
+        """Materialize on host, counting the transfer when it crosses
+        (on the loop thread this waits for the device result)."""
         if isinstance(arr, np.ndarray):
             return arr
-        out = np.asarray(arr)
+        with tracing.span("ec:d2h"):
+            out = np.asarray(arr)
         self.perf.inc("ec_resident_d2h_bytes", out.nbytes)
         return out
 
     def _to_device(self, arr):
         """Upload to device, counting the transfer when it crosses."""
         if not self._is_device(arr):
-            arr = np.asarray(arr, np.uint8)
-            self.perf.inc("ec_resident_h2d_bytes", arr.nbytes)
             import jax.numpy as jnp
-            return jnp.asarray(arr)
+
+            with tracing.span("ec:h2d"):
+                arr = np.asarray(arr, np.uint8)
+                dev = jnp.asarray(arr)
+            self.perf.inc("ec_resident_h2d_bytes", arr.nbytes)
+            return dev
         return arr
+
+    @staticmethod
+    async def _launch(fn, *args):
+        """Run one codec call on a worker thread (a first-time XLA
+        compile must not stall heartbeats and leases on the loop),
+        spanned there as ``ec:launch``.  A device-array call returns
+        once its work is enqueued, so on the device-resident paths the
+        span (and the launch time recorded around it) ends at the
+        enqueue, not when the device has run it."""
+        def run():
+            with tracing.span("ec:launch"):
+                return fn(*args)
+
+        return await asyncio.to_thread(run)
 
     async def _encode_batch(self, stripes) -> np.ndarray:
         """(B, k, C) -> (B, k+m, C), through the mesh plane when one is
@@ -799,22 +822,25 @@ class ECBackend:
         if self._is_device(stripes):
             in_bytes = int(getattr(stripes, "nbytes", 0))
             self.perf.inc("ec_launch_bytes", in_bytes)
-            stripes, b = pad_batch_pow2_device(stripes)
+            with tracing.span("ec:prep"):
+                stripes, b = pad_batch_pow2_device(stripes)
             if stripes.shape[0] != b:
                 self.perf.inc("ec_coalesce_pad_waste",
                               stripes.shape[0] - b)
             self.mesh_stats["encode_buckets"].add(int(stripes.shape[0]))
             self.perf.inc("ec_device_launches")
             t0 = time.perf_counter()
-            out = await asyncio.to_thread(
-                self.ec.encode_chunks_device, stripes)
+            out = await self._launch(self.ec.encode_chunks_device, stripes)
             dt_us = (time.perf_counter() - t0) * 1e6
             self.perf.hinc("ec_encode_launch_us", dt_us)
             self.profiler.record(f"{self.codec_sig}:enc", dt_us,
-                                 stripes=b, hbm_bytes=in_bytes)
-            return out[:b]
+                                 stripes=b, hbm_bytes=in_bytes,
+                                 enqueue_only=True)
+            with tracing.span("ec:prep"):
+                return out[:b]
         in_bytes = stripes.nbytes if hasattr(stripes, "nbytes") else 0
-        stripes, b = pad_batch_pow2(stripes)
+        with tracing.span("ec:prep"):
+            stripes, b = pad_batch_pow2(stripes)
         if stripes.shape[0] != b:
             self.perf.inc("ec_coalesce_pad_waste", stripes.shape[0] - b)
         self.mesh_stats["encode_buckets"].add(stripes.shape[0])
@@ -825,7 +851,7 @@ class ECBackend:
         if self.mesh is not None:
             ap = self._mesh_applier(
                 ("enc",), lambda: self._mesh_gen[self.k:])
-            parity = await asyncio.to_thread(ap, stripes)
+            parity = await self._launch(ap, stripes)
             self.mesh_stats["encodes"] += 1
             dt_us = (time.perf_counter() - t0) * 1e6
             self.perf.hinc("ec_encode_launch_us", dt_us)
@@ -835,7 +861,7 @@ class ECBackend:
                 [np.asarray(stripes, np.uint8), parity], axis=1)[:b]
             self.perf.inc("ec_resident_d2h_bytes", out.nbytes)
             return out
-        out = np.asarray(await asyncio.to_thread(
+        out = np.asarray(await self._launch(
             self.ec.encode_chunks_batch, stripes
         ))[:b]
         dt_us = (time.perf_counter() - t0) * 1e6
@@ -863,13 +889,15 @@ class ECBackend:
             bp = pow2_bucket(b)
             if bp != b:
                 self.perf.inc("ec_coalesce_pad_waste", bp - b)
-                batched = {
-                    s: np.concatenate([
-                        np.asarray(c, np.uint8),
-                        np.zeros((bp - b,) + np.shape(c)[1:], np.uint8),
-                    ], axis=0)
-                    for s, c in batched.items()
-                }
+                with tracing.span("ec:prep"):
+                    batched = {
+                        s: np.concatenate([
+                            np.asarray(c, np.uint8),
+                            np.zeros((bp - b,) + np.shape(c)[1:],
+                                     np.uint8),
+                        ], axis=0)
+                        for s, c in batched.items()
+                    }
             self.mesh_stats["decode_buckets"].add(bp)
         self.perf.inc("ec_device_launches")
         self.perf.inc("ec_launch_bytes", in_bytes)
@@ -891,7 +919,7 @@ class ECBackend:
                     ("dec", survivors, tuple(todo)), lambda: D)
                 stacked = np.stack([avail[s] for s in survivors],
                                    axis=1)
-                rebuilt = await asyncio.to_thread(ap, stacked)
+                rebuilt = await self._launch(ap, stacked)
                 for i, w in enumerate(todo):
                     out[w] = np.asarray(rebuilt[:b, i])
                     self.perf.inc("ec_resident_d2h_bytes",
@@ -902,14 +930,17 @@ class ECBackend:
             self.profiler.record(f"{self.codec_sig}:dec", dt_us,
                                  stripes=b, hbm_bytes=in_bytes)
             return out
-        out = await asyncio.to_thread(
+        out = await self._launch(
             self.ec.decode_chunks_batch, batched, missing
         )
+        # the interval runs through the host copy of the result, so it
+        # is the whole launch whatever the codec hands back
+        with tracing.span("ec:d2h"):
+            res = {w: np.asarray(c)[:b] for w, c in out.items()}
         dt_us = (time.perf_counter() - t0) * 1e6
         self.perf.hinc("ec_decode_launch_us", dt_us)
         self.profiler.record(f"{self.codec_sig}:dec", dt_us,
                              stripes=b, hbm_bytes=in_bytes)
-        res = {w: np.asarray(c)[:b] for w, c in out.items()}
         # only rebuilt chunks cross back down; available targets are
         # passed through as the same host arrays
         self.perf.inc("ec_resident_d2h_bytes", sum(
@@ -928,8 +959,9 @@ class ECBackend:
         b = next(iter(avail.values())).shape[0] if avail else 0
         if b:
             padded = {}
-            for s, c in avail.items():
-                padded[s], _ = pad_batch_pow2_device(c)
+            with tracing.span("ec:prep"):
+                for s, c in avail.items():
+                    padded[s], _ = pad_batch_pow2_device(c)
             bp = next(iter(padded.values())).shape[0]
             if bp != b:
                 self.perf.inc("ec_coalesce_pad_waste", bp - b)
@@ -945,14 +977,16 @@ class ECBackend:
         if todo:
             if len(avail) < self.k:
                 raise IOError(f"cannot decode {todo}")
-            rebuilt = await asyncio.to_thread(
+            rebuilt = await self._launch(
                 self.ec.decode_chunks_device, avail, todo)
-            for i, w in enumerate(todo):
-                out[w] = rebuilt[:b, i]
+            with tracing.span("ec:prep"):
+                for i, w in enumerate(todo):
+                    out[w] = rebuilt[:b, i]
         dt_us = (time.perf_counter() - t0) * 1e6
         self.perf.hinc("ec_decode_launch_us", dt_us)
         self.profiler.record(f"{self.codec_sig}:dec", dt_us,
-                             stripes=b, hbm_bytes=in_bytes)
+                             stripes=b, hbm_bytes=in_bytes,
+                             enqueue_only=True)
         return out
 
     # -- cross-op coalescing (CoalescedLauncher front ends) ---------------
@@ -1028,23 +1062,25 @@ class ECBackend:
                 return [await self._encode_batch(payloads[0])]
             sizes = [p.shape[0] for p in payloads]
             any_dev = any(self._is_device(p) for p in payloads)
-            if any_dev:
-                # mixed batch: host batchmates are promoted (counted
-                # uploads) so the whole launch stays on device; their
-                # slices come back down below
-                import jax.numpy as jnp
-                cat = jnp.concatenate(
-                    [self._to_device(p) for p in payloads], axis=0)
-            else:
-                cat = np.concatenate(payloads, axis=0)
+            with tracing.span("ec:prep"):
+                if any_dev:
+                    # mixed batch: host batchmates are promoted (counted
+                    # uploads) so the whole launch stays on device;
+                    # their slices come back down below
+                    import jax.numpy as jnp
+                    cat = jnp.concatenate(
+                        [self._to_device(p) for p in payloads], axis=0)
+                else:
+                    cat = np.concatenate(payloads, axis=0)
             out = await self._encode_batch(cat)
             res, off = [], 0
-            for p, sz in zip(payloads, sizes):
-                sl = out[off:off + sz]
-                if any_dev and not self._is_device(p):
-                    sl = self._to_host(sl)
-                res.append(sl)
-                off += sz
+            with tracing.span("ec:prep"):
+                for p, sz in zip(payloads, sizes):
+                    sl = out[off:off + sz]
+                    if any_dev and not self._is_device(p):
+                        sl = self._to_host(sl)
+                    res.append(sl)
+                    off += sz
             return res
         _, shards, todo = key
         if len(payloads) == 1:
@@ -1052,27 +1088,29 @@ class ECBackend:
         sizes = [next(iter(p.values())).shape[0] for p in payloads]
         any_dev = any(
             self._is_device(c) for p in payloads for c in p.values())
-        if any_dev:
-            import jax.numpy as jnp
-            cat = {
-                s: jnp.concatenate(
-                    [self._to_device(p[s]) for p in payloads], axis=0)
-                for s in shards
-            }
-        else:
-            cat = {
-                s: np.concatenate([p[s] for p in payloads], axis=0)
-                for s in shards
-            }
+        with tracing.span("ec:prep"):
+            if any_dev:
+                import jax.numpy as jnp
+                cat = {
+                    s: jnp.concatenate(
+                        [self._to_device(p[s]) for p in payloads], axis=0)
+                    for s in shards
+                }
+            else:
+                cat = {
+                    s: np.concatenate([p[s] for p in payloads], axis=0)
+                    for s in shards
+                }
         out = await self._decode_batch(cat, list(todo))
         res, off = [], 0
-        for p, sz in zip(payloads, sizes):
-            host_op = not any(self._is_device(c) for c in p.values())
-            sl = {w: c[off:off + sz] for w, c in out.items()}
-            if any_dev and host_op:
-                sl = {w: self._to_host(c) for w, c in sl.items()}
-            res.append(sl)
-            off += sz
+        with tracing.span("ec:prep"):
+            for p, sz in zip(payloads, sizes):
+                host_op = not any(self._is_device(c) for c in p.values())
+                sl = {w: c[off:off + sz] for w, c in out.items()}
+                if any_dev and host_op:
+                    sl = {w: self._to_host(c) for w, c in sl.items()}
+                res.append(sl)
+                off += sz
         return res
 
     async def _mesh_subchunk_repair(self, avail: dict,
@@ -1155,7 +1193,7 @@ class ECBackend:
         self.perf.inc("ec_launch_bytes", chunks.nbytes)
         self.perf.inc("ec_resident_h2d_bytes", chunks.nbytes)
         t0 = time.perf_counter()
-        rec = np.asarray(await asyncio.to_thread(
+        rec = np.asarray(await self._launch(
             repair, mesh, ec, chunks, lost))[:b]
         launch_us = (time.perf_counter() - t0) * 1e6
         self.perf.hinc("ec_decode_launch_us", launch_us)
@@ -1339,11 +1377,11 @@ class ECBackend:
                     meta.version if meta else None,
                 )
             else:
-                buf = np.zeros(a_len, np.uint8)
                 # RMW: read back surviving logical bytes around the
                 # write — the extent cache (ExtentCache role) serves
                 # back-to-back overwrites without re-reading + decoding
                 # k shards
+                existing = None
                 if old_size > a_start:
                     keep_len = min(old_size, a_start + a_len) - a_start
                     existing = self.extent_cache.get(oid, a_start,
@@ -1353,11 +1391,15 @@ class ECBackend:
                             oid, a_start, keep_len, old_size,
                             meta.version if meta else None,
                         )
-                    buf[:keep_len] = np.frombuffer(existing, np.uint8)
-                buf[offset - a_start: end - a_start] = np.frombuffer(
-                    bytes(data), np.uint8
-                )
-                stripes = self.sinfo.split_stripes(buf)
+                with tracing.span("ec:prep", oid=oid):
+                    buf = np.zeros(a_len, np.uint8)
+                    if existing is not None:
+                        buf[:len(existing)] = np.frombuffer(existing,
+                                                            np.uint8)
+                    buf[offset - a_start: end - a_start] = np.frombuffer(
+                        bytes(data), np.uint8
+                    )
+                    stripes = self.sinfo.split_stripes(buf)
             # device encode off the event loop: a first-time XLA
             # compile must not stall heartbeats/leases in this process
             chunks = await self._coalesced_encode(stripes)
@@ -1365,7 +1407,8 @@ class ECBackend:
             meta_attr = self._meta_attr(ECObjectMeta(new_size, new_version))
             streams = None
             if buf is None:
-                streams = self.sinfo.shard_streams(chunks)
+                with tracing.span("ec:prep", oid=oid):
+                    streams = self.sinfo.shard_streams(chunks)
                 if self.resident_writeback:
                     # shard data stays device-resident; the store gets
                     # an attrs-only commit now and the bytes on
@@ -1387,28 +1430,33 @@ class ECBackend:
                     hattrs = await self._update_hinfo(
                         oid, shard_off, shard_bytes, old_size
                     )
-                    data_bytes = [c.tobytes() for c in shard_bytes]
+                    with tracing.span("ec:prep", oid=oid):
+                        data_bytes = [c.tobytes() for c in shard_bytes]
                     write_off = shard_off
             else:
-                shard_bytes = self.sinfo.shard_bytes(chunks)
+                with tracing.span("ec:prep", oid=oid):
+                    shard_bytes = self.sinfo.shard_bytes(chunks)
                 hattrs = await self._update_hinfo(
                     oid, shard_off, shard_bytes, old_size
                 )
-                data_bytes = [c.tobytes() for c in shard_bytes]
+                with tracing.span("ec:prep", oid=oid):
+                    data_bytes = [c.tobytes() for c in shard_bytes]
                 write_off = shard_off
             entry = (self.log_hook(oid, "modify", new_version,
                                    meta.version if meta else 0, reqid)
                      if self.log_hook else None)
             try:
-                results = await asyncio.gather(*(
-                    self.shards[i].write_shard(
-                        oid, write_off, data_bytes[i],
-                        {VERSION_ATTR: meta_attr,
-                         HINFO_ATTR: hattrs[i]},
-                        log=entry,
-                    )
-                    for i in range(self.n)
-                ), return_exceptions=True)
+                with tracing.span("osd:fanout", reqid=reqid or None,
+                                  oid=oid):
+                    results = await asyncio.gather(*(
+                        self.shards[i].write_shard(
+                            oid, write_off, data_bytes[i],
+                            {VERSION_ATTR: meta_attr,
+                             HINFO_ATTR: hattrs[i]},
+                            log=entry,
+                        )
+                        for i in range(self.n)
+                    ), return_exceptions=True)
                 failed = [i for i, r in enumerate(results)
                           if isinstance(r, BaseException)]
                 await self._settle_write_failures(
@@ -1467,11 +1515,13 @@ class ECBackend:
                     host = np.zeros(a_len, np.uint8)
                     host[:keep_len] = np.frombuffer(existing, np.uint8)
                     base = self._to_device(host)
-            if base is None:
-                base = jnp.zeros(a_len, jnp.uint8)
-            flat = base.at[offset - a_start: end - a_start].set(
-                self._to_device(new))
-        return flat.reshape(-1, self.k, self.sinfo.chunk_size)
+            with tracing.span("ec:prep", oid=oid):
+                if base is None:
+                    base = jnp.zeros(a_len, jnp.uint8)
+                flat = base.at[offset - a_start: end - a_start].set(
+                    self._to_device(new))
+        with tracing.span("ec:prep", oid=oid):
+            return flat.reshape(-1, self.k, self.sinfo.chunk_size)
 
     def _resident_logical(self, oid: str, a_start: int, a_len: int,
                           keep_len: int, old_size: int, version):
@@ -1521,8 +1571,9 @@ class ECBackend:
         dirty = self.resident_writeback
         clen = int(streams.shape[1])
         old_len = self.sinfo.logical_to_next_chunk_offset(old_size)
-        for i in range(self.n):
-            seg = streams[i]
+        with tracing.span("ec:prep", oid=oid):
+            segs = [streams[i] for i in range(self.n)]
+        for i, seg in enumerate(segs):
             ent = cache.get(self.resident_ns, oid, i, count=False)
             if ent is not None and not (
                     shard_off == 0 and clen >= ent.arr.shape[0]):
@@ -1603,11 +1654,12 @@ class ECBackend:
             0, min(length, shard_size - off))
         if arr.shape[0] < off + expected:
             return None
-        seg = arr[off: off + length]
-        if seg.shape[0] < length:
-            import jax.numpy as jnp
-            seg = jnp.concatenate([
-                seg, jnp.zeros(length - seg.shape[0], jnp.uint8)])
+        with tracing.span("ec:prep", oid=oid, shard=shard):
+            seg = arr[off: off + length]
+            if seg.shape[0] < length:
+                import jax.numpy as jnp
+                seg = jnp.concatenate([
+                    seg, jnp.zeros(length - seg.shape[0], jnp.uint8)])
         return seg
 
     async def flush_resident(self) -> None:
@@ -1753,7 +1805,8 @@ class ECBackend:
         hinfo: HashInfo | None = None
         if shard_off == 0:
             hinfo = HashInfo(self.n)
-            hinfo.append(0, [b.tobytes() for b in shard_bytes])
+            with tracing.span("ec:hinfo", oid=oid):
+                hinfo.append(0, [b.tobytes() for b in shard_bytes])
         elif shard_off == self.sinfo.logical_to_next_chunk_offset(old_size):
             raw = await self._get_attr_any(oid, HINFO_ATTR)
             try:
@@ -1762,7 +1815,9 @@ class ECBackend:
             except ValueError:
                 hinfo = None
             if hinfo is not None and hinfo.total_chunk_size == shard_off:
-                hinfo.append(shard_off, [b.tobytes() for b in shard_bytes])
+                with tracing.span("ec:hinfo", oid=oid):
+                    hinfo.append(shard_off,
+                                 [b.tobytes() for b in shard_bytes])
             else:
                 hinfo = None
         blob = b"" if hinfo is None else json.dumps(hinfo.to_dict()).encode()
@@ -1883,8 +1938,11 @@ class ECBackend:
 
     async def _read_logical(self, oid: str, offset: int, length: int,
                             obj_size: int,
-                            version: int | None = None) -> bytes:
-        """Read stripe-aligned logical range, reconstructing if needed."""
+                            version: int | None = None,
+                            reqid: str = "") -> bytes:
+        """Read stripe-aligned logical range, reconstructing if needed.
+        Its ``osd:fanout`` span runs from the first shard read to the
+        last one a reconstruction fetches, before any decode."""
         if offset % self.sinfo.stripe_width:
             raise ValueError("offset must be stripe aligned")
         nstripes = -(-length // self.sinfo.stripe_width)
@@ -1893,36 +1951,44 @@ class ECBackend:
         ssize = self.sinfo.logical_to_next_chunk_offset(obj_size)
 
         want = list(self.data_shards)
-        if self.hedge_timeout:
-            chunks = await self._read_chunks_hedged(
-                oid, coff, clen, ssize, version, want
-            )
-        else:
-            results = await asyncio.gather(*(
-                self._read_shard_range(i, oid, coff, clen, ssize, version)
-                for i in want
-            ), return_exceptions=True)
-            missing = [s for s, r in zip(want, results)
-                       if isinstance(r, BaseException)]
-            if missing:
-                chunks = await self._reconstruct(
-                    oid, coff, clen, missing, results, ssize, version
+        fanout = tracing.span("osd:fanout", reqid=reqid or None, oid=oid)
+        try:
+            if self.hedge_timeout:
+                chunks = await self._read_chunks_hedged(
+                    oid, coff, clen, ssize, version, want, fanout
                 )
             else:
-                chunks = dict(zip(want, results))
+                results = await asyncio.gather(*(
+                    self._read_shard_range(i, oid, coff, clen, ssize,
+                                           version)
+                    for i in want
+                ), return_exceptions=True)
+                missing = [s for s, r in zip(want, results)
+                           if isinstance(r, BaseException)]
+                if missing:
+                    chunks = await self._reconstruct(
+                        oid, coff, clen, missing, results, ssize,
+                        version, fanout
+                    )
+                else:
+                    chunks = dict(zip(want, results))
+        finally:
+            fanout.end()
         # the Objecter/client boundary: resident chunks materialize to
         # host HERE (one counted copy of the payload), not per-launch
-        stripes = np.stack(
-            [self._to_host(chunks[i]).reshape(nstripes,
-                                              self.sinfo.chunk_size)
-             for i in self.data_shards], axis=1,
-        )
-        flat = self.sinfo.merge_stripes(stripes)
-        return flat[:length].tobytes()
+        with tracing.span("ec:prep", oid=oid):
+            stripes = np.stack(
+                [self._to_host(chunks[i]).reshape(nstripes,
+                                                  self.sinfo.chunk_size)
+                 for i in self.data_shards], axis=1,
+            )
+            flat = self.sinfo.merge_stripes(stripes)
+            return flat[:length].tobytes()
 
     async def _read_chunks_hedged(
         self, oid: str, coff: int, clen: int, ssize: int | None,
         version: int | None, want: list[int],
+        fanout=tracing.NULL_SPAN,
     ) -> dict[int, np.ndarray]:
         """Hedged shard fan-in: wait ``hedge_timeout`` for the direct
         data-shard reads; shards still pending are treated as slow and
@@ -1951,14 +2017,15 @@ class ECBackend:
         if not slow:
             if failed:
                 return await self._reconstruct(
-                    oid, coff, clen, failed, results, ssize, version)
+                    oid, coff, clen, failed, results, ssize, version,
+                    fanout)
             return {i: tasks[i].result() for i in want}
         # hedge fires: reconstruct failed+slow positions from survivors
         # while the stragglers keep running; first full answer wins
         self.perf.inc("hedge_issued")
         missing = failed + slow
         rec = asyncio.create_task(self._reconstruct(
-            oid, coff, clen, missing, results, ssize, version))
+            oid, coff, clen, missing, results, ssize, version, fanout))
         slow_all = asyncio.ensure_future(asyncio.gather(
             *(tasks[i] for i in slow), return_exceptions=True))
         pending = {rec, slow_all}
@@ -1999,16 +2066,17 @@ class ECBackend:
         if not missing2:
             return {i: r for i, r in zip(want, final)}
         return await self._reconstruct(
-            oid, coff, clen, missing2, final, ssize, version)
+            oid, coff, clen, missing2, final, ssize, version, fanout)
 
     async def _reconstruct(
         self, oid: str, coff: int, clen: int,
         missing: Sequence[int], partial, shard_size: int | None = None,
-        version: int | None = None,
+        version: int | None = None, fanout=tracing.NULL_SPAN,
     ) -> dict[int, np.ndarray]:
         """minimum_to_decode-driven repair read + batched decode.
         ``partial`` is aligned with the read path's want set (the data
-        shards, in logical order)."""
+        shards, in logical order).  The read's ``fanout`` span ends
+        once the last survivor is fetched, before the decode."""
         have = {
             s: r for s, r in zip(self.data_shards, partial)
             if not isinstance(r, BaseException)
@@ -2045,6 +2113,7 @@ class ECBackend:
                     have[s] = r
             if not newly_dead:
                 break
+        fanout.end()
         nstripes = clen // self.sinfo.chunk_size
         batched = {
             s: arr.reshape(nstripes, self.sinfo.chunk_size)
@@ -2062,7 +2131,7 @@ class ECBackend:
         return chunks
 
     async def read(self, oid: str, offset: int = 0,
-                   length: int | None = None) -> bytes:
+                   length: int | None = None, reqid: str = "") -> bytes:
         async with self._track_op():
             meta = await self._read_meta(oid)
             if meta is None:
@@ -2076,7 +2145,8 @@ class ECBackend:
                 offset, length
             )
             data = await self._read_logical(oid, a_start, a_len,
-                                            meta.size, meta.version)
+                                            meta.size, meta.version,
+                                            reqid)
             rel = offset - a_start
             return data[rel: rel + length]
 
@@ -2421,8 +2491,9 @@ class ECBackend:
         read_set = list(plan.read_set)
         span = (self.tracer.span(
             "osd:ec:repair_batch", current_span(),
-            objects=len(group), strategy=plan.strategy,
-            lost=",".join(str(s) for s in lost), shard_len=shard_len,
+            tags={"objects": len(group), "strategy": plan.strategy,
+                  "lost": ",".join(str(s) for s in lost),
+                  "shard_len": shard_len},
         ) if self.tracer is not None else contextlib.nullcontext())
         with span:
             if plan.strategy == "clay":
@@ -2576,7 +2647,7 @@ class ECBackend:
         self.perf.inc("ec_launch_bytes", stacked.nbytes)
         self.perf.inc("ec_resident_h2d_bytes", stacked.nbytes)
         t0 = time.perf_counter()
-        rec = await asyncio.to_thread(
+        rec = await self._launch(
             batched_lrc_group_repair, self.ec, plan.matrix, stacked)
         dt_us = (time.perf_counter() - t0) * 1e6
         self.perf.hinc("ec_decode_launch_us", dt_us)
@@ -2597,7 +2668,7 @@ class ECBackend:
         self.perf.inc("ec_launch_bytes", flat.nbytes)
         self.perf.inc("ec_resident_h2d_bytes", flat.nbytes)
         t0 = time.perf_counter()
-        rec = await asyncio.to_thread(
+        rec = await self._launch(
             batched_clay_plane_repair, self.ec, plan.matrix, flat)
         dt_us = (time.perf_counter() - t0) * 1e6
         self.perf.hinc("ec_decode_launch_us", dt_us)

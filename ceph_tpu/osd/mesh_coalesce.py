@@ -36,15 +36,17 @@ import weakref
 
 import numpy as np
 
-from ceph_tpu.common import events
+from ceph_tpu.common import events, tracing
 from ceph_tpu.common.tracing import current_span
 
 
 class _MeshItem:
     """One op's parked launch request, tagged with its backend (items
-    from several OSDs' backends share a flush bucket)."""
+    from several OSDs' backends share a flush bucket); ``wait`` is its
+    ``ec:coalesce_wait`` span, parked to flush."""
 
-    __slots__ = ("backend", "payload", "nstripes", "fut", "t0", "span")
+    __slots__ = ("backend", "payload", "nstripes", "fut", "t0", "span",
+                 "wait")
 
     def __init__(self, backend, payload, nstripes, fut, t0, span=None):
         self.backend = backend
@@ -53,6 +55,7 @@ class _MeshItem:
         self.fut = fut
         self.t0 = t0
         self.span = span
+        self.wait = tracing.span("ec:coalesce_wait")
 
 
 class MeshCoalescer:
@@ -248,6 +251,8 @@ class MeshCoalescer:
         except asyncio.CancelledError:
             self.cancelled_waiters += 1
             raise
+        finally:
+            item.wait.end()
 
     def _inflight_total(self) -> int:
         return sum(be._inflight_ops for be in self._backends)
@@ -294,13 +299,14 @@ class MeshCoalescer:
             return
         now = self._loop.time()
         for it in live:
-            wait_us = (now - it.t0) * 1e6
-            it.backend.perf.tinc("ec_coalesce_wait_us", wait_us)
-            it.backend.perf.hinc("ec_coalesce_wait_hist_us", wait_us)
+            it.wait.end()
+            it.backend.perf.hinc("ec_coalesce_wait_hist_us",
+                                 (now - it.t0) * 1e6)
         wall0 = time.time()
         t0 = time.perf_counter()
         try:
-            outs = await self._mesh_launch(full_key, live)
+            with tracing.span("osd:ec:mesh_launch"):
+                outs = await self._mesh_launch(full_key, live)
         except asyncio.CancelledError:
             raise
         except BaseException as exc:
@@ -348,12 +354,18 @@ class MeshCoalescer:
         if full_key[1][0] == "enc":
             hbm = sum(int(getattr(it.payload, "nbytes", 0))
                       for it in live)
+            host = any(not be0._is_device(it.payload) for it in live)
         else:
             hbm = sum(int(getattr(c, "nbytes", 0))
                       for it in live for c in it.payload.values())
+            host = any(not any(be0._is_device(c)
+                               for c in it.payload.values())
+                       for it in live)
+        # only a host batchmate's result is copied down inside the timed
+        # launch; an all-device launch returns at the enqueue
         be0.profiler.record(f"{be0.codec_sig}:{kind}", launch_us,
                             stripes=sum(it.nstripes for it in live),
-                            hbm_bytes=hbm)
+                            hbm_bytes=hbm, enqueue_only=not host)
         # the launcher is a host singleton shared across OSDs, so mesh
         # launches land in the process journal (like failpoints), not
         # an arbitrary member backend's daemon ring

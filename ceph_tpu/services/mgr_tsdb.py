@@ -108,11 +108,12 @@ class TSDBMonitor(MgrModule):
             for sig, rec in (dump.get("ec_kernels") or {}).items():
                 agg = kernels.setdefault(sig, {
                     "launches": 0, "stripes": 0, "wall_us": 0.0,
-                    "hbm_bytes": 0})
+                    "hbm_bytes": 0, "enqueue_only": 0})
                 agg["launches"] += int(rec.get("launches", 0))
                 agg["stripes"] += int(rec.get("stripes", 0))
                 agg["wall_us"] += float(rec.get("wall_us", 0.0))
                 agg["hbm_bytes"] += int(rec.get("hbm_bytes", 0))
+                agg["enqueue_only"] += int(rec.get("enqueue_only", 0))
         feed["tracer.ring_evictions"] = evictions
         feed["tracer.orphan_spans"] = orphans
         rate = 0.0
@@ -129,15 +130,17 @@ class TSDBMonitor(MgrModule):
         }
         peak = hbm_peak_gibps()
         for sig, agg in kernels.items():
+            feed[f"kernel.{sig}.wall_us"] = agg["wall_us"]
+            feed[f"kernel.{sig}.launches"] = agg["launches"]
+            feed[f"kernel.{sig}.hbm_bytes"] = agg["hbm_bytes"]
+            if agg["enqueue_only"]:
+                continue    # an enqueue time is no bandwidth: not measured
             wall_s = agg["wall_us"] / 1e6
             agg["gibps"] = round(
                 agg["hbm_bytes"] / (1 << 30) / wall_s, 3) \
                 if wall_s > 0 else 0.0
             if peak:
                 agg["roofline_pct"] = round(100.0 * agg["gibps"] / peak, 3)
-            feed[f"kernel.{sig}.wall_us"] = agg["wall_us"]
-            feed[f"kernel.{sig}.launches"] = agg["launches"]
-            feed[f"kernel.{sig}.hbm_bytes"] = agg["hbm_bytes"]
             feed[f"kernel.{sig}.gibps"] = agg["gibps"]
         self.last_kernels = kernels
 

@@ -580,7 +580,7 @@ class RGWLite:
         if not prob or random.random() >= prob:
             yield None
             return
-        with self.tracer.span(name, **tags) as ctx:
+        with self.tracer.span(name, tags=tags) as ctx:
             with use_span(ctx):
                 yield ctx
 

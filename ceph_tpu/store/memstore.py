@@ -12,8 +12,22 @@ import threading
 from dataclasses import dataclass, field
 
 from ceph_tpu.common import failpoint as fp
+from ceph_tpu.common import tracing
 from ceph_tpu.store.object_store import ObjectStore, Transaction
 from ceph_tpu.store.types import CollectionId, GHObject
+
+
+def _apply_span(txns: list[Transaction]):
+    """The ``store:apply`` span of a commit, tagged with the object
+    (and EC shard) of its first object op."""
+    if not tracing.capturing():
+        return tracing.NULL_SPAN
+    for t in txns:
+        for op in t.ops:
+            if len(op) > 2 and isinstance(op[2], GHObject):
+                return tracing.span("store:apply", oid=op[2].name,
+                                    shard=op[2].shard)
+    return tracing.span("store:apply")
 
 
 @dataclass
@@ -38,7 +52,7 @@ class MemStore(ObjectStore):
         if self.fail_next is not None:
             exc, self.fail_next = self.fail_next, None
             raise exc
-        with self._lock:
+        with self._lock, _apply_span(txns):
             self._validate(txns)  # all-or-nothing: reject before mutating
             for t in txns:
                 for op in t.ops:
@@ -203,7 +217,8 @@ class MemStore(ObjectStore):
 
     # -- reads -----------------------------------------------------------
     def read(self, cid, oid, offset=0, length=None) -> bytes:
-        with self._lock:
+        with self._lock, tracing.span("store:read", oid=oid.name,
+                                      shard=oid.shard):
             obj = self._get(cid, oid)
             if length is None:
                 return bytes(obj.data[offset:])

@@ -130,7 +130,7 @@ def test_64_concurrent_writes_share_launches():
         occ = dump["ec_coalesce_occupancy"]
         assert occ["avgcount"] == launches
         assert occ["sum"] == ops
-        assert dump["ec_coalesce_wait_us"]["avgcount"] == 64
+        assert dump["ec_coalesce_wait_hist_us"]["count"] == 64
 
     asyncio.run(run())
 

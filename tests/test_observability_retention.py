@@ -33,7 +33,7 @@ from ceph_tpu.common.slo import (
     make_target,
 )
 from ceph_tpu.common.tsdb import TSDB, agg_merge, Series
-from ceph_tpu.ec.profiler import KernelProfiler, profiler_for
+from ceph_tpu.ec.profiler import KernelProfiler, profiler_for, roofline_text
 from ceph_tpu.msg import reset_local_namespace
 from ceph_tpu.vstart import DevCluster
 
@@ -271,6 +271,20 @@ def test_kernel_profiler_totals_and_registry():
     enc = d["jaxrs-k4-m2:enc"]
     assert enc["launches"] == 2 and enc["hbm_bytes"] == 6144
     assert enc["gibps"] > 0 and enc["roofline_pct"] > 0
+    # a launch timed to its enqueue is counted, but no bandwidth is
+    # derived for its signature: the table reads "not measured"
+    prof.record("jaxrs-k4-m2:dec", 5.0, stripes=1, hbm_bytes=512,
+                enqueue_only=True)
+    prof.record("jaxrs-k4-m2:enc-dev", 7.0, stripes=2, hbm_bytes=1024,
+                enqueue_only=True)
+    assert prof.totals() == {"launches": 5, "stripes": 16,
+                             "wall_us": 187.0, "hbm_bytes": 8192}
+    d = prof.dump(peak_gibps=100.0)
+    for sig in ("jaxrs-k4-m2:dec", "jaxrs-k4-m2:enc-dev"):
+        assert d[sig]["enqueue_only"] >= 1
+        assert "gibps" not in d[sig] and "roofline_pct" not in d[sig]
+        assert roofline_text(d[sig].get("roofline_pct")) == "not measured"
+    assert d["jaxrs-k4-m2:enc"]["enqueue_only"] == 0
     prof.reset()
     assert prof.totals()["launches"] == 0
 
@@ -355,6 +369,19 @@ def test_profiler_attribution_matches_launch_counters():
             assert {"launches", "stripes", "wall_us",
                     "hbm_bytes", "gibps",
                     "roofline_pct"} <= set(rec)
+
+        # the device-resident write path times its launch to the
+        # enqueue: still attributed, byte-exact, but not a bandwidth
+        rbe = ECBackend(codec, shards, stripe_unit=128, resident=True)
+        for i in range(4):
+            await rbe.write(f"r{i}", datas[f"o{i}"])
+        rk = rbe.profiler.dump(peak_gibps=100.0)
+        enc = rk[rbe.codec_sig + ":enc"]
+        assert enc["launches"] >= 1
+        assert enc["enqueue_only"] == enc["launches"]
+        assert "gibps" not in enc and "roofline_pct" not in enc
+        assert rbe.profiler.totals()["hbm_bytes"] == \
+            rbe.perf.value("ec_launch_bytes")
 
     asyncio.run(run())
 
