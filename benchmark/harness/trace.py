@@ -5,10 +5,7 @@ and the idle gaps between them.
 The window is the benchmark's own ``TraceAnnotation`` (``WINDOW_SPAN``)
 on the host plane; device planes are those named ``/device:<kind>:<n>``
 and their op line is ``XLA Ops``.  ``summarize`` takes the ``.xplane.pb``
-path, so a small recorded trace checks the arithmetic on the CPU.  It
-also keeps the program's own spans on the host planes (names starting
-with one of ``SPAN_PREFIXES``) that overlap the window, for
-``harness/spans.py``.
+path, so a small recorded trace checks the arithmetic on the CPU.
 """
 
 from __future__ import annotations
@@ -20,8 +17,6 @@ from dataclasses import dataclass, field
 
 WINDOW_SPAN = "ceph_tpu_bench:window"
 OPS_LINE = "XLA Ops"
-# the op path's span layers (ceph_tpu/common/tracing.py)
-SPAN_PREFIXES = ("msgr:", "ec:", "store:", "osd:", "objecter:")
 
 
 @dataclass
@@ -33,10 +28,6 @@ class TraceSummary:
     events: list = field(default_factory=list)
     busy_ns: float = 0.0           # union of op intervals, mean per device
     gaps: list = field(default_factory=list)   # (start_ns, end_ns)
-    # (line, name, start_ns, end_ns) of the window span and of every
-    # program span on a host plane that overlaps the window, unclipped;
-    # ``line`` numbers the host lines (threads) of all host planes
-    host_spans: list = field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -72,19 +63,12 @@ def summarize(xplane_path: str) -> TraceSummary:
     data = jax.profiler.ProfileData.from_file(xplane_path)
     window = None
     device_lines = []
-    host = []
     for plane in data.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
-                n = len(host)
-                host.append([])
                 for ev in line.events:
                     if ev.name == WINDOW_SPAN:
                         window = (ev.start_ns, ev.start_ns + ev.duration_ns)
-                    if ev.name == WINDOW_SPAN or ev.name.startswith(
-                            SPAN_PREFIXES):
-                        host[n].append((n, ev.name, ev.start_ns,
-                                        ev.start_ns + ev.duration_ns))
         elif plane.name.startswith("/device:"):
             for line in plane.lines:
                 if line.name == OPS_LINE:
@@ -93,8 +77,6 @@ def summarize(xplane_path: str) -> TraceSummary:
         raise ValueError(f"{xplane_path}: no {WINDOW_SPAN!r} span")
     w0, w1 = window
     s = TraceSummary(window_ns=(w0, w1), devices=len(device_lines))
-    s.host_spans = [ev for line in host for ev in line
-                    if ev[3] > w0 and ev[2] < w1]
     merged_all = []
     for line in device_lines:
         spans = []

@@ -119,17 +119,16 @@ def result_line(cell: dict, bench: dict, out: dict, device: dict,
     line = {"correct": correct, "attempted": len(w.ops),
             "failed": int(wrong), "metrics": metrics, "device": device}
     if traced:
-        from harness import spans, trace
+        from harness import trace
 
         s = w.trace
         device["busy_s"] = s.busy_s
         device["window_s"] = s.window_s
         at = out["ops_in_flight"]
         offset = s.window_ns[0] / 1e9 - w.t_start
-        host = spans.GapLabeller(s)
 
         def label(a, b):
-            return at((a + b) / 2e9 - offset) + " in flight" + host(a, b)
+            return at((a + b) / 2e9 - offset) + " in flight"
 
         line["breakdown"] = trace.breakdown(s, label)
     line["checks"] = {c["name"]: {"value": c["value"],

@@ -3,7 +3,9 @@ on synthetic spans and on a small CPU trace of the write cell's harness
 path (record_span_trace.py), and the existing metrics unchanged on the
 recorded v5e trace."""
 
+import json
 import os
+import shutil
 
 import jax
 import pytest
@@ -108,7 +110,8 @@ def test_gap_label_names_layers_unspanned_and_launch_thread():
                                "launch thread 0%")
 
 
-def test_a_program_without_spans_reads_none():
+def test_a_program_without_spans_reads_none(tmp_path, monkeypatch):
+    monkeypatch.setattr(spans, "TRACE_DIR", str(tmp_path))   # no capture
     s = _summary([])
     w = _window(s, _ops(4, MIB))
     for name in NEW:
@@ -145,8 +148,29 @@ def _timeline(events, w0, w1):
     return busy
 
 
-def test_recorded_trace_readers_match_a_microsecond_timeline():
+def _recorded(path):
+    s = trace.summarize(path)
+    spans.load(s, path)
+    return s
+
+
+def test_host_spans_are_read_from_the_runs_capture(tmp_path, monkeypatch):
+    # the readers find the capture where run.py has the profiler write it
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    shutil.copy(CPU, d / "host.xplane.pb")
+    monkeypatch.setattr(spans, "TRACE_DIR", str(tmp_path))
     s = trace.summarize(CPU)
+    assert spans.host_spans(s) == _recorded(CPU).host_spans
+    assert spans.has_spans(s)
+    # a capture of another window gives no spans
+    other = TraceSummary(window_ns=(s.window_ns[0], s.window_ns[1] + 1),
+                         devices=0)
+    assert spans.host_spans(other) == []
+
+
+def test_recorded_trace_readers_match_a_microsecond_timeline():
+    s = _recorded(CPU)
     (w0, w1), loop, lines = _raw_loop()
     assert s.window_ns == (w0, w1) and spans.loop_line(s) == loop
     ops = _ops(80, 64 << 10)
@@ -186,7 +210,7 @@ def test_recorded_trace_readers_match_a_microsecond_timeline():
 
 
 def test_recorded_trace_gap_label_adds_up():
-    s = trace.summarize(CPU)
+    s = _recorded(CPU)
     w0, w1 = s.window_ns
     text = spans.GapLabeller(s)(w0, w1)
     assert text.startswith("; loop: ") and "unspanned" in text
@@ -211,7 +235,7 @@ PARENT = {       # read by the parent commit's harness on this window
 
 
 def test_existing_metrics_read_as_at_the_parent():
-    s = trace.summarize(V5E)
+    s = _recorded(V5E)
     ops = []
     for i in range(3):
         for kind in ("write_full", "read"):
@@ -240,3 +264,12 @@ def test_existing_metrics_read_as_at_the_parent():
     assert [n for _, n, _, _ in s.host_spans] == [WINDOW_SPAN]
     for name in NEW:
         assert spec.reader(name)(w) is None
+
+
+def test_gap_listing_prints_the_ten_longest_gaps(capsys):
+    assert spans.main([V5E]) == 0
+    rows = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    s = trace.summarize(V5E)
+    want = trace.breakdown(s, lambda a, b: "")["idle_gaps"]
+    # no program spans in that recording: each gap is listed, unlabelled
+    assert rows == want and len(rows) == 10
