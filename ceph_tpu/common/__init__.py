@@ -7,8 +7,9 @@
   (reference src/common/perf_counters.h:154, src/perf_histogram.h).
 - ``log``     — per-subsystem leveled logging with an in-memory ring buffer
   dumped on crash (reference src/common/dout.h:122-176, src/log/Log.cc).
-- ``crc32c``  — Castagnoli CRC32 (native C via ctypes when built,
-  pure-Python table fallback) for ECUtil HashInfo parity
+- ``crc32c``  — Castagnoli CRC32 (native C via ctypes: hardware crc32
+  with a slice-by-8 fallback, picked from the CPU's feature bits) for
+  ECUtil HashInfo parity
   (reference src/common/crc32c.h).
 """
 

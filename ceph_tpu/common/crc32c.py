@@ -1,9 +1,12 @@
-"""crc32c (Castagnoli) with native C fast path.
+"""crc32c (Castagnoli) in native C: hardware crc32 with a slice-by-8
+fallback, picked from the CPU's feature bits.
 
 Loads ceph_tpu/native/libceph_tpu_native.so via ctypes, built with make
-on first use from the committed sources (_SOURCES).  A build that fails
-raises; the pure-Python table loop is the reference the tests compare
-against. Semantics match ceph_crc32c(seed, buf, len)
+on first use from the committed sources (_SOURCES).  The library picks
+its path once, when it loads (SSE4.2 or ARMv8 CRC instructions where
+the CPU reports them, else the table); ``impl()`` names the pick.  A
+build that fails raises; the pure-Python table loop is the reference
+the tests compare against. Semantics match ceph_crc32c(seed, buf, len)
 (reference src/common/crc32c.h): callers chain seeds; ECUtil HashInfo uses
 the previous cumulative crc as the seed for each appended shard extent.
 """
@@ -61,12 +64,19 @@ def _load_native():
     if _stale():
         _build()
     lib = ctypes.CDLL(str(_SO))
-    lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
-    lib.ceph_tpu_crc32c.argtypes = (
-        ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t,
-    )
+    for fn in (lib.ceph_tpu_crc32c, lib.ceph_tpu_crc32c_table):
+        fn.restype = ctypes.c_uint32
+        fn.argtypes = (ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t)
+    lib.ceph_tpu_crc32c_impl.restype = ctypes.c_char_p
+    lib.ceph_tpu_crc32c_impl.argtypes = ()
     _native = lib
     return _native
+
+
+def impl() -> str:
+    """The path ``crc32c`` runs in this process: "sse4.2", "armv8-crc"
+    or "table" (chosen once, from the CPU's feature bits)."""
+    return _load_native().ceph_tpu_crc32c_impl().decode()
 
 
 _TABLE = None
@@ -85,6 +95,16 @@ def _table():
     return _TABLE
 
 
+def _py_crc32c(crc: int, data) -> int:
+    """The pure-Python table loop: the reference the native paths are
+    tested against."""
+    tbl = _table()
+    c = (~crc) & 0xFFFFFFFF
+    for b in data:
+        c = tbl[(c ^ b) & 0xFF] ^ (c >> 8)
+    return (~c) & 0xFFFFFFFF
+
+
 def crc32c(crc: int, data: bytes | bytearray | memoryview) -> int:
     """Castagnoli CRC over ``data`` seeded with ``crc``."""
     if not isinstance(data, bytes):
@@ -92,8 +112,4 @@ def crc32c(crc: int, data: bytes | bytearray | memoryview) -> int:
     lib = _load_native()
     if lib:
         return int(lib.ceph_tpu_crc32c(crc & 0xFFFFFFFF, data, len(data)))
-    tbl = _table()
-    c = (~crc) & 0xFFFFFFFF
-    for b in data:
-        c = tbl[(c ^ b) & 0xFF] ^ (c >> 8)
-    return (~c) & 0xFFFFFFFF
+    return _py_crc32c(crc, data)

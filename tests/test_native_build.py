@@ -13,20 +13,10 @@ import pathlib
 import shutil
 import subprocess
 
-from ceph_tpu.common.crc32c import _SO, _load_native, _table
+from ceph_tpu.common.crc32c import _SO, _load_native, _py_crc32c
 
 NATIVE = pathlib.Path(__file__).resolve().parents[1] / "ceph_tpu" / \
     "native"
-
-
-def _py_crc(crc, data):
-    # the ceph_crc32c semantics of crc32c.py's fallback: invert the
-    # chained seed in and the result out
-    tbl = _table()
-    c = (~crc) & 0xFFFFFFFF
-    for b in data:
-        c = tbl[(c ^ b) & 0xFF] ^ (c >> 8)
-    return (~c) & 0xFFFFFFFF
 
 
 def test_so_builds_from_source_and_matches_python(tmp_path):
@@ -44,7 +34,7 @@ def test_so_builds_from_source_and_matches_python(tmp_path):
     for seed in (0, 0xFFFFFFFF, 0x1234):
         for body in (b"", b"a", b"hello ceph" * 999):
             assert lib.ceph_tpu_crc32c(seed, body, len(body)) == \
-                _py_crc(seed, body)
+                _py_crc32c(seed, body)
 
 
 def test_runtime_loader_built_the_in_tree_so():
@@ -54,6 +44,7 @@ def test_runtime_loader_built_the_in_tree_so():
     lib = _load_native()
     assert lib, "native library failed to build from source"
     assert _SO.exists()
-    for sym in ("ceph_tpu_crc32c", "we_open", "we_append",
+    for sym in ("ceph_tpu_crc32c", "ceph_tpu_crc32c_table",
+                "ceph_tpu_crc32c_impl", "we_open", "we_append",
                 "we_replay", "we_close"):
         assert hasattr(lib, sym), f"missing symbol {sym}"
