@@ -25,8 +25,8 @@ event loop's thread it is what the loop was doing: ``msgr:encode``,
 ``msgr:frame_out``, ``msgr:frame_in``, ``ec:prep``, ``ec:h2d``,
 ``ec:d2h``, ``ec:hinfo``, ``store:apply``, ``store:read``.  A *wait*
 span encloses awaits:
-``osd:queue``, ``osd:fanout``, ``ec:coalesce_wait``, and ``ec:launch``
-on the worker thread that runs the codec call.
+``osd:queue``, ``osd:obj_wait``, ``osd:fanout``, ``ec:coalesce_wait``,
+and ``ec:launch`` on the worker thread that runs the codec call.
 
 With no capture running and the op not sampled, a span site costs one
 flag check and returns the shared no-op ``NULL_SPAN``: no dict, no id,
@@ -210,12 +210,13 @@ class _Span:
             self._tracer._append(rec)
 
 
-def span(name: str, *, reqid=None, oid=None, shard=None):
+def span(name: str, *, reqid=None, oid=None, shard=None, tags=None):
     """A layer span into the running capture (no ring: layer spans are
-    for the profiler's timeline); ``NULL_SPAN`` when none runs."""
+    for the profiler's timeline); ``NULL_SPAN`` when none runs.  Build
+    ``tags`` only where ``capturing()`` said a capture runs."""
     if not capturing():
         return NULL_SPAN
-    return _note(name, reqid, oid, shard, None)
+    return _note(name, reqid, oid, shard, tags)
 
 
 class Tracer:

@@ -72,6 +72,7 @@ from ceph_tpu.osd.codes import (
 )
 from ceph_tpu.osd.osd_map import NO_OSD, OSDMap
 from ceph_tpu.osd import pg_log, snaps
+from ceph_tpu.osd.object_state import READ, WRITE
 from ceph_tpu.osd.op_tracker import OpTracker
 from ceph_tpu.osd.scheduler import MClockScheduler
 from ceph_tpu.osd.pg import (
@@ -117,6 +118,8 @@ XATTR_PREFIX = "_u_"          # user xattrs, kept clear of internal attrs
 # read-class client ops (no mutation): ONE definition for the dedup
 # cache policy, the replay path, perf counters, and caps enforcement
 _CAPS_READ_OPS = READ_CLASS_OPS
+# an EC op vector of only these takes its object's state as a reader
+_EC_READER_OPS = frozenset({"read", "stat", "getxattr", "getxattrs"})
 # space-reclaiming ops stay allowed on a FULL_QUOTA pool: blocking
 # deletes would make a full pool unrecoverable (the reference exempts
 # delete-class ops the same way).  Ops carrying the "full_try" wire
@@ -3915,13 +3918,15 @@ class OSDDaemon:
                     # the log entry names the version to converge to —
                     # a rewound object's stale shards still advertise
                     # the dropped (higher) version in their attrs, so
-                    # the internal max-version guess would be wrong
-                    nbytes = await pg.backend.recover_shard(
-                        name, shards,
-                        version=target_version.get(name) or None,
-                        stray_read=stray_read if stray_pos else None,
-                        stray_positions=sorted(stray_pos),
-                    )
+                    # the internal max-version guess would be wrong;
+                    # a writer of the object, like a client write
+                    async with pg.backend.object_lock(name):
+                        nbytes = await pg.backend.recover_shard(
+                            name, shards,
+                            version=target_version.get(name) or None,
+                            stray_read=stray_read if stray_pos else None,
+                            stray_positions=sorted(stray_pos),
+                        )
                     self.perf.inc("recovery_ops")
                     if clazz == "backfill" and nbytes:
                         self.perf.inc("backfill_bytes", int(nbytes))
@@ -4611,99 +4616,104 @@ class OSDDaemon:
                           "remove", "create", "setxattr")
         last_mut = max((i for i, op in enumerate(ops)
                         if op.get("op") in mutating_kinds), default=-1)
+        mode = READ if all(op.get("op") in _EC_READER_OPS
+                           for op in ops) else WRITE
         try:
-            for opi, op in enumerate(ops):
-                kind = op["op"]
-                reqid = batch_reqid if opi == last_mut else ""
-                if kind == "write":
-                    meta = await be.write(oid, op["data"],
-                                          int(op.get("off", 0)),
-                                          reqid=reqid)
-                    version = meta.version
-                    results.append({})
-                elif kind == "writefull":
-                    old = await be._read_meta(oid)
-                    if old is not None and old.size > len(op["data"]):
-                        await be.remove(oid, reqid=reqid)
-                    meta = await be.write(oid, op["data"], 0,
-                                          reqid=reqid)
-                    version = meta.version
-                    results.append({})
-                elif kind == "append":
-                    meta = await be._read_meta(oid)
-                    off = meta.size if meta else 0
-                    meta = await be.write(oid, op["data"], off,
-                                          reqid=reqid)
-                    version = meta.version
-                    results.append({})
-                elif kind == "truncate":
-                    # overwrite-capable EC pools support truncate; shrink
-                    # is read-back + rewrite (stripe bounds change)
-                    nsize = int(op["size"])
-                    meta = await be._read_meta(oid)
-                    cur = meta.size if meta else 0
-                    if nsize < cur:
-                        keep = await be.read(oid, 0, nsize)
-                        await be.remove(oid)
-                        meta = await be.write(oid, keep, 0,
+            # one state per op vector: writefull's remove + write and
+            # truncate's read-back + rewrite are atomic to readers
+            async with be.object_lock(oid, mode, batch_reqid or None):
+                for opi, op in enumerate(ops):
+                    kind = op["op"]
+                    reqid = batch_reqid if opi == last_mut else ""
+                    if kind == "write":
+                        meta = await be.write(oid, op["data"],
+                                              int(op.get("off", 0)),
                                               reqid=reqid)
-                    elif nsize > cur:
-                        meta = await be.write(
-                            oid, b"\0" * (nsize - cur), cur,
-                            reqid=reqid,
+                        version = meta.version
+                        results.append({})
+                    elif kind == "writefull":
+                        old = await be._read_meta(oid)
+                        if old is not None and old.size > len(op["data"]):
+                            await be.remove(oid, reqid=reqid)
+                        meta = await be.write(oid, op["data"], 0,
+                                              reqid=reqid)
+                        version = meta.version
+                        results.append({})
+                    elif kind == "append":
+                        meta = await be._read_meta(oid)
+                        off = meta.size if meta else 0
+                        meta = await be.write(oid, op["data"], off,
+                                              reqid=reqid)
+                        version = meta.version
+                        results.append({})
+                    elif kind == "truncate":
+                        # overwrite-capable EC pools support truncate; shrink
+                        # is read-back + rewrite (stripe bounds change)
+                        nsize = int(op["size"])
+                        meta = await be._read_meta(oid)
+                        cur = meta.size if meta else 0
+                        if nsize < cur:
+                            keep = await be.read(oid, 0, nsize)
+                            await be.remove(oid)
+                            meta = await be.write(oid, keep, 0,
+                                                  reqid=reqid)
+                        elif nsize > cur:
+                            meta = await be.write(
+                                oid, b"\0" * (nsize - cur), cur,
+                                reqid=reqid,
+                            )
+                        elif meta is None:
+                            meta = await be.write(oid, b"", 0, reqid=reqid)
+                        version = meta.version
+                        results.append({})
+                    elif kind == "read":
+                        data = await be.read(oid, int(op.get("off", 0)),
+                                             op.get("len"), batch_reqid)
+                        results.append({"data": data})
+                    elif kind == "stat":
+                        meta = await be._read_meta(oid)
+                        if meta is None:
+                            return ENOENT_RC, results, 0
+                        results.append({"size": meta.size,
+                                        "version": meta.version})
+                    elif kind == "remove":
+                        meta = await be._read_meta(oid)
+                        if meta is None:
+                            return ENOENT_RC, results, 0
+                        await be.remove(oid, reqid=reqid)
+                        results.append({})
+                    elif kind == "create":
+                        meta = await be._read_meta(oid)
+                        if meta is None:
+                            meta = await be.write(oid, b"", 0, reqid=reqid)
+                        version = meta.version
+                        results.append({})
+                    elif kind == "setxattr":
+                        await be.set_attr(oid, XATTR_PREFIX + op["name"],
+                                          op["value"], reqid=reqid)
+                        results.append({})
+                    elif kind == "getxattr":
+                        raw = await be._get_attr_any(
+                            oid, XATTR_PREFIX + op["name"]
                         )
-                    elif meta is None:
-                        meta = await be.write(oid, b"", 0, reqid=reqid)
-                    version = meta.version
-                    results.append({})
-                elif kind == "read":
-                    data = await be.read(oid, int(op.get("off", 0)),
-                                         op.get("len"), batch_reqid)
-                    results.append({"data": data})
-                elif kind == "stat":
-                    meta = await be._read_meta(oid)
-                    if meta is None:
-                        return ENOENT_RC, results, 0
-                    results.append({"size": meta.size,
-                                    "version": meta.version})
-                elif kind == "remove":
-                    meta = await be._read_meta(oid)
-                    if meta is None:
-                        return ENOENT_RC, results, 0
-                    await be.remove(oid, reqid=reqid)
-                    results.append({})
-                elif kind == "create":
-                    meta = await be._read_meta(oid)
-                    if meta is None:
-                        meta = await be.write(oid, b"", 0, reqid=reqid)
-                    version = meta.version
-                    results.append({})
-                elif kind == "setxattr":
-                    await be.set_attr(oid, XATTR_PREFIX + op["name"],
-                                      op["value"], reqid=reqid)
-                    results.append({})
-                elif kind == "getxattr":
-                    raw = await be._get_attr_any(
-                        oid, XATTR_PREFIX + op["name"]
-                    )
-                    if raw is None:
-                        return ENOENT_RC, results, 0
-                    results.append({"value": raw})
-                elif kind == "getxattrs":
-                    if await be._read_meta(oid) is None:
-                        return ENOENT_RC, results, 0
-                    attrs = await be.get_attrs(oid)
-                    results.append({"attrs": {
-                        k[len(XATTR_PREFIX):]: v
-                        for k, v in attrs.items()
-                        if k.startswith(XATTR_PREFIX)
-                    }})
-                elif kind.startswith("omap_") or kind == "call":
-                    # parity with the reference: EC pools support neither
-                    # omap nor (here) object classes, which depend on it
-                    return ENOTSUP_RC, results, 0
-                else:
-                    return EINVAL_RC, results, 0
+                        if raw is None:
+                            return ENOENT_RC, results, 0
+                        results.append({"value": raw})
+                    elif kind == "getxattrs":
+                        if await be._read_meta(oid) is None:
+                            return ENOENT_RC, results, 0
+                        attrs = await be.get_attrs(oid)
+                        results.append({"attrs": {
+                            k[len(XATTR_PREFIX):]: v
+                            for k, v in attrs.items()
+                            if k.startswith(XATTR_PREFIX)
+                        }})
+                    elif kind.startswith("omap_") or kind == "call":
+                        # parity with the reference: EC pools support neither
+                        # omap nor (here) object classes, which depend on it
+                        return ENOTSUP_RC, results, 0
+                    else:
+                        return EINVAL_RC, results, 0
         except KeyError:
             return ENOENT_RC, results, 0
         except ECWriteDegraded as e:

@@ -28,6 +28,7 @@ over local stores (tests, single host) or network shards (OSD daemons).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import time
 from dataclasses import dataclass
@@ -42,6 +43,7 @@ from ceph_tpu.common.perf import CounterType, PerfCounters
 from ceph_tpu.common.tracing import current_span
 from ceph_tpu.ec import checksum as ec_checksum
 from ceph_tpu.osd.ec_util import HashInfo, StripeInfo
+from ceph_tpu.osd.object_state import READ, WRITE, ObjectStates
 from ceph_tpu.osd.repair import (RepairPlan, minimum_to_decode_cached,
                                  plan_repair, register_repair_counters)
 from ceph_tpu.osd.scrub import register_scrub_counters
@@ -550,7 +552,6 @@ class ECBackend:
         self.shards = dict(shards)
         if set(self.shards) != set(range(self.n)):
             raise ValueError(f"need shards 0..{self.n - 1}")
-        self._object_locks: dict[str, tuple[asyncio.Lock, int]] = {}
         self._repair_tasks: set[asyncio.Task] = set()
         self.extent_cache = ExtentCache()
         # oid -> shards known stale from a failed mutation: a subsequent
@@ -584,6 +585,8 @@ class ECBackend:
         # reconstruction from the surviving shards (None/0 = off)
         self.hedge_timeout = hedge_timeout or None
         self.perf = perf if perf is not None else PerfCounters("ec")
+        # per-object reader/writer state: the one lock table
+        self._objects = ObjectStates(self.perf)
         # kernel profiler (ec/profiler.py): every device launch below
         # attributes its wall time / stripes / bytes to this backend's
         # codec signature, recorded at the SAME sites with the SAME
@@ -675,46 +678,13 @@ class ECBackend:
             self.mesh_co = mesh_coalescer
             self._mesh_dec_ok = mesh_coalescer.supports_decode(self)
 
-    def _lock(self, oid: str):
-        """Per-object write lock, refcounted so the table doesn't grow
-        with every object name ever written."""
-        backend = self
-
-        class _Guard:
-            @staticmethod
-            def _unref():
-                lock, refs = backend._object_locks[oid]
-                if refs <= 1:
-                    del backend._object_locks[oid]
-                else:
-                    backend._object_locks[oid] = (lock, refs - 1)
-
-            async def __aenter__(self):
-                lock, refs = backend._object_locks.get(
-                    oid, (asyncio.Lock(), 0)
-                )
-                backend._object_locks[oid] = (lock, refs + 1)
-                self._lock_obj = lock
-                try:
-                    await lock.acquire()
-                except BaseException:
-                    # cancelled while waiting: drop the refcount or the
-                    # table entry leaks forever
-                    self._unref()
-                    raise
-                return lock
-
-            async def __aexit__(self, *exc):
-                self._lock_obj.release()
-                self._unref()
-                return False
-
-        return _Guard()
-
-    def object_lock(self, oid: str):
-        """Public per-object write-serialization guard (scrub and other
-        external coordinators serialize against mutations with this)."""
-        return self._lock(oid)
+    def object_lock(self, oid: str, mode: str = WRITE, reqid=None):
+        """The object's reader/writer state (``osd/object_state.py``):
+        ``"r"`` shared, ``"w"`` alone, FIFO.  Op vectors take it once
+        (``OSD._do_ops_ec``); writes, removes, scrub, recovery and
+        backfill take it as writers, reads as readers; a call under a
+        state its task already holds re-enters it."""
+        return self._objects.lock(oid, mode, reqid)
 
     # -- codec dispatch (single-device vs distributed mesh plane) ---------
     _MESH_APPLIER_CAP = 64
@@ -1348,7 +1318,7 @@ class ECBackend:
                     version: int | None = None,
                     reqid: str = "") -> ECObjectMeta:
         """Write ``data`` at logical ``offset`` (stripe-granular RMW)."""
-        async with self._track_op(), self._lock(oid):
+        async with self.object_lock(oid), self._track_op():
             await self._heal_dirty(oid)
             # capture the cache generation BEFORE the RMW read/encode:
             # if a concurrent invalidate() lands while our (possibly
@@ -1787,7 +1757,7 @@ class ECBackend:
         """Settle a prior attempt's shard gaps (used by the daemon when
         a client replays a not-yet-acked op): True when the object has
         no dirty shards left."""
-        async with self._lock(oid):
+        async with self.object_lock(oid):
             try:
                 await self._heal_dirty(oid)
             except ShardReadError:
@@ -2132,7 +2102,9 @@ class ECBackend:
 
     async def read(self, oid: str, offset: int = 0,
                    length: int | None = None, reqid: str = "") -> bytes:
-        async with self._track_op():
+        # as a reader: the version read and the shards read at it are
+        # never half replaced by a write
+        async with self.object_lock(oid, READ), self._track_op():
             meta = await self._read_meta(oid)
             if meta is None:
                 raise KeyError(f"no such object {oid}")
@@ -2155,7 +2127,7 @@ class ECBackend:
         """Remove every shard object. A shard that lacks it is fine; IO
         failures beyond m mean the removal did not take and must raise
         (a silently-surviving shard would resurrect the object)."""
-        async with self._lock(oid):
+        async with self.object_lock(oid):
             # invalidate INSIDE the object lock: outside it, a write
             # already past its gather could note_write AFTER this
             # invalidate and resurrect pre-delete bytes in the cache
@@ -2196,7 +2168,7 @@ class ECBackend:
         per-object version is bumped and rewritten with the attr so a
         shard that missed the write is distinguishable from a current
         one (stale-version detection, like the degraded data path)."""
-        async with self._lock(oid):
+        async with self.object_lock(oid):
             await self._heal_dirty(oid)
             meta = await self._read_meta(oid)
             new_meta = ECObjectMeta(
@@ -2440,9 +2412,13 @@ class ECBackend:
             {"recovered": [names...], "strategy": "rs|lrc|clay",
              "batches": <decode launches issued>}
         """
-        async with self._track_op():
-            return await self._recover_batch_impl(
-                list(names), list(lost), dict(versions or {}))
+        async with contextlib.AsyncExitStack() as held:
+            # a writer of every object in the batch, taken in name order
+            for oid in sorted(set(names)):
+                await held.enter_async_context(self.object_lock(oid))
+            async with self._track_op():
+                return await self._recover_batch_impl(
+                    list(names), list(lost), dict(versions or {}))
 
     async def _recover_batch_impl(self, names: list, lost: list,
                                   versions: dict) -> dict:

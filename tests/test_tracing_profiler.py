@@ -4,7 +4,8 @@ With no capture running and the op unsampled a span site records and
 allocates nothing; under a capture every span lands on the line of the
 thread that ran it, inside the annotations around it, on the capture's
 clock; and a small EC cluster's write and degraded read leave every
-layer span of the op path, op-level ones carrying the client reqid.
+layer span of the op path, op-level ones carrying the client reqid; an
+object's reader/writer state emits one osd:obj_wait span per acquisition.
 """
 
 import asyncio
@@ -225,3 +226,44 @@ def test_ec_write_and_degraded_read_emit_every_layer_span(tmp_path):
         assert any(li == window_line for li, _ in names[n]), n
     launch_lines = {li for li, _ in names["ec:launch"]}
     assert window_line not in launch_lines
+
+
+def test_contended_object_state_emits_one_wait_span(tmp_path):
+    from ceph_tpu.common.perf import PerfCounters
+    from ceph_tpu.osd.object_state import ObjectStates
+
+    perf = PerfCounters("osd.0")
+    table = ObjectStates(perf)
+
+    async def run():
+        gate = asyncio.Event()
+
+        async def hold():
+            async with table.lock("obj", "w", reqid="c.1:1"):
+                await gate.wait()
+
+        async def want():
+            async with table.lock("obj", "r", reqid="c.1:2"):
+                pass
+
+        holder = asyncio.ensure_future(hold())
+        await asyncio.sleep(0)
+        waiter = asyncio.ensure_future(want())
+        await asyncio.sleep(0.02)
+        gate.set()
+        await asyncio.gather(holder, waiter)
+
+    events = _capture(tmp_path, lambda: asyncio.run(run()))
+    spans = {st["reqid"]: (b - a, st) for _, n, a, b, st in events
+             if n == "osd:obj_wait"}
+    assert set(spans) == {"c.1:1", "c.1:2"}
+    free, free_st = spans["c.1:1"]
+    waited, waited_st = spans["c.1:2"]
+    assert free_st == {"reqid": "c.1:1", "oid": "obj", "mode": "w",
+                       "waited": 0}
+    assert waited_st == {"reqid": "c.1:2", "oid": "obj", "mode": "r",
+                         "waited": 1}
+    # the contended one runs from its request to its grant, ~20 ms
+    assert waited >= 0.02 * 1e9 * 0.9 > free
+    assert perf.value("obj_rw_acquires") == 2
+    assert perf.value("obj_rw_waits") == 1
