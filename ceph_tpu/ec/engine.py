@@ -152,6 +152,20 @@ def pad_batch_to(arr, target: int):
     return jnp.concatenate([arr, pad], axis=0)
 
 
+@functools.partial(jax.jit, static_argnums=(2,))
+def split_shard_streams(chunks: jax.Array, off, b: int):
+    """Stripes [off, off + b) of an encoded (Bp, n, C) device batch ->
+    their (n, b*C) per-shard byte streams and the tuple of the n
+    per-shard (b*C,) rows, as ONE program (no eager slice per shard).
+    ``off`` is traced, so the batchmates of one coalesced launch that
+    share a size share a program; jit's cache keys the rest (b, Bp, n,
+    C)."""
+    _, n, c = chunks.shape
+    rows = jax.lax.dynamic_slice_in_dim(chunks, off, b)
+    streams = rows.transpose(1, 0, 2).reshape(n, b * c)
+    return streams, tuple(streams[i] for i in range(n))
+
+
 def _default_use_pallas() -> bool:
     """Fused Pallas kernel on a TPU; XLA einsum elsewhere (CPU tests,
     interpret-mode covers the Pallas math there)."""

@@ -54,6 +54,18 @@ from ceph_tpu.store.device_cache import (DeviceShardCache,
 HINFO_ATTR = "hinfo"
 VERSION_ATTR = "version"
 
+# ``site`` tags of the ec:prep spans: which host-side step of the EC
+# path the span times (the span name stays ec:prep for its readers)
+_SITE = {s: {"site": s} for s in (
+    "stripes", "split", "tobytes", "pad", "trim", "concat", "merge",
+    "resident_read")}
+
+# (Bp, n, C, b) shapes the write path's split program
+# (engine.split_shard_streams) was called at in this process: jit's own
+# cache keeps each compiled program, so the first call at a shape is
+# its one compile (counted in ec_write_glue_compiles)
+_SPLIT_SHAPES: set = set()
+
 
 class ShardIO(Protocol):
     """One shard's IO endpoint (local store or remote OSD). ``log`` on
@@ -606,13 +618,20 @@ class ECBackend:
         # numerator of achieved-GiB/s: ec_launch_bytes delta over
         # encode+decode launch-us delta — the utilization telemetry's
         # HBM-roofline-% input)
+        # ec_write_glue_*: resident writes whose split ran as one
+        # jitted program, and the split programs compiled
+        # (_note_split_shapes);
+        # ec_rmw_gather_skipped: writes whose new bytes covered every
+        # surviving byte of their stripes, so nothing was read back
         for _k in ("hedge_issued", "hedge_won", "hedge_lost",
                    "hedge_meta",
                    "ec_coalesce_launches", "ec_coalesce_ops",
                    "ec_coalesce_pad_waste", "ec_device_launches",
                    "ec_launch_bytes",
                    "ec_mesh_launches", "ec_mesh_ops",
-                   "ec_mesh_ici_bytes", "ec_mesh_ici_whole_bytes"):
+                   "ec_mesh_ici_bytes", "ec_mesh_ici_whole_bytes",
+                   "ec_write_glue_fused", "ec_rmw_gather_skipped",
+                   "ec_write_glue_compiles"):
             self.perf.add(_k, CounterType.U64)
         for _k in ("ec_coalesce_occupancy", "ec_mesh_occupancy"):
             self.perf.add(_k, CounterType.LONGRUNAVG)
@@ -722,6 +741,15 @@ class ECBackend:
             self._mesh_appliers[key] = ap
         return ap
 
+    def _note_split_shapes(self, bp: int, sizes) -> None:
+        """Count the split programs a launch of padded batch ``bp``
+        with batchmates of ``sizes`` stripes compiles (_SPLIT_SHAPES)."""
+        for sz in set(sizes):
+            key = (bp, self.n, self.sinfo.chunk_size, sz)
+            if key not in _SPLIT_SHAPES:
+                _SPLIT_SHAPES.add(key)
+                self.perf.inc("ec_write_glue_compiles")
+
     # -- host<->device boundary ------------------------------------------
     #
     # Both data-path flavors account the logical bytes that cross the
@@ -779,37 +807,21 @@ class ECBackend:
         """(B, k, C) -> (B, k+m, C), through the mesh plane when one is
         configured (parity = sharded generator apply; data rows pass
         through, so the result is bit-identical to the codec path).
-        A device-resident batch (jax array in) encodes through the
-        codec's device entry point and stays on device.
+        A device-resident batch (jax array in) encodes as the write
+        path's launch (_encode_write) and stays on device.
 
         The batch dim is shape-bucketed: B pads up to a power of two
         (zero stripes; rows are independent, result sliced back) so the
         program/applier cache holds at most ceil(log2(max B)) + 1
         distinct encode shapes per codec instead of one per stripe
         count."""
-        from ceph_tpu.ec.engine import pad_batch_pow2, pad_batch_pow2_device
+        from ceph_tpu.ec.engine import pad_batch_pow2
 
         if self._is_device(stripes):
-            in_bytes = int(getattr(stripes, "nbytes", 0))
-            self.perf.inc("ec_launch_bytes", in_bytes)
-            with tracing.span("ec:prep"):
-                stripes, b = pad_batch_pow2_device(stripes)
-            if stripes.shape[0] != b:
-                self.perf.inc("ec_coalesce_pad_waste",
-                              stripes.shape[0] - b)
-            self.mesh_stats["encode_buckets"].add(int(stripes.shape[0]))
-            self.perf.inc("ec_device_launches")
-            t0 = time.perf_counter()
-            out = await self._launch(self.ec.encode_chunks_device, stripes)
-            dt_us = (time.perf_counter() - t0) * 1e6
-            self.perf.hinc("ec_encode_launch_us", dt_us)
-            self.profiler.record(f"{self.codec_sig}:enc", dt_us,
-                                 stripes=b, hbm_bytes=in_bytes,
-                                 enqueue_only=True)
-            with tracing.span("ec:prep"):
-                return out[:b]
+            streams, _ = (await self._encode_write([stripes]))[0]
+            return self.sinfo.stream_chunks(streams)
         in_bytes = stripes.nbytes if hasattr(stripes, "nbytes") else 0
-        with tracing.span("ec:prep"):
+        with tracing.span("ec:prep", tags=_SITE["pad"]):
             stripes, b = pad_batch_pow2(stripes)
         if stripes.shape[0] != b:
             self.perf.inc("ec_coalesce_pad_waste", stripes.shape[0] - b)
@@ -841,6 +853,52 @@ class ECBackend:
         self.perf.inc("ec_resident_d2h_bytes", out.nbytes)
         return out
 
+    async def _encode_write(self, payloads: list) -> list:
+        """The device encode: one launch for the (b_j, k, C) stripe
+        batches of ``payloads`` (host ones are uploaded, counted),
+        returning for each its (n, b_j*C) shard-stream batch and the
+        tuple of its n per-shard (b_j*C,) device arrays.
+
+        All of it runs on the launch thread: the batchmates'
+        concatenation, the pow2 zero padding (B bucketed as in
+        _encode_batch), the codec's device encode, then ONE jitted
+        split per batchmate (engine.split_shard_streams) — so a
+        first-time compile of any of it never stalls the loop."""
+        import jax.numpy as jnp
+
+        from ceph_tpu.ec.engine import (pad_batch_to, pow2_bucket,
+                                        split_shard_streams)
+
+        payloads = [self._to_device(p) for p in payloads]
+        sizes = [int(p.shape[0]) for p in payloads]
+        b = sum(sizes)
+        bp = pow2_bucket(b)
+        in_bytes = sum(int(p.nbytes) for p in payloads)
+        self.perf.inc("ec_launch_bytes", in_bytes)
+        if bp != b:
+            self.perf.inc("ec_coalesce_pad_waste", bp - b)
+        self.mesh_stats["encode_buckets"].add(bp)
+        self.perf.inc("ec_device_launches")
+        self._note_split_shapes(bp, sizes)
+        offs = np.cumsum([0] + sizes[:-1], dtype=np.int32)
+        encode = self.ec.encode_chunks_device
+
+        def run():
+            x = payloads[0] if len(payloads) == 1 \
+                else jnp.concatenate(payloads, axis=0)
+            chunks = encode(pad_batch_to(x, bp))
+            return [split_shard_streams(chunks, off, sz)
+                    for off, sz in zip(offs, sizes)]
+
+        t0 = time.perf_counter()
+        outs = await self._launch(run)
+        dt_us = (time.perf_counter() - t0) * 1e6
+        self.perf.hinc("ec_encode_launch_us", dt_us)
+        self.profiler.record(f"{self.codec_sig}:enc", dt_us,
+                             stripes=b, hbm_bytes=in_bytes,
+                             enqueue_only=True)
+        return outs
+
     async def _decode_batch(self, batched: dict, missing: list) -> dict:
         """Batched reconstruct through the mesh plane when configured.
         Survivor selection mirrors the codec's decode_chunks_batch
@@ -859,7 +917,7 @@ class ECBackend:
             bp = pow2_bucket(b)
             if bp != b:
                 self.perf.inc("ec_coalesce_pad_waste", bp - b)
-                with tracing.span("ec:prep"):
+                with tracing.span("ec:prep", tags=_SITE["pad"]):
                     batched = {
                         s: np.concatenate([
                             np.asarray(c, np.uint8),
@@ -929,7 +987,7 @@ class ECBackend:
         b = next(iter(avail.values())).shape[0] if avail else 0
         if b:
             padded = {}
-            with tracing.span("ec:prep"):
+            with tracing.span("ec:prep", tags=_SITE["pad"]):
                 for s, c in avail.items():
                     padded[s], _ = pad_batch_pow2_device(c)
             bp = next(iter(padded.values())).shape[0]
@@ -949,7 +1007,7 @@ class ECBackend:
                 raise IOError(f"cannot decode {todo}")
             rebuilt = await self._launch(
                 self.ec.decode_chunks_device, avail, todo)
-            with tracing.span("ec:prep"):
+            with tracing.span("ec:prep", tags=_SITE["trim"]):
                 for i, w in enumerate(todo):
                     out[w] = rebuilt[:b, i]
         dt_us = (time.perf_counter() - t0) * 1e6
@@ -966,25 +1024,51 @@ class ECBackend:
         concurrent batchmates) or falls through to the direct path when
         coalescing is off.  Shape validation happens HERE, before the op
         joins a batch, so a malformed op can only fail itself.  Device
-        batches (the resident write path) ride the same launcher and
-        stay on device end to end."""
+        batches ride the same launcher as the resident write path's
+        (_encode_write) and stay on device end to end; their shard
+        streams are stacked back to (B, k+m, C) here."""
         if not self._is_device(stripes):
             stripes = np.asarray(stripes, np.uint8)
         if self.coalescer is None and self.mesh_co is None:
             return await self._encode_batch(stripes)
+        self._check_encode_shape(stripes)
+        if self.mesh_co is not None:
+            # host-wide launcher: batchmates may come from OTHER OSDs'
+            # backends, and the launch shards over the whole mesh
+            return await self.mesh_co.submit(
+                self, ("enc",), stripes, stripes.shape[0])
+        out = await self.coalescer.submit(
+            ("enc",), stripes, stripes.shape[0])
+        if isinstance(out, tuple):
+            out = self.sinfo.stream_chunks(out[0])
+        return out
+
+    async def _coalesced_encode_write(self, stripes):
+        """Encode entry of the resident write path: (B, k, C) device
+        stripes -> ((n, B*C) shard-stream batch, tuple of the n
+        per-shard (B*C,) device arrays), from one launch shared with
+        the op's batchmates (_encode_write)."""
+        if self.mesh_co is not None:
+            from ceph_tpu.ec.engine import split_shard_streams
+
+            chunks = await self._coalesced_encode(stripes)
+            b = int(chunks.shape[0])
+            self._note_split_shapes(b, [b])
+            return await self._launch(
+                split_shard_streams, chunks, np.int32(0), b)
+        if self.coalescer is None:
+            return (await self._encode_write([stripes]))[0]
+        self._check_encode_shape(stripes)
+        return await self.coalescer.submit(
+            ("enc",), stripes, stripes.shape[0])
+
+    def _check_encode_shape(self, stripes) -> None:
         if stripes.ndim != 3 or stripes.shape[1] != self.k \
                 or stripes.shape[2] != self.sinfo.chunk_size:
             raise ValueError(
                 f"encode batch shape {stripes.shape} != "
                 f"(B, {self.k}, {self.sinfo.chunk_size})"
             )
-        if self.mesh_co is not None:
-            # host-wide launcher: batchmates may come from OTHER OSDs'
-            # backends, and the launch shards over the whole mesh
-            return await self.mesh_co.submit(
-                self, ("enc",), stripes, stripes.shape[0])
-        return await self.coalescer.submit(
-            ("enc",), stripes, stripes.shape[0])
 
     async def _coalesced_decode(self, batched: dict,
                                 missing: list) -> dict:
@@ -1026,30 +1110,28 @@ class ECBackend:
         """One device launch for a list of batchmate payloads (called
         only by the CoalescedLauncher): concatenate along the leading
         stripe axis, run the direct batch path (which shape-buckets),
-        scatter the slices back in order."""
+        scatter the slices back in order.  An encode with a device
+        batch runs as _encode_write and gives each device batchmate its
+        (streams, shard rows) pair."""
         if key[0] == "enc":
+            if any(self._is_device(p) for p in payloads):
+                # device batches take the resident write path's launch;
+                # a host batchmate is uploaded (counted) and gets its
+                # (B, k+m, C) chunks back down
+                outs = await self._encode_write(payloads)
+                return [out if self._is_device(p)
+                        else self.sinfo.stream_chunks(self._to_host(out[0]))
+                        for p, out in zip(payloads, outs)]
             if len(payloads) == 1:
                 return [await self._encode_batch(payloads[0])]
             sizes = [p.shape[0] for p in payloads]
-            any_dev = any(self._is_device(p) for p in payloads)
-            with tracing.span("ec:prep"):
-                if any_dev:
-                    # mixed batch: host batchmates are promoted (counted
-                    # uploads) so the whole launch stays on device;
-                    # their slices come back down below
-                    import jax.numpy as jnp
-                    cat = jnp.concatenate(
-                        [self._to_device(p) for p in payloads], axis=0)
-                else:
-                    cat = np.concatenate(payloads, axis=0)
+            with tracing.span("ec:prep", tags=_SITE["concat"]):
+                cat = np.concatenate(payloads, axis=0)
             out = await self._encode_batch(cat)
             res, off = [], 0
-            with tracing.span("ec:prep"):
-                for p, sz in zip(payloads, sizes):
-                    sl = out[off:off + sz]
-                    if any_dev and not self._is_device(p):
-                        sl = self._to_host(sl)
-                    res.append(sl)
+            with tracing.span("ec:prep", tags=_SITE["split"]):
+                for sz in sizes:
+                    res.append(out[off:off + sz])
                     off += sz
             return res
         _, shards, todo = key
@@ -1058,7 +1140,7 @@ class ECBackend:
         sizes = [next(iter(p.values())).shape[0] for p in payloads]
         any_dev = any(
             self._is_device(c) for p in payloads for c in p.values())
-        with tracing.span("ec:prep"):
+        with tracing.span("ec:prep", tags=_SITE["concat"]):
             if any_dev:
                 import jax.numpy as jnp
                 cat = {
@@ -1073,7 +1155,7 @@ class ECBackend:
                 }
         out = await self._decode_batch(cat, list(todo))
         res, off = [], 0
-        with tracing.span("ec:prep"):
+        with tracing.span("ec:prep", tags=_SITE["split"]):
             for p, sz in zip(payloads, sizes):
                 host_op = not any(self._is_device(c) for c in p.values())
                 sl = {w: c[off:off + sz] for w, c in out.items()}
@@ -1333,52 +1415,51 @@ class ECBackend:
             )
             end = offset + len(data)
             new_size = max(old_size, end)
-            sw = self.sinfo.stripe_width
             a_start, a_len = self.sinfo.offset_len_to_stripe_bounds(
                 offset, len(data)
             )
-            buf = None
-            if self.resident is not None:
-                # device-resident RMW: the stripe batch is assembled on
-                # device (resident shard gather + client-byte upload)
-                # and never materializes as host bytes
-                stripes = await self._resident_stripes(
-                    oid, a_start, a_len, offset, end, data, old_size,
-                    meta.version if meta else None,
-                )
-            else:
-                # RMW: read back surviving logical bytes around the
-                # write — the extent cache (ExtentCache role) serves
-                # back-to-back overwrites without re-reading + decoding
-                # k shards
-                existing = None
-                if old_size > a_start:
-                    keep_len = min(old_size, a_start + a_len) - a_start
-                    existing = self.extent_cache.get(oid, a_start,
-                                                     keep_len)
-                    if existing is None:
-                        existing = await self._read_logical(
-                            oid, a_start, keep_len, old_size,
-                            meta.version if meta else None,
-                        )
-                with tracing.span("ec:prep", oid=oid):
+            # RMW: the object's surviving bytes in the stripe range are
+            # read back only where the new bytes leave some of them
+            # standing (a fresh object, a same-size or longer
+            # write_full and a whole-stripe overwrite need none)
+            keep_len = (min(old_size, a_start + a_len) - a_start
+                        if old_size > a_start else 0)
+            rmw = keep_len > 0 and not (
+                offset <= a_start and end >= a_start + keep_len)
+            if keep_len > 0 and not rmw:
+                self.perf.inc("ec_rmw_gather_skipped")
+            existing = None
+            if rmw:
+                # the extent cache (ExtentCache role) serves back-to-back
+                # overwrites without re-reading + decoding k shards
+                existing = self.extent_cache.get(oid, a_start, keep_len)
+                if existing is None:
+                    existing = await self._read_logical(
+                        oid, a_start, keep_len, old_size,
+                        meta.version if meta else None)
+            # the (B, k, C) stripe batch is built on the host; a write
+            # that fills its whole stripe range is reshaped in place
+            new = np.frombuffer(bytes(data), np.uint8)
+            with tracing.span("ec:prep", oid=oid, tags=_SITE["stripes"]):
+                if existing is None and offset == a_start \
+                        and new.size == a_len:
+                    buf = new
+                else:
                     buf = np.zeros(a_len, np.uint8)
                     if existing is not None:
                         buf[:len(existing)] = np.frombuffer(existing,
                                                             np.uint8)
-                    buf[offset - a_start: end - a_start] = np.frombuffer(
-                        bytes(data), np.uint8
-                    )
-                    stripes = self.sinfo.split_stripes(buf)
-            # device encode off the event loop: a first-time XLA
-            # compile must not stall heartbeats/leases in this process
-            chunks = await self._coalesced_encode(stripes)
+                    buf[offset - a_start:end - a_start] = new
+                buf = self.sinfo.split_stripes(buf)
             shard_off = self.sinfo.logical_to_prev_chunk_offset(a_start)
             meta_attr = self._meta_attr(ECObjectMeta(new_size, new_version))
-            streams = None
-            if buf is None:
-                with tracing.span("ec:prep", oid=oid):
-                    streams = self.sinfo.shard_streams(chunks)
+            if self.resident is not None:
+                # one counted upload; the device encode and the split
+                # run off the event loop: a first-time XLA compile must
+                # not stall heartbeats or leases in this process
+                streams, shards = await self._coalesced_encode_write(
+                    self._to_device(buf))
+                self.perf.inc("ec_write_glue_fused")
                 if self.resident_writeback:
                     # shard data stays device-resident; the store gets
                     # an attrs-only commit now and the bytes on
@@ -1400,16 +1481,18 @@ class ECBackend:
                     hattrs = await self._update_hinfo(
                         oid, shard_off, shard_bytes, old_size
                     )
-                    with tracing.span("ec:prep", oid=oid):
+                    with tracing.span("ec:prep", oid=oid,
+                                      tags=_SITE["tobytes"]):
                         data_bytes = [c.tobytes() for c in shard_bytes]
                     write_off = shard_off
             else:
-                with tracing.span("ec:prep", oid=oid):
+                chunks = await self._coalesced_encode(buf)
+                with tracing.span("ec:prep", oid=oid, tags=_SITE["split"]):
                     shard_bytes = self.sinfo.shard_bytes(chunks)
                 hattrs = await self._update_hinfo(
                     oid, shard_off, shard_bytes, old_size
                 )
-                with tracing.span("ec:prep", oid=oid):
+                with tracing.span("ec:prep", oid=oid, tags=_SITE["tobytes"]):
                     data_bytes = [c.tobytes() for c in shard_bytes]
                 write_off = shard_off
             entry = (self.log_hook(oid, "modify", new_version,
@@ -1444,9 +1527,9 @@ class ECBackend:
                 if self.resident is not None:
                     self.resident.drop_object(self.resident_ns, oid)
                 raise
-            if streams is not None:
+            if self.resident is not None:
                 await self._resident_install(
-                    oid, shard_off, streams, new_version, old_size)
+                    oid, shard_off, shards, new_version, old_size)
             else:
                 self.extent_cache.note_write(oid, a_start,
                                              buf.tobytes(),
@@ -1454,96 +1537,20 @@ class ECBackend:
             return ECObjectMeta(new_size, new_version)
 
     # -- device residency (DeviceShardCache integration) ------------------
-    async def _resident_stripes(self, oid: str, a_start: int, a_len: int,
-                                offset: int, end: int, data,
-                                old_size: int, version):
-        """Assemble the write's (B, k, C) stripe batch on device.
-
-        Only the client's new bytes are uploaded; surviving bytes
-        around the write come from the resident data-shard entries (a
-        pure device gather).  A residency miss falls back to the host
-        read path (_read_logical handles reconstruction and hedging)
-        with ONE counted upload of the surrounding bytes."""
-        import jax.numpy as jnp
-
-        new = np.frombuffer(bytes(data), np.uint8)
-        keep_len = (min(old_size, a_start + a_len) - a_start
-                    if old_size > a_start else 0)
-        if keep_len <= 0 and new.size == a_len:
-            flat = self._to_device(new)
-        else:
-            base = None
-            if keep_len > 0:
-                base = self._resident_logical(
-                    oid, a_start, a_len, keep_len, old_size, version)
-                if base is None:
-                    existing = self.extent_cache.get(oid, a_start,
-                                                     keep_len)
-                    if existing is None:
-                        existing = await self._read_logical(
-                            oid, a_start, keep_len, old_size, version)
-                    host = np.zeros(a_len, np.uint8)
-                    host[:keep_len] = np.frombuffer(existing, np.uint8)
-                    base = self._to_device(host)
-            with tracing.span("ec:prep", oid=oid):
-                if base is None:
-                    base = jnp.zeros(a_len, jnp.uint8)
-                flat = base.at[offset - a_start: end - a_start].set(
-                    self._to_device(new))
-        with tracing.span("ec:prep", oid=oid):
-            return flat.reshape(-1, self.k, self.sinfo.chunk_size)
-
-    def _resident_logical(self, oid: str, a_start: int, a_len: int,
-                          keep_len: int, old_size: int, version):
-        """Device gather of logical bytes [a_start, a_start + a_len)
-        from the resident data-shard entries (bytes past keep_len are
-        zeroed, matching the host RMW buffer), or None when any needed
-        shard segment is not resident at the object's version."""
-        import jax.numpy as jnp
-
-        C = self.sinfo.chunk_size
-        nstripes = a_len // self.sinfo.stripe_width
-        coff = self.sinfo.aligned_logical_offset_to_chunk_offset(a_start)
-        clen = nstripes * C
-        ssize = self.sinfo.logical_to_next_chunk_offset(old_size)
-        need = min(coff + clen, ssize)
-        segs = []
-        for i in self.data_shards:
-            ent = self.resident.get(self.resident_ns, oid, i)
-            if ent is None or (version is not None
-                               and ent.version != version):
-                return None
-            arr = ent.arr
-            if arr.shape[0] < need:
-                return None
-            seg = arr[coff: coff + clen]
-            if seg.shape[0] < clen:
-                seg = jnp.concatenate([
-                    seg, jnp.zeros(clen - seg.shape[0], jnp.uint8)])
-            segs.append(seg)
-        flat = self.sinfo.stack_shard_streams(jnp.stack(segs), nstripes)
-        if keep_len < a_len:
-            # zero the RMW buffer past the surviving bytes, as the host
-            # path's zero-initialized buf does
-            flat = jnp.where(
-                jnp.arange(a_len) < keep_len, flat, jnp.uint8(0))
-        return flat
-
-    async def _resident_install(self, oid: str, shard_off: int, streams,
+    async def _resident_install(self, oid: str, shard_off: int, shards,
                                 version: int, old_size: int) -> None:
-        """Install the write's encoded shard streams into the resident
-        cache (spliced over any prior entry), then enforce the byte
-        budget.  Write-back entries are dirty — the cache's spill hook
-        persists them on evict/flush."""
+        """Install the write's encoded per-shard streams (``shards``, one
+        device array per shard) into the resident cache (spliced over
+        any prior entry), then enforce the byte budget.  Write-back
+        entries are dirty — the cache's spill hook persists them on
+        evict/flush."""
         import jax.numpy as jnp
 
         cache = self.resident
         dirty = self.resident_writeback
-        clen = int(streams.shape[1])
+        clen = int(shards[0].shape[0])
         old_len = self.sinfo.logical_to_next_chunk_offset(old_size)
-        with tracing.span("ec:prep", oid=oid):
-            segs = [streams[i] for i in range(self.n)]
-        for i, seg in enumerate(segs):
+        for i, seg in enumerate(shards):
             ent = cache.get(self.resident_ns, oid, i, count=False)
             if ent is not None and not (
                     shard_off == 0 and clen >= ent.arr.shape[0]):
@@ -1624,7 +1631,8 @@ class ECBackend:
             0, min(length, shard_size - off))
         if arr.shape[0] < off + expected:
             return None
-        with tracing.span("ec:prep", oid=oid, shard=shard):
+        with tracing.span("ec:prep", oid=oid, shard=shard,
+                          tags=_SITE["resident_read"]):
             seg = arr[off: off + length]
             if seg.shape[0] < length:
                 import jax.numpy as jnp
@@ -1646,7 +1654,9 @@ class ECBackend:
         out = {"enabled": True,
                "writeback": self.resident_writeback,
                **self.resident.stats(ns=self.resident_ns)}
-        for key in ("ec_resident_h2d_bytes", "ec_resident_d2h_bytes"):
+        for key in ("ec_resident_h2d_bytes", "ec_resident_d2h_bytes",
+                    "ec_write_glue_fused", "ec_rmw_gather_skipped",
+                    "ec_write_glue_compiles"):
             out[key] = int(self.perf.value(key))
         return out
 
@@ -1946,7 +1956,7 @@ class ECBackend:
             fanout.end()
         # the Objecter/client boundary: resident chunks materialize to
         # host HERE (one counted copy of the payload), not per-launch
-        with tracing.span("ec:prep", oid=oid):
+        with tracing.span("ec:prep", oid=oid, tags=_SITE["merge"]):
             stripes = np.stack(
                 [self._to_host(chunks[i]).reshape(nstripes,
                                                   self.sinfo.chunk_size)

@@ -85,21 +85,12 @@ class StripeInfo:
         return [np.ascontiguousarray(chunks[:, i]).reshape(-1)
                 for i in range(n)]
 
-    def shard_streams(self, chunks):
-        """(num_stripes, n, chunk_size) encoded batch -> (n, num_stripes
-        * chunk_size) per-shard byte streams as ONE array.  Uses only
-        array methods so a device batch stays on device (the resident
-        write path) and a numpy batch stays numpy."""
-        b, n, c = chunks.shape
-        return chunks.transpose(1, 0, 2).reshape(n, b * c)
-
-    def stack_shard_streams(self, streams, nstripes: int):
-        """Inverse of shard_streams for the k data shards: (k, nstripes
-        * chunk_size) streams -> flat logical bytes of nstripes stripes.
-        Array-method only, so device streams gather on device."""
-        k = streams.shape[0]
-        return streams.reshape(k, nstripes, self.chunk_size) \
-                      .transpose(1, 0, 2).reshape(-1)
+    def stream_chunks(self, streams):
+        """(n, num_stripes * chunk_size) per-shard byte streams -> the
+        (num_stripes, n, chunk_size) encoded batch.  Array methods
+        only, so device streams stay on device."""
+        n = streams.shape[0]
+        return streams.reshape(n, -1, self.chunk_size).transpose(1, 0, 2)
 
 
 @dataclass

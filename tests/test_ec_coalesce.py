@@ -247,6 +247,51 @@ def test_cancelled_waiter_cleanup():
     asyncio.run(run())
 
 
+@pytest.mark.parametrize(
+    "profile", COALESCE_PROFILES,
+    ids=lambda p: f"k{p['k']}m{p['m']}_{p['technique']}")
+def test_coalesced_write_batchmates_match_solo(profile, monkeypatch):
+    """Device stripe batches of concurrent resident writes share one
+    launch, and one jitted split call per batchmate hands it the shard
+    streams and per-shard rows a solo launch gives it — the chunks of
+    the host encode, shard by shard."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.osd import ec_backend
+
+    monkeypatch.setattr(ec_backend, "_SPLIT_SHAPES", set())
+
+    async def run():
+        be = await _backend(profile, resident=True)
+        rng = np.random.default_rng(13)
+        k, chunk, n = be.k, be.sinfo.chunk_size, be.n
+        host = [np.asarray(rng.integers(0, 256, (b, k, chunk)), np.uint8)
+                for b in (1, 3, 8, 2)]
+        dev = [jnp.asarray(h) for h in host]
+        be._inflight_ops = len(dev) + 1
+        try:
+            outs = await asyncio.gather(*(
+                be._coalesced_encode_write(d) for d in dev))
+        finally:
+            be._inflight_ops = 0
+        st = be.coalescer.stats()
+        assert st["ops"] == len(dev) and st["launches"] == 1, st
+        # one split program per batchmate size (solo splits come below)
+        assert be.perf.value("ec_write_glue_compiles") == len(dev)
+        for h, d, (streams, shards) in zip(host, dev, outs):
+            chunks = np.asarray(await be._encode_batch(h))
+            want = chunks.transpose(1, 0, 2).reshape(n, -1)
+            solo_streams, solo_shards = (await be._encode_write([d]))[0]
+            assert np.array_equal(np.asarray(streams), want)
+            assert np.array_equal(np.asarray(solo_streams), want)
+            assert len(shards) == len(solo_shards) == n
+            for i in range(n):
+                assert np.array_equal(np.asarray(shards[i]), want[i])
+                assert np.array_equal(np.asarray(solo_shards[i]), want[i])
+
+    asyncio.run(run())
+
+
 def test_shape_buckets_bounded():
     """pow2 batch-dim bucketing: any mix of stripe counts up to max B
     compiles at most ceil(log2(max B)) + 1 encode shapes per codec

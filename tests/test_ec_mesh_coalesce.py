@@ -233,6 +233,31 @@ def test_resident_device_batch_feeds_sharded_launch_no_h2d():
     asyncio.run(run())
 
 
+def test_resident_write_through_mesh_coalescer():
+    """A resident backend on the host mesh coalescer writes through the
+    sharded launch and splits its shard streams with the write path's
+    jitted program: each shard's resident entry holds the bytes its
+    store got, and the object reads back."""
+    async def run():
+        co = MeshCoalescer()
+        be = await _backend({"k": "4", "m": "2",
+                             "technique": "reed_sol_van"},
+                            mesh_coalescer=co, resident=True)
+        assert be.mesh_co is co
+        data = np.random.default_rng(6).integers(
+            0, 256, 5 * be.sinfo.stripe_width + 9, np.uint8).tobytes()
+        await be.write("obj", data)
+        assert co.stats()["launches"] == 1
+        assert be.perf.value("ec_write_glue_fused") == 1
+        for i in range(be.n):
+            ent = be.resident.get(be.resident_ns, "obj", i, count=False)
+            assert np.asarray(ent.arr).tobytes() == \
+                await be.shards[i].read_shard("obj")
+        assert await be.read("obj") == data
+
+    asyncio.run(run())
+
+
 def test_mixed_host_device_batchmates():
     """One device op + one host op share a launch; each gets its own
     representation back and the host op's transfers are counted."""

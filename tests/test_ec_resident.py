@@ -171,8 +171,8 @@ def test_resident_corpus_payload_bit_identical(profile):
 
 def test_resident_write_evict_readback_cycle():
     """write -> sub-stripe overwrite -> evict -> read-back, in both
-    write-through and write-back modes; write-back uploads only the
-    client payload on the overwrite."""
+    write-through and write-back modes; the overwrite uploads its one
+    stripe (old bytes spliced with the client's on the host), once."""
     async def run(writeback):
         be = await _backend(resident=True, resident_writeback=writeback)
         assert be.resident_writeback is writeback
@@ -182,9 +182,9 @@ def test_resident_write_evict_readback_cycle():
         patch = b"\xee" * 96
         await be.write("cyc", patch, offset=700)
         data[700:796] = patch
-        if writeback:
-            # resident RMW: only the 96 client bytes cross to device
-            assert be.perf.value("ec_resident_h2d_bytes") - h2d0 == 96
+        # the RMW stripe batch [512, 1024) is the overwrite's one upload
+        assert be.perf.value("ec_resident_h2d_bytes") - h2d0 == \
+            be.sinfo.stripe_width == 512
         assert await be.read("cyc") == bytes(data)
         await be.flush_resident()
         await be.resident.evict(target=0)
@@ -273,6 +273,150 @@ def test_resident_and_classic_backends_concurrent():
         for o, d in datas.items():
             assert await res.read(o) == d
             assert await cla.read(o) == d
+
+    _run(run())
+
+
+# -- the resident write path against the host path ------------------------
+
+WRITE_PROFILES = RESIDENT_PROFILES + [
+    {"k": "8", "m": "4", "technique": "reed_sol_van"}]
+
+# each case: its writes as (offset, length) from the stripe width; the
+# first may create the object, the last is the one under test
+WRITE_CASES = {
+    "fresh_full_batch": lambda sw: [(0, 5 * sw)],
+    "fresh_sub_stripe": lambda sw: [(0, sw // 3)],
+    "writefull_same": lambda sw: [(0, sw // 3), (0, sw // 3)],
+    "writefull_longer": lambda sw: [(0, sw // 3), (0, 2 * sw + 7)],
+    "partial_overwrite": lambda sw: [(0, 3 * sw), (sw + 5, sw // 2)],
+    "append": lambda sw: [(0, sw + 100), (sw + 100, sw)],
+}
+
+
+async def _stored(be, oid):
+    """Per shard: (store bytes, version attr, hinfo attr)."""
+    out = []
+    for i in range(be.n):
+        attrs = await be.shards[i].get_attrs(oid)
+        out.append((await be.shards[i].read_shard(oid),
+                    attrs.get("version"), attrs.get("hinfo")))
+    return out
+
+
+@pytest.mark.parametrize("writeback", [False, True],
+                         ids=["write_through", "write_back"])
+@pytest.mark.parametrize("case", list(WRITE_CASES))
+@pytest.mark.parametrize(
+    "profile", WRITE_PROFILES,
+    ids=lambda p: f"k{p['k']}m{p['m']}_{p['technique']}")
+def test_fused_write_matches_host_path(profile, case, writeback):
+    """The resident write path (host-built stripes, one launch, one
+    jitted split) against the non-resident host path on the same
+    writes: the stores hold the same shard bytes, version and hinfo
+    attrs, every shard's resident entry holds its store bytes, and both
+    read back the same object."""
+    async def run():
+        res = await _backend(profile, resident=True,
+                             resident_writeback=writeback)
+        host = await _backend(profile, resident=False)
+        sw = res.sinfo.stripe_width
+        rng = np.random.default_rng(7)
+        want = bytearray()
+        writes = WRITE_CASES[case](sw)
+        for off, n in writes:
+            data = rng.integers(0, 256, n, np.uint8).tobytes()
+            for be in (res, host):
+                await be.write("obj", data, offset=off)
+            want[len(want):off + n] = b"\0" * max(0, off + n - len(want))
+            want[off:off + n] = data
+        await res.flush_resident()
+        stored = await _stored(host, "obj")
+        assert await _stored(res, "obj") == stored
+        for i in range(res.n):
+            ent = res.resident.get(res.resident_ns, "obj", i, count=False)
+            assert ent is not None
+            assert np.asarray(ent.arr).tobytes() == stored[i][0]
+        assert await res.read("obj") == bytes(want)
+        assert await host.read("obj") == bytes(want)
+        assert res.resident_stats()["ec_write_glue_fused"] == len(writes)
+        assert host.perf.value("ec_write_glue_fused") == 0
+
+    _run(run())
+
+
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["resident", "host"])
+def test_rmw_gather_skipped_counts_covering_writes(resident):
+    """Writes whose new bytes cover every surviving byte of their
+    stripes (same-size and longer write_full, a whole-stripe overwrite)
+    count in ec_rmw_gather_skipped and read nothing back; a fresh write
+    has nothing to cover; a partial overwrite still reads the old bytes
+    back and splices them."""
+    async def run():
+        be = await _backend(resident=resident)
+        sw = be.sinfo.stripe_width
+        rng = np.random.default_rng(3)
+        fetched = []
+        read_logical = be._read_logical
+
+        async def counted_read(*a, **kw):
+            fetched.append("read")
+            return await read_logical(*a, **kw)
+
+        be._read_logical = counted_read
+        skipped = lambda: be.perf.value("ec_rmw_gather_skipped")  # noqa
+        want = bytearray(rng.integers(0, 256, 2 * sw + 10, np.uint8).tobytes())
+        await be.write("o", bytes(want))
+        assert skipped() == 0
+        want[:] = rng.integers(0, 256, len(want), np.uint8).tobytes()
+        await be.write("o", bytes(want))                   # same size
+        assert skipped() == 1
+        want[:] = rng.integers(0, 256, 3 * sw + 1, np.uint8).tobytes()
+        await be.write("o", bytes(want))                   # longer
+        assert skipped() == 2
+        stripe = rng.integers(0, 256, sw, np.uint8).tobytes()
+        await be.write("o", stripe, offset=sw)             # whole stripe
+        want[sw:2 * sw] = stripe
+        assert skipped() == 3 and fetched == []
+        be.extent_cache.clear()
+        patch = b"\xa5" * 40
+        await be.write("o", patch, offset=sw + 5)          # partial
+        want[sw + 5:sw + 45] = patch
+        assert skipped() == 3
+        assert fetched == ["read"]
+        assert await be.read("o") == bytes(want)
+        assert be.perf.dump()["ec_rmw_gather_skipped"] == 3
+
+    _run(run())
+
+
+def test_write_glue_compiles_bounded(monkeypatch):
+    """Writes of 70 distinct stripe counts compile one split program
+    each, kept by jit's own cache: writing every size again compiles
+    nothing (neither the counter nor jit's cache moves), and each object
+    reads back its newest bytes."""
+    from ceph_tpu.ec.engine import split_shard_streams
+    from ceph_tpu.osd import ec_backend
+
+    monkeypatch.setattr(ec_backend, "_SPLIT_SHAPES", set())
+    sizes = range(1, 71)
+
+    async def run():
+        be = await _backend(resident=True)
+        sw = be.sinfo.stripe_width
+        compiles = lambda: be.perf.value("ec_write_glue_compiles")  # noqa
+        for b in sizes:
+            await be.write(f"o{b}", bytes([b]) * (b * sw))
+        assert compiles() == len(sizes)
+        cached = split_shard_streams._cache_size()
+        for b in sizes:
+            await be.write(f"o{b}", bytes([b + 1]) * (b * sw))
+        assert compiles() == len(sizes)
+        assert split_shard_streams._cache_size() == cached
+        assert be.resident_stats()["ec_write_glue_compiles"] == len(sizes)
+        for b in (1, 40, 70):
+            assert await be.read(f"o{b}") == bytes([b + 1]) * (b * sw)
 
     _run(run())
 

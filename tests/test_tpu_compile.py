@@ -171,6 +171,27 @@ def test_byte_lane_conversions_compile_quickly(one_chip, rows):
     assert time.monotonic() - t0 < 60
 
 
+@pytest.mark.parametrize("n,b,bp", [(12, 128, 128), (12, 128, 2048),
+                                    (6, 1, 1), (6, 1, 32)],
+                         ids=["write_solo", "write_16_mates",
+                              "ycsb_solo", "ycsb_32_mates"])
+def test_write_split_program_compiles(one_chip, n, b, bp):
+    """The write path's split (engine.split_shard_streams) at the
+    cells' shapes: a 4 MiB object of k=8 m=4 (128 stripes of 12 x 4 KiB
+    chunks) and a 1,000 B record of k=4 m=2 (one stripe), alone and in
+    the largest coalesced launch the cells warm.  A uint8 relayout of
+    12 or 6 rows compiles in seconds."""
+    import time
+
+    from ceph_tpu.ec.engine import split_shard_streams
+
+    t0 = time.monotonic()
+    _compiled_text(lambda c, off: split_shard_streams(c, off, b),
+                   _shape((bp, n, 4096), jnp.uint8, one_chip),
+                   _shape((), jnp.int32, one_chip))
+    assert time.monotonic() - t0 < 60
+
+
 def test_byte_lane_conversions_match_the_numpy_view():
     data = np.random.default_rng(5).integers(0, 256, (3, 4096), np.uint8)
     words = np.asarray(pk.bytes_to_words(data))
