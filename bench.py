@@ -270,15 +270,16 @@ def _cfg7_resident_ab(n_objects: int = 64, object_bytes: int = 16384,
     """cfg7: device-resident EC data path A/B — the same workload (64
     objects fully written at 16 KiB, then ``rounds`` waves of 64
     concurrent 512 B sub-stripe overwrites) run once with the resident
-    shard cache in write-back mode and once through the classic host
-    path.  The graded signal is HOST<->DEVICE BYTES over the overwrite
-    phase (perf counters ec_resident_h2d_bytes / ec_resident_d2h_bytes):
-    the resident arm uploads only the client payload and defers
-    persistence to eviction/flush, while the classic arm re-uploads the
-    full RMW stripe and downloads all k+m encoded chunks per write.
-    Both counters are exact logical-byte tallies, valid on CPU — no chip
-    chip needed to verify the counter.  Read-back is verified
-    bit-identical in both modes after a full flush."""
+    shard cache and once through the classic host path.  The graded
+    signal is HOST<->DEVICE BYTES over the overwrite phase (perf
+    counters ec_resident_h2d_bytes / ec_resident_d2h_bytes).  Both arms
+    write through: each write uploads its RMW stripe and downloads all
+    k+m encoded chunks for the store.  The >= 4x gate was set for the
+    removed write-back mode, which uploaded only client bytes; the
+    write-through arm does not reach it.  Both counters are exact
+    logical-byte tallies, valid on CPU — no chip needed to verify the
+    counter.  Read-back is verified bit-identical in both modes after a
+    full eviction."""
     import asyncio
 
     from ceph_tpu.ec.registry import ErasureCodePluginRegistry
@@ -298,7 +299,7 @@ def _cfg7_resident_ab(n_objects: int = 64, object_bytes: int = 16384,
             shards[i] = LocalShard(store, cid, pool=1, shard=i)
         # stripe_unit=1024, k=4 -> 4 KiB stripes (the ISSUE target size)
         return ECBackend(codec, shards, stripe_unit=1024,
-                         resident=resident, resident_writeback=resident)
+                         resident=resident)
 
     async def populate(be: ECBackend) -> dict[str, bytearray]:
         datas = {f"obj-{i}": bytearray(bytes([i % 256]) * object_bytes)
@@ -323,8 +324,7 @@ def _cfg7_resident_ab(n_objects: int = 64, object_bytes: int = 16384,
 
     async def verify(be: ECBackend, datas: dict[str, bytearray]) -> None:
         if be.resident is not None:
-            await be.flush_resident()
-            await be.resident.evict(target=0)
+            be.resident.evict(target=0)
         for o, d in datas.items():
             got = await be.read(o)
             if got != bytes(d):

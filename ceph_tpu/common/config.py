@@ -164,12 +164,6 @@ def global_options() -> list[Option]:
                "records and checkpoint segments ('' = off; zlib, zstd, "
                "lzma, bz2 — the BlueStore compress-on-write role)",
                enum_values=("", "zlib", "zstd", "lzma", "bz2")),
-        Option("osd_ec_mesh_cs", int, 0,
-               "chunk-sharding axis size of the distributed EC data "
-               "plane mesh (0 = single-device EC; >0 = shard encode/"
-               "decode batches over all local jax devices with a "
-               "('dp','cs') mesh, cs dividing the device count)",
-               min=0),
         Option("mds_beacon_interval", float, 0.5,
                "mds -> mon beacon period (s)", min=0.05),
         Option("mds_beacon_grace", float, 3.0,
@@ -296,10 +290,10 @@ def global_options() -> list[Option]:
                "crossing it evicts LRU entries to the low watermark",
                Level.ADVANCED, min=1 << 20),
         Option("osd_ec_resident_writeback", bool, False,
-               "defer shard-data persistence to cache evict/flush "
-               "(attrs-only store commit per write); honored only in "
-               "lenient (unlogged) mode — logged acks require the "
-               "store commit", Level.ADVANCED),
+               "only false: write-back residency was removed because "
+               "an OSD's logged mode always wrote through; kept so "
+               "configs that set it false still load",
+               Level.ADVANCED, enum_values=(False,)),
         Option("osd_ec_repair_batch", bool, True,
                "drain PG missing sets through the batched repair "
                "engine: degraded objects grouped by lost-shard "

@@ -230,8 +230,8 @@ class ErasureCodeJaxRS(ErasureCode):
         self, available_ids, missing
     ) -> tuple[tuple[int, ...], np.ndarray]:
         """Deterministic survivor choice + decode matrix, shared by the
-        single-device path (decode_chunks_batch) AND the distributed
-        mesh plane (osd.ec_backend._decode_batch).  One definition, so
+        single-device path (decode_chunks_batch) AND the host mesh
+        coalescer (osd.mesh_coalesce).  One definition, so
         the two planes can never drift apart and silently build
         different decode matrices (cross-plane bit-identity depends on
         this)."""

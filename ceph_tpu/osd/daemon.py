@@ -105,10 +105,6 @@ from ceph_tpu.store.txcodec import (
 
 log = Dout("osd")
 
-# process-wide EC data-plane meshes (cs -> jax Mesh): jax devices are a
-# process resource, so every OSD in one test process shares the mesh
-_EC_MESH_CACHE: dict[int, object] = {}
-
 # the active trace span of the op being executed on this task lives in
 # common.tracing's shared contextvar (current_span/use_span): sub-op
 # fan-out, the EC coalescer, and the messenger all read it there
@@ -393,14 +389,11 @@ class OSDDaemon:
         await self.store.mount()
         await self.msgr.bind(self.addr)
         await self.monc.start(timeout)
-        if int(self.conf["osd_ec_mesh_cs"]) > 0:
-            # build the EC data-plane mesh OFF the event loop before
-            # any PG needs it: first-time jax runtime init blocks for
-            # seconds and would stall heartbeats/leases mid-peering
-            await asyncio.to_thread(self._ec_mesh)
         if bool(self.conf["osd_ec_mesh_coalesce"]):
-            # same off-loop warmup for the host mesh coalescer's
-            # device pool (first OSD up pays it; later ones find the
+            # warm the host mesh coalescer's device pool OFF the event
+            # loop before any PG needs it: first-time jax runtime init
+            # blocks for seconds and would stall heartbeats/leases
+            # mid-peering (first OSD up pays it; later ones find the
             # singleton warm)
             co = self._host_coalescer()
             if co is not None:
@@ -531,7 +524,6 @@ class OSDDaemon:
             ms = be.mesh_stats
             out[str(pgid)] = {
                 "plane": ("mesh-coalesced" if be.mesh_co is not None
-                          else "mesh" if be.mesh is not None
                           else "single-device"),
                 "sharded_decode": bool(be._mesh_dec_ok),
                 "encodes": ms["encodes"],
@@ -793,16 +785,6 @@ class OSDDaemon:
             self.admin_socket = None
         await self.monc.shutdown()
         await self.msgr.shutdown()
-        # spill any dirty device-resident shard streams BEFORE the
-        # store unmounts — device HBM is a cache tier, not durability
-        for pg in self.pgs.values():
-            be = getattr(pg, "backend", None)
-            if be is not None and getattr(be, "resident", None) \
-                    is not None:
-                try:
-                    await be.flush_resident()
-                except Exception:
-                    log.exception("resident flush failed on shutdown")
         await self.store.umount()
 
     # -- cephx -------------------------------------------------------------
@@ -1808,30 +1790,6 @@ class OSDDaemon:
             ]
         return [CollectionId(pg.pgid.pool, pg.pgid.ps)]
 
-    def _ec_mesh(self):
-        """Distributed EC data-plane mesh (osd_ec_mesh_cs > 0): one
-        ('dp','cs') mesh over all local jax devices, built once per
-        process (OSDs in one process share the devices).  A cs that
-        does not divide the local device count is a configuration error
-        and raises: the single-device plane would hide it."""
-        cs = int(self.conf["osd_ec_mesh_cs"])
-        if cs <= 0:
-            return None
-        mesh = _EC_MESH_CACHE.get(cs)
-        if mesh is None:
-            import jax
-
-            from ceph_tpu.parallel.ec_sharding import make_ec_mesh
-
-            devs = jax.devices()
-            if len(devs) < cs or len(devs) % cs:
-                raise ValueError(
-                    f"osd.{self.osd_id}: osd_ec_mesh_cs={cs} does not "
-                    f"divide the {len(devs)} local devices")
-            mesh = make_ec_mesh(devs, cs=cs)
-            _EC_MESH_CACHE[cs] = mesh
-        return mesh
-
     def _host_coalescer(self):
         """Host-level mesh coalescer (osd_ec_mesh_coalesce): ONE
         launcher per process shared by every co-located OSD's EC
@@ -1901,7 +1859,6 @@ class OSDDaemon:
                 # the profile's stripe_unit (Ceph's per-pool knob);
                 # unset = the codec's alignment
                 stripe_unit=int(profile.get("stripe_unit", 0)) or None,
-                mesh=self._ec_mesh(),
                 hedge_timeout=hedge or None,
                 perf=self.perf,
                 tracer=self.tracer,
@@ -1913,8 +1870,6 @@ class OSDDaemon:
                     self.conf["osd_ec_coalesce_max_stripes"]),
                 resident=resident,
                 resident_ns=resident_ns,
-                resident_writeback=bool(
-                    self.conf["osd_ec_resident_writeback"]),
                 mesh_coalescer=self._host_coalescer(),
             )
             pg.ec_k = pg.backend.k

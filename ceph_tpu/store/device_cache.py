@@ -7,12 +7,11 @@ feed the coalesced Pallas launches without re-uploading host bytes on
 every op.  Keys are ``(ns, oid, shard)`` — ``ns`` namespaces one
 shared per-daemon cache across PG backends.
 
+Every entry is a copy of bytes the store already holds (the backend
+writes through before it installs), so dropping one never loses data.
 Entries are LRU-tracked with a byte budget: when usage crosses the
-high watermark the owner calls :meth:`evict`, which drops clean
-entries and spills dirty ones to the store through the per-entry
-``spill`` callable captured at install time (write-back mode defers
-shard persistence to exactly this path).  :meth:`flush` persists all
-dirty entries without dropping them — the shutdown/export hook.
+high watermark the owner calls :meth:`evict`, which drops LRU entries
+down to the low watermark.
 
 Counters (``ec_resident_hits/_misses/_evictions`` here; the owner
 accounts ``_h2d_bytes/_d2h_bytes`` at its conversion points) mirror
@@ -44,13 +43,11 @@ def register_resident_counters(perf: PerfCounters) -> None:
 
 
 class _Entry:
-    __slots__ = ("arr", "version", "dirty", "spill", "nbytes")
+    __slots__ = ("arr", "version", "nbytes")
 
-    def __init__(self, arr, version, dirty, spill):
+    def __init__(self, arr, version):
         self.arr = arr
         self.version = int(version)
-        self.dirty = bool(dirty)
-        self.spill = spill
         self.nbytes = int(arr.nbytes)
 
 
@@ -110,7 +107,7 @@ class DeviceShardCache:
     def get(self, ns, oid, shard, count: bool = True) -> _Entry | None:
         """The entry for (ns, oid, shard), LRU-touched, or None.
 
-        The caller owns version/dirty semantics; ``count=False`` skips
+        The caller owns version semantics; ``count=False`` skips
         the hit/miss counters for internal bookkeeping lookups.
         """
         ent = self._entries.get((ns, oid, shard))
@@ -125,20 +122,19 @@ class DeviceShardCache:
             self.perf.inc("ec_resident_hits")
         return ent
 
-    def put(self, ns, oid, shard, arr, version: int,
-            dirty: bool = False, spill=None) -> None:
+    def put(self, ns, oid, shard, arr, version: int) -> None:
         """Install (replacing any prior entry) the shard stream ``arr``."""
         key = (ns, oid, shard)
         old = self._entries.pop(key, None)
         if old is not None:
             self.bytes -= old.nbytes
-        ent = _Entry(self._place(arr), version, dirty, spill)
+        ent = _Entry(self._place(arr), version)
         self._entries[key] = ent
         self.bytes += ent.nbytes
 
     def install_batch(self, ns, entries) -> int:
         """Vectored install: ``entries`` is an iterable of
-        ``(oid, shard, arr, version)`` tuples, installed clean in one
+        ``(oid, shard, arr, version)`` tuples, installed in one
         call.  The repair engine's bulk survivor pull lands here — the
         fetched shard streams become resident in the same pass that
         feeds the batched decode launch, so the decode consumes the
@@ -177,41 +173,20 @@ class DeviceShardCache:
             if key[0] == ns and key[1] == oid:
                 ent.version = int(version)
 
-    # -- eviction / flush -------------------------------------------------
+    # -- eviction ---------------------------------------------------------
 
     @property
     def over_high(self) -> bool:
         return self.bytes > self.max_bytes
 
-    async def _spill(self, key, ent) -> None:
-        host = np.asarray(ent.arr, np.uint8)
-        self.perf.inc("ec_resident_d2h_bytes", host.nbytes)
-        await ent.spill(key[1], key[2], host)
-
-    async def evict(self, target: int | None = None) -> None:
-        """Evict LRU entries until usage <= target (default: low
-        watermark).  Clean entries drop; dirty entries spill first.
-        A failing spill skips that entry (store degraded) rather than
-        losing the only copy of the data."""
+    def evict(self, target: int | None = None) -> None:
+        """Drop LRU entries until usage <= target (default: low
+        watermark)."""
         if target is None:
             target = self.low_bytes
-        skipped: set[tuple] = set()
         evicted = freed = 0
-        while self.bytes > target:
-            key = next((k for k in self._entries if k not in skipped), None)
-            if key is None:
-                break
-            ent = self._entries[key]
-            if ent.dirty:
-                if ent.spill is None:
-                    skipped.add(key)
-                    continue
-                try:
-                    await self._spill(key, ent)
-                except Exception:
-                    skipped.add(key)
-                    continue
-            self._entries.pop(key, None)
+        while self.bytes > target and self._entries:
+            _, ent = self._entries.popitem(last=False)
             self.bytes -= ent.nbytes
             self.evictions += 1
             evicted += 1
@@ -222,42 +197,18 @@ class DeviceShardCache:
                               freed_bytes=freed, bytes=self.bytes,
                               target=int(target))
 
-    async def flush(self, ns=None) -> None:
-        """Spill every dirty entry (optionally one namespace) to the
-        store and mark it clean; entries stay resident for reads.
-        Raises the first spill failure after attempting all."""
-        first_err: Exception | None = None
-        for key, ent in list(self._entries.items()):
-            if not ent.dirty or (ns is not None and key[0] != ns):
-                continue
-            if ent.spill is None:
-                continue
-            try:
-                await self._spill(key, ent)
-                ent.dirty = False
-            except Exception as e:
-                if first_err is None:
-                    first_err = e
-        if first_err is not None:
-            raise first_err
-
     # -- introspection ----------------------------------------------------
 
     def stats(self, ns=None) -> dict:
-        entries = nbytes = dirty = dirty_bytes = 0
+        entries = nbytes = 0
         for key, ent in self._entries.items():
             if ns is not None and key[0] != ns:
                 continue
             entries += 1
             nbytes += ent.nbytes
-            if ent.dirty:
-                dirty += 1
-                dirty_bytes += ent.nbytes
         return {
             "entries": entries,
             "bytes": nbytes,
-            "dirty_entries": dirty,
-            "dirty_bytes": dirty_bytes,
             "max_bytes": self.max_bytes,
             "hits": self.hits,
             "misses": self.misses,

@@ -67,6 +67,17 @@ def test_config_register_subsystem_options():
     assert cfg.get("my_opt") == 9
 
 
+def test_config_resident_writeback_accepts_only_false():
+    """Residency always writes through: osd_ec_resident_writeback loads
+    as false and refuses true."""
+    cfg = ConfigProxy(overrides={"osd_ec_resident_writeback": False})
+    assert cfg.get("osd_ec_resident_writeback") is False
+    with pytest.raises(ValueError):
+        cfg.set("osd_ec_resident_writeback", True)
+    with pytest.raises(ValueError):
+        ConfigProxy(overrides={"osd_ec_resident_writeback": "true"})
+
+
 def test_config_bool_parse():
     cfg = ConfigProxy()
     cfg.set("osd_ec_coalesce", "false")

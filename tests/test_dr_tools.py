@@ -400,39 +400,6 @@ def test_mds_stale_fragtree_retry_finds_moved_name():
     asyncio.run(run())
 
 
-def test_ec_mesh_applier_pin_and_lru(monkeypatch):
-    """The write-path ('enc',) applier is pinned outside the bounded
-    decode-combo cache, and the cache evicts least-recently-USED, not
-    oldest-inserted."""
-    from ceph_tpu.osd.ec_backend import ECBackend
-    from ceph_tpu.parallel import ec_sharding
-
-    class _Stub:
-        def __init__(self, mesh, coeff):
-            self.coeff = coeff
-
-    monkeypatch.setattr(ec_sharding, "ShardedApplier", _Stub)
-    be = ECBackend.__new__(ECBackend)
-    be.mesh = object()
-    be._mesh_appliers = {}
-    be._mesh_enc_applier = None
-
-    enc = be._mesh_applier(("enc",), lambda: "E")
-    assert be._mesh_applier(("enc",), lambda: "E2") is enc  # cached
-    assert ("enc",) not in be._mesh_appliers                # pinned
-
-    cap = ECBackend._MESH_APPLIER_CAP
-    for i in range(cap):                      # fill to capacity
-        be._mesh_applier(("dec", i), lambda: i)
-    be._mesh_applier(("dec", 0), lambda: 0)   # touch the oldest
-    be._mesh_applier(("dec", cap), lambda: cap)  # overflow by one
-    assert ("dec", 0) in be._mesh_appliers    # recently used: kept
-    assert ("dec", 1) not in be._mesh_appliers  # LRU victim
-    assert len(be._mesh_appliers) == cap
-    # a wide decode burst never evicted the pinned encoder
-    assert be._mesh_applier(("enc",), lambda: "E3") is enc
-
-
 def test_rgw_file_rename_subtree_guards():
     """rename of a directory into its own subtree is EINVAL, and
     rename-to-self is a no-op — both BEFORE the copy+delete loop that

@@ -10,7 +10,9 @@ across the dense GF(2^8) techniques, solo ops and 1-device meshes
 degrade gracefully, device-resident payloads feed sharded launches
 with no host round trip, and CLAY/LRC single-chunk degraded reads move
 counter-verified >= 2x fewer interconnect bytes than whole-chunk
-repair.
+repair.  A running OSD cluster's PG write and shard recovery ride the
+coalescer, and ShardedApplier (the compile-once shard_map apply it
+launches through) matches the codec's encode.
 """
 
 import asyncio
@@ -22,6 +24,7 @@ from ceph_tpu.ec.registry import ErasureCodePluginRegistry
 from ceph_tpu.osd.ec_backend import ECBackend, LocalShard
 from ceph_tpu.osd.mesh_coalesce import (MeshCoalescer, host_coalescer,
                                         reset_host_coalescer)
+from ceph_tpu.parallel.ec_sharding import ShardedApplier, make_ec_mesh
 from ceph_tpu.store.memstore import MemStore
 from ceph_tpu.store.object_store import Transaction
 from ceph_tpu.store.types import CollectionId
@@ -390,3 +393,94 @@ def test_full_write_read_through_host_singleton():
             reset_host_coalescer()
 
     asyncio.run(run())
+
+
+def test_sharded_applier_matches_codec():
+    """ShardedApplier output == codec encode, any batch size (padding
+    path included)."""
+    k = 4
+    codec = ErasureCodePluginRegistry().factory(
+        "jax_rs", {"k": str(k), "m": "2", "technique": "cauchy_good"}
+    )
+    mesh = make_ec_mesh(cs=2)
+    gen = np.asarray(codec.generator, np.uint8)
+    ap = ShardedApplier(mesh, gen[k:])
+    for batch in (1, 3, 8, 13):
+        data = np.random.default_rng(batch).integers(
+            0, 256, (batch, k, 64), np.uint8)
+        want = np.asarray(codec.encode_chunks_batch(data))
+        parity = ap(data)
+        assert np.array_equal(parity, want[:, k:]), f"batch={batch}"
+
+
+def test_cluster_pg_write_and_recovery_ride_the_mesh():
+    """OSD-cluster proof on the 8-device virtual mesh: with
+    osd_ec_mesh_coalesce an EC-pool PG write and a shard recovery run
+    through the host mesh coalescer (mesh_stats move) and stay correct
+    end to end."""
+    from ceph_tpu.common.config import ConfigProxy
+    from tests.test_osd_daemon import start_cluster, wait_active
+
+    def conf():
+        return ConfigProxy(overrides={
+            "mon_lease": 0.4, "mon_lease_interval": 0.1,
+            "mon_election_timeout": 0.3, "mon_tick_interval": 0.1,
+            "mon_accept_timeout": 0.5,
+            "osd_heartbeat_interval": 0.1,
+            "osd_heartbeat_grace": 0.6,
+            "mon_osd_down_out_interval": 30.0,
+            "osd_ec_mesh_coalesce": True,
+        })
+
+    async def run():
+        mon, osds, client = await start_cluster(6, conf_factory=conf,
+                                                pools=[
+            {"prefix": "osd erasure-code-profile set", "name": "p42",
+             "profile": {"plugin": "jax_rs", "k": "4", "m": "2",
+                         "crush-failure-domain": "osd"}},
+            {"prefix": "osd pool create", "pool": "ecm", "pg_num": 4,
+             "pool_type": "erasure", "erasure_code_profile": "p42"},
+        ])
+        pool_id = next(p.pool_id for p in mon.osd_monitor.osdmap
+                       .pools.values() if p.name == "ecm")
+        await wait_active(osds, pool_id)
+
+        payload = bytes(range(256)) * 64      # 16 KiB
+        r = await client.op("ecm", "big", [
+            {"op": "write", "off": 0, "data": payload},
+        ])
+        assert r["rc"] == 0, r
+        r = await client.op("ecm", "big", [{"op": "read", "off": 0}])
+        assert r["results"][0]["data"] == payload
+
+        backends = [pg.backend for osd in osds
+                    for pg in osd.pgs.values()
+                    if pg.pgid.pool == pool_id and pg.backend]
+        assert backends, "no EC backends instantiated"
+        assert all(b.mesh_co is host_coalescer() and b._mesh_dec_ok
+                   for b in backends), \
+            "mesh coalescer not registered on the PG backends"
+        assert sum(b.mesh_stats["encodes"] for b in backends) >= 1, \
+            "write did not ride the sharded plane"
+
+        # recovery: rebuild a lost shard through the mesh decode on
+        # the primary that served the write
+        be = next(b for b in backends if b.mesh_stats["encodes"] >= 1)
+        await be.shards[0].remove_shard("big")
+        d0 = be.mesh_stats["decodes"]
+        await be.recover_shard("big", [0])
+        assert be.mesh_stats["decodes"] > d0, \
+            "recovery did not ride the sharded plane"
+        r = await client.op("ecm", "big", [{"op": "read", "off": 0}])
+        assert r["results"][0]["data"] == payload
+
+        await client.shutdown()
+        for o in osds:
+            await o.shutdown()
+        await mon.shutdown()
+
+    reset_host_coalescer()
+    try:
+        asyncio.run(run())
+    finally:
+        reset_host_coalescer()

@@ -4,7 +4,8 @@ The residency tier must be invisible to clients: corpus-profile
 bit-identity through the device-resident write/read path, a full
 write -> evict -> read-back cycle landing on the store copy, coalesced
 launches with mixed resident/non-resident batchmates, and the cache's
-LRU/watermark/spill/flush mechanics (dirty data is never dropped).
+LRU/watermark mechanics (every entry is a copy of store bytes, so
+eviction only drops).
 """
 
 import asyncio
@@ -76,7 +77,7 @@ def test_cache_lru_watermark_eviction():
     cache.get("pg", "o0", 0)              # o0 becomes most-recent
     cache.put("pg", "o4", 0, _arr(256, 4), version=1)
     assert cache.over_high
-    _run(cache.evict())
+    cache.evict()
     assert cache.bytes <= 512
     assert cache.get("pg", "o0", 0) is not None   # refreshed, survived
     assert cache.get("pg", "o1", 0) is None       # LRU, evicted
@@ -84,49 +85,6 @@ def test_cache_lru_watermark_eviction():
     st = cache.stats()
     assert st["entries"] == 2 and st["evictions"] == 3
     assert st["hits"] == 2 and st["misses"] == 1
-
-
-def test_cache_dirty_spill_on_evict_and_flush():
-    """Dirty entries spill (host bytes reach the callback) before
-    dropping; flush persists without dropping and marks clean; a
-    failing spill never loses the only copy."""
-    spilled = {}
-
-    async def spill(oid, shard, host):
-        spilled[(oid, shard)] = bytes(host)
-
-    async def bad_spill(oid, shard, host):
-        raise OSError("store degraded")
-
-    cache = DeviceShardCache(max_bytes=512, low_watermark=0.5)
-    cache.put("pg", "a", 0, _arr(256, 7), version=1,
-              dirty=True, spill=spill)
-    cache.put("pg", "b", 0, _arr(256, 9), version=1,
-              dirty=True, spill=spill)
-    _run(cache.flush())
-    assert spilled[("a", 0)] == b"\x07" * 256
-    assert spilled[("b", 0)] == b"\x09" * 256
-    st = cache.stats()
-    assert st["entries"] == 2 and st["dirty_entries"] == 0
-
-    # dirty again, then evict: spill fires before the drop
-    spilled.clear()
-    cache.put("pg", "a", 0, _arr(256, 8), version=2,
-              dirty=True, spill=spill)
-    cache.put("pg", "c", 0, _arr(256, 1), version=1,
-              dirty=True, spill=spill)
-    assert cache.over_high
-    _run(cache.evict(target=0))
-    assert spilled[("a", 0)] == b"\x08" * 256
-    assert cache.stats()["entries"] == 0
-
-    # failing spill: evict skips the entry, flush raises after trying all
-    cache.put("pg", "d", 0, _arr(256, 3), version=1,
-              dirty=True, spill=bad_spill)
-    _run(cache.evict(target=0))
-    assert cache.get("pg", "d", 0, count=False) is not None
-    with pytest.raises(OSError):
-        _run(cache.flush())
 
 
 def test_cache_drop_scopes_and_bump_version():
@@ -163,19 +121,18 @@ def test_resident_corpus_payload_bit_identical(profile):
         payload = _payload()
         await be.write("corpus", payload)
         assert await be.read("corpus") == payload      # cache-served
-        await be.resident.evict(target=0)
+        be.resident.evict(target=0)
         assert await be.read("corpus") == payload      # store-served
 
     _run(run())
 
 
 def test_resident_write_evict_readback_cycle():
-    """write -> sub-stripe overwrite -> evict -> read-back, in both
-    write-through and write-back modes; the overwrite uploads its one
-    stripe (old bytes spliced with the client's on the host), once."""
-    async def run(writeback):
-        be = await _backend(resident=True, resident_writeback=writeback)
-        assert be.resident_writeback is writeback
+    """write -> sub-stripe overwrite -> evict -> read-back; the
+    overwrite uploads its one stripe (old bytes spliced with the
+    client's on the host), once."""
+    async def run():
+        be = await _backend(resident=True)
         data = bytearray(bytes(range(256)) * 16)       # 4 KiB, 8 stripes
         await be.write("cyc", bytes(data))
         h2d0 = be.perf.value("ec_resident_h2d_bytes")
@@ -186,15 +143,13 @@ def test_resident_write_evict_readback_cycle():
         assert be.perf.value("ec_resident_h2d_bytes") - h2d0 == \
             be.sinfo.stripe_width == 512
         assert await be.read("cyc") == bytes(data)
-        await be.flush_resident()
-        await be.resident.evict(target=0)
+        be.resident.evict(target=0)
         assert be.resident.stats()["entries"] == 0
         assert await be.read("cyc") == bytes(data)     # store copy
         st = be.resident_stats()
         assert st["enabled"] and st["evictions"] >= be.k
 
-    _run(run(False))
-    _run(run(True))
+    _run(run())
 
 
 def test_resident_remove_and_version_coherence():
@@ -211,6 +166,29 @@ def test_resident_remove_and_version_coherence():
         await be.write("attr", b"\x17" * 1024)
         await be.set_attr("attr", "user.x", b"y")      # bumps version
         assert await be.read("attr") == b"\x17" * 1024
+
+    _run(run())
+
+
+def test_resident_read_serves_only_version_matched():
+    """A resident entry serves a shard read only at its own version: a
+    raw read (version=None) and a read at another version fall through
+    to the store (None), and a matched read returns the cached range."""
+    async def run():
+        be = await _backend(resident=True)
+        data = bytes(range(256)) * 4
+        meta = await be.write("v", data)
+        clen = be.sinfo.logical_to_next_chunk_offset(len(data))
+        stored = await be.shards[0].read_shard("v")
+        hit = be._resident_read(0, "v", 0, clen, clen, meta.version)
+        assert hit is not None
+        assert np.asarray(hit).tobytes() == stored[:clen]
+        assert be._resident_read(0, "v", 0, clen, clen, None) is None
+        assert be._resident_read(
+            0, "v", 0, clen, clen, meta.version + 1) is None
+        # the raw read still returns the store bytes
+        raw = await be._read_shard_range(0, "v", 0, clen, clen)
+        assert raw.tobytes() == stored[:clen]
 
     _run(run())
 
@@ -304,33 +282,69 @@ async def _stored(be, oid):
     return out
 
 
-@pytest.mark.parametrize("writeback", [False, True],
-                         ids=["write_through", "write_back"])
+async def _write_beside_batchmate(be, oid, data, off, mate, mate_data):
+    """Write ``oid`` and ``mate`` concurrently on one resident backend so
+    their encodes share ONE coalesced launch, ``mate`` first: ``oid``'s
+    shard streams are split out at a nonzero stripe offset.  The extra
+    in-flight count holds the flush until both have parked (the
+    backend's window is long enough not to fire first)."""
+    co = be.coalescer
+    st0 = co.stats()
+    be._inflight_ops += 1
+
+    async def after_mate_parks():
+        while co._npending < 1:
+            await asyncio.sleep(0)
+        await be.write(oid, data, offset=off)
+
+    async def release_when_both_park():
+        try:
+            while co._npending < 2:
+                await asyncio.sleep(0)
+        finally:
+            be._inflight_ops -= 1
+            co.notify()
+
+    await asyncio.gather(be.write(mate, mate_data), after_mate_parks(),
+                         release_when_both_park())
+    st = co.stats()
+    assert (st["launches"] - st0["launches"],
+            st["ops"] - st0["ops"]) == (1, 2), (st0, st)
+
+
+@pytest.mark.parametrize("launch", ["solo", "shared"])
 @pytest.mark.parametrize("case", list(WRITE_CASES))
 @pytest.mark.parametrize(
     "profile", WRITE_PROFILES,
     ids=lambda p: f"k{p['k']}m{p['m']}_{p['technique']}")
-def test_fused_write_matches_host_path(profile, case, writeback):
+def test_fused_write_matches_host_path(profile, case, launch):
     """The resident write path (host-built stripes, one launch, one
     jitted split) against the non-resident host path on the same
     writes: the stores hold the same shard bytes, version and hinfo
     attrs, every shard's resident entry holds its store bytes, and both
-    read back the same object."""
+    read back the same object.  ``shared``: each resident write shares
+    its launch with a batchmate write of another size, launched first,
+    so the object's streams are split out at a nonzero offset."""
     async def run():
         res = await _backend(profile, resident=True,
-                             resident_writeback=writeback)
+                             coalesce_window_us=30e6)
         host = await _backend(profile, resident=False)
         sw = res.sinfo.stripe_width
         rng = np.random.default_rng(7)
         want = bytearray()
         writes = WRITE_CASES[case](sw)
-        for off, n in writes:
+        for j, (off, n) in enumerate(writes):
             data = rng.integers(0, 256, n, np.uint8).tobytes()
-            for be in (res, host):
-                await be.write("obj", data, offset=off)
+            if launch == "shared":
+                mate = bytes([j + 1]) * (2 * sw + 3)
+                await _write_beside_batchmate(res, "obj", data, off,
+                                              f"mate{j}", mate)
+                assert await res.read(f"mate{j}") == mate
+            else:
+                await res.write("obj", data, offset=off)
+            await host.write("obj", data, offset=off)
             want[len(want):off + n] = b"\0" * max(0, off + n - len(want))
             want[off:off + n] = data
-        await res.flush_resident()
         stored = await _stored(host, "obj")
         assert await _stored(res, "obj") == stored
         for i in range(res.n):
@@ -339,7 +353,8 @@ def test_fused_write_matches_host_path(profile, case, writeback):
             assert np.asarray(ent.arr).tobytes() == stored[i][0]
         assert await res.read("obj") == bytes(want)
         assert await host.read("obj") == bytes(want)
-        assert res.resident_stats()["ec_write_glue_fused"] == len(writes)
+        fused = len(writes) * (2 if launch == "shared" else 1)
+        assert res.resident_stats()["ec_write_glue_fused"] == fused
         assert host.perf.value("ec_write_glue_fused") == 0
 
     _run(run())

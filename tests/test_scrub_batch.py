@@ -183,7 +183,7 @@ def test_warm_resident_scrub_zero_h2d():
         assert be.perf.value("ec_resident_h2d_bytes") - h2d0 == 0
         # evicted entries fall back to store reads — still clean, but
         # the cold path pays the transfer again
-        await be.resident.evict(target=0)
+        be.resident.evict(target=0)
         reports = (await be.scrub_batch(sorted(datas)))["reports"]
         assert all(r["clean"] for r in reports.values())
         assert be.perf.value("ec_resident_h2d_bytes") > h2d0
