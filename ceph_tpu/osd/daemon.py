@@ -1877,6 +1877,14 @@ class OSDDaemon:
             pg.backend = None       # replicated path works on the store
 
     # -- peering (primary) ---------------------------------------------------
+    @staticmethod
+    def _forget_metas(pg: PG) -> None:
+        """At activation: peering's recovery and log rewind rewrite
+        shards behind the backend (a rewind can lower an object's max
+        version), so no metadata learned before may serve a client op."""
+        if pg.backend is not None:
+            pg.backend.meta_cache.clear()
+
     def _notify_stray(self, pg: PG, pgid: PGId, primary: int) -> None:
         entries, tail = pg_log.read_log(self.store, pgid.pool, pgid.ps)
         try:
@@ -2102,6 +2110,7 @@ class OSDDaemon:
                         "pgid": [pg.pgid.pool, pg.pgid.ps],
                         "epoch": epoch,
                     }, priority=PRIO_HIGH))
+                self._forget_metas(pg)
                 pg.state = STATE_ACTIVE
                 self.journal.emit("pg.state", epoch=epoch,
                                   pgid=str(pg.pgid), state="active",
@@ -2132,6 +2141,7 @@ class OSDDaemon:
             for shard, osd in pg.acting_peers():
                 self._send_osd(osd, Message("pg_activate", dict(merge),
                                             priority=PRIO_HIGH))
+            self._forget_metas(pg)
             pg.state = STATE_ACTIVE
             self.journal.emit("pg.state", epoch=epoch,
                               pgid=str(pg.pgid), state="active")

@@ -139,10 +139,99 @@ class ECWriteDegraded(ShardReadError):
     scheduled), distinct from an unrecoverable >m failure."""
 
 
-@dataclass
+@dataclass(frozen=True)
 class ECObjectMeta:
     size: int               # logical object size
     version: int
+
+
+# objects whose metadata one backend keeps: the default of Ceph's
+# osd_pg_object_context_cache_count (a backend is one PG's primary)
+META_CACHE_OBJECTS = 64
+
+# MetaCache.get's answer for an object it does not hold (None is an
+# object affirmed absent)
+MISS = object()
+
+
+class MetaCache:
+    """Each object's ``_read_meta`` answer, (size, version) or None for
+    an affirmed absence, kept by the primary for its interval (the
+    object_info_t of reference ObjectContext, which ``on_change``
+    clears).  Every mutation of the PG's objects flows through this one
+    backend, and the daemon builds a new backend at each interval change
+    and clears this at activation, so the max version over the shards
+    moves only through the backend's own mutations: a settled one
+    ``put``s its result, an unsettled one ``drop``s the entry.  Recovery
+    and scrub repair copy the authoritative attrs and change no answer;
+    peering's log rewind, which can, comes before the activation clear.
+    LRU-bounded by object count.
+
+    Scrub and recovery read metadata outside the object lock, so a
+    fan-in may overlap a mutation it does not order against: a fill
+    captures ``begin_fill``'s token and ``end_fill`` keeps its answer
+    only if no ``put``/``drop``/``clear`` of the object landed in
+    between (the role of ExtentCache's generation).  Only objects with
+    a fill in flight keep a counter."""
+
+    def __init__(self, max_objects: int = META_CACHE_OBJECTS):
+        from collections import OrderedDict
+
+        self.max_objects = max_objects
+        self._metas: "OrderedDict[str, ECObjectMeta | None]" = OrderedDict()
+        # oid -> [fills in flight, mutations since the oldest began]
+        self._fills: dict[str, list[int]] = {}
+
+    def __len__(self) -> int:
+        return len(self._metas)
+
+    def get(self, oid: str):
+        """The object's cached metadata (None: absent), or MISS."""
+        meta = self._metas.get(oid, MISS)
+        if meta is not MISS:
+            self._metas.move_to_end(oid)
+        return meta
+
+    def begin_fill(self, oid: str) -> int:
+        slot = self._fills.setdefault(oid, [0, 0])
+        slot[0] += 1
+        return slot[1]
+
+    def end_fill(self, oid: str, token: int, meta=MISS) -> None:
+        """Close a fill; keep ``meta`` (MISS: nothing to keep) unless a
+        mutation superseded it since ``begin_fill`` gave ``token``."""
+        slot = self._fills[oid]
+        slot[0] -= 1
+        if not slot[0]:
+            del self._fills[oid]
+        if meta is not MISS and slot[1] == token:
+            self._store(oid, meta)
+
+    def put(self, oid: str, meta: ECObjectMeta | None) -> None:
+        """A settled mutation's result."""
+        self._supersede(oid)
+        self._store(oid, meta)
+
+    def drop(self, oid: str) -> None:
+        """A mutation whose outcome on the shards is not settled."""
+        self._supersede(oid)
+        self._metas.pop(oid, None)
+
+    def clear(self) -> None:
+        for slot in self._fills.values():
+            slot[1] += 1
+        self._metas.clear()
+
+    def _supersede(self, oid: str) -> None:
+        slot = self._fills.get(oid)
+        if slot is not None:
+            slot[1] += 1
+
+    def _store(self, oid: str, meta: ECObjectMeta | None) -> None:
+        self._metas[oid] = meta
+        self._metas.move_to_end(oid)
+        if len(self._metas) > self.max_objects:
+            self._metas.popitem(last=False)
 
 
 class ExtentCache:
@@ -560,6 +649,9 @@ class ECBackend:
             raise ValueError(f"need shards 0..{self.n - 1}")
         self._repair_tasks: set[asyncio.Task] = set()
         self.extent_cache = ExtentCache()
+        # each object's (size, version) for the interval: _read_meta
+        # answers from it without a fan-in to every shard
+        self.meta_cache = MetaCache()
         # oid -> shards known stale from a failed mutation: a subsequent
         # write must heal them FIRST — otherwise its version bump would
         # make the stale shard pass the per-object version check and
@@ -603,9 +695,11 @@ class ECBackend:
         # jitted program, and the split programs compiled
         # (_note_split_shapes);
         # ec_rmw_gather_skipped: writes whose new bytes covered every
-        # surviving byte of their stripes, so nothing was read back
+        # surviving byte of their stripes, so nothing was read back;
+        # ec_meta_cache_*: _read_meta calls answered by meta_cache, and
+        # those that fanned in to the shards
         for _k in ("hedge_issued", "hedge_won", "hedge_lost",
-                   "hedge_meta",
+                   "hedge_meta", "ec_meta_cache_hit", "ec_meta_cache_miss",
                    "ec_coalesce_launches", "ec_coalesce_ops",
                    "ec_coalesce_pad_waste", "ec_device_launches",
                    "ec_launch_bytes",
@@ -1238,31 +1332,49 @@ class ECBackend:
         missed a degraded write serve a stale version as authoritative,
         inverting the stale-shard check (fresh shards would then fail
         version verification). The peering-time authoritative-version
-        choice, applied per read."""
-        results = await self._attr_all(oid, VERSION_ATTR, hedged=True)
-        best: ECObjectMeta | None = None
-        errors = []
-        absent = False
-        for i, r in enumerate(results):
-            if isinstance(r, KeyError):
-                absent = True
-            elif isinstance(r, BaseException):
-                errors.append((i, r))
-            else:
-                try:
-                    d = json.loads(r)
-                    meta = ECObjectMeta(int(d["size"]), int(d["version"]))
-                except (ValueError, TypeError, KeyError):
-                    continue
-                if best is None or meta.version > best.version:
-                    best = meta
-        if best is not None:
-            return best
-        if absent:
-            return None
-        raise ShardReadError(
-            f"all shards unreachable reading meta of {oid}: {errors}"
-        )
+        choice, applied per read.
+
+        Within the interval ``meta_cache`` answers without the fan-in.
+        A fan-in fills it with a version found, and with an absence only
+        when no shard was unreachable: one that was might hold it."""
+        cached = self.meta_cache.get(oid)
+        if cached is not MISS:
+            self.perf.inc("ec_meta_cache_hit")
+            return cached
+        self.perf.inc("ec_meta_cache_miss")
+        token = self.meta_cache.begin_fill(oid)
+        fill = MISS
+        try:
+            results = await self._attr_all(oid, VERSION_ATTR, hedged=True)
+            best: ECObjectMeta | None = None
+            errors = []
+            absent = False
+            for i, r in enumerate(results):
+                if isinstance(r, KeyError):
+                    absent = True
+                elif isinstance(r, BaseException):
+                    errors.append((i, r))
+                else:
+                    try:
+                        d = json.loads(r)
+                        meta = ECObjectMeta(int(d["size"]),
+                                            int(d["version"]))
+                    except (ValueError, TypeError, KeyError):
+                        continue
+                    if best is None or meta.version > best.version:
+                        best = meta
+            if best is not None:
+                fill = best
+                return best
+            if absent:
+                if not errors:
+                    fill = None
+                return None
+            raise ShardReadError(
+                f"all shards unreachable reading meta of {oid}: {errors}"
+            )
+        finally:
+            self.meta_cache.end_fill(oid, token, fill)
 
     @staticmethod
     def _meta_attr(meta: ECObjectMeta) -> bytes:
@@ -1392,11 +1504,14 @@ class ECBackend:
             except BaseException:
                 # unsettled on-disk outcome (failure OR cancellation
                 # mid-gather, when a subset of shards already hold the
-                # new bytes): cached extents can no longer be trusted
+                # new bytes): cached extents can no longer be trusted,
+                # nor the cached version
                 self.extent_cache.invalidate(oid)
+                self.meta_cache.drop(oid)
                 if self.resident is not None:
                     self.resident.drop_object(self.resident_ns, oid)
                 raise
+            self.meta_cache.put(oid, ECObjectMeta(new_size, new_version))
             if self.resident is not None:
                 self._resident_install(
                     oid, shard_off, shards, new_version, old_size)
@@ -1556,11 +1671,15 @@ class ECBackend:
             if isinstance(r, KeyError)
         )
         if absent >= self.k:
-            for i in shards:
-                try:
-                    await self.shards[i].remove_shard(oid, log=entry)
-                except KeyError:
-                    pass
+            try:
+                for i in shards:
+                    try:
+                        await self.shards[i].remove_shard(oid, log=entry)
+                    except KeyError:
+                        pass
+            finally:
+                # the stragglers' versions leave the fan-in's answer
+                self.meta_cache.drop(oid)
             return
         await self.recover_shard(oid, shards)
         if entry is not None:
@@ -1942,11 +2061,6 @@ class ECBackend:
                     await self.shards[i].remove_shard(oid, log=entry)
                 except KeyError:
                     pass            # already absent on this shard
-            results = await asyncio.gather(
-                *(rm(i) for i in range(self.n)), return_exceptions=True
-            )
-            failed = [i for i, r in enumerate(results)
-                      if isinstance(r, BaseException)]
 
             async def heal(live):
                 for i in live:
@@ -1955,8 +2069,19 @@ class ECBackend:
                                                           log=entry)
                     except KeyError:
                         pass
-            await self._settle_write_failures("remove", oid, failed,
-                                              heal, entry)
+            try:
+                results = await asyncio.gather(
+                    *(rm(i) for i in range(self.n)),
+                    return_exceptions=True
+                )
+                failed = [i for i, r in enumerate(results)
+                          if isinstance(r, BaseException)]
+                await self._settle_write_failures("remove", oid, failed,
+                                                  heal, entry)
+            except BaseException:
+                self.meta_cache.drop(oid)
+                raise
+            self.meta_cache.put(oid, None)
             self._dirty.pop(oid, None)  # nothing left to be stale about
 
     async def set_attr(self, oid: str, name: str, value: bytes,
@@ -1978,17 +2103,23 @@ class ECBackend:
             entry = (self.log_hook(oid, "modify", new_meta.version,
                                    meta.version if meta else 0, reqid)
                      if self.log_hook else None)
-            results = await asyncio.gather(*(
-                self.shards[i].write_shard(oid, 0, b"", attrs, log=entry)
-                for i in range(self.n)
-            ), return_exceptions=True)
-            failed = [i for i, r in enumerate(results)
-                      if isinstance(r, BaseException)]
-            await self._settle_write_failures(
-                "set_attr", oid, failed,
-                lambda live: self._heal_shards(oid, live, entry),
-                entry,
-            )
+            try:
+                results = await asyncio.gather(*(
+                    self.shards[i].write_shard(oid, 0, b"", attrs,
+                                               log=entry)
+                    for i in range(self.n)
+                ), return_exceptions=True)
+                failed = [i for i, r in enumerate(results)
+                          if isinstance(r, BaseException)]
+                await self._settle_write_failures(
+                    "set_attr", oid, failed,
+                    lambda live: self._heal_shards(oid, live, entry),
+                    entry,
+                )
+            except BaseException:
+                self.meta_cache.drop(oid)
+                raise
+            self.meta_cache.put(oid, new_meta)
             if self.resident is not None:
                 # shard data is untouched; restamp resident entries so
                 # version-matched reads keep hitting
