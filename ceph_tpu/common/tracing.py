@@ -26,6 +26,8 @@ event loop's thread it is what the loop was doing: ``msgr:encode``,
 ``ec:d2h``, ``ec:hinfo``, ``store:apply``, ``store:read``.  A *wait*
 span encloses awaits:
 ``osd:queue``, ``osd:obj_wait``, ``osd:fanout``, ``ec:coalesce_wait``,
+``ec:repair`` (a degraded read's rebuild, from its plan to the rebuilt
+chunk, its byte counts set as tags at its end through ``set_metadata``),
 and ``ec:launch`` on the worker thread that runs the codec call.
 
 With no capture running and the op not sampled, a span site costs one
@@ -157,6 +159,9 @@ class _NullSpan:
         return False
 
     def end(self) -> None:
+        pass
+
+    def set_metadata(self, **tags) -> None:
         pass
 
 

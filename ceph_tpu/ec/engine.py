@@ -258,28 +258,48 @@ class BitplaneEngine:
             return _apply_bitmatrix(mat, data[None])[0]
         return _apply_bitmatrix(mat, data)
 
+    def _word_applier(self, coeff: np.ndarray):
+        """The word kernel for ``coeff``: the grouped applier where
+        ``GroupedPlan.profitable``, else the dense shard applier where it
+        fits, else None (the bitplane einsum)."""
+        from ceph_tpu.ec.pallas_kernels import shard_kernel_supported
+
+        if self.use_pallas:
+            grouped = self._grouped_applier(coeff)
+            if grouped is not None:
+                return grouped
+            if shard_kernel_supported(coeff.shape[1], coeff.shape[0]):
+                return self._pallas_applier(coeff)
+        return None
+
     def apply_words(self, coeff: np.ndarray, words) -> jax.Array:
         """Word-typed hot path: (k, N4) int32 lanes -> (m, N4) int32.
 
         Device-resident buffers stay int32 end-to-end (no uint8 relayout
         pass); use pallas_kernels.bytes_to_words/words_to_bytes at the
         boundaries."""
-        from ceph_tpu.ec.pallas_kernels import (
-            bytes_to_words,
-            shard_kernel_supported,
-            words_to_bytes,
-        )
+        from ceph_tpu.ec.pallas_kernels import bytes_to_words, words_to_bytes
 
         coeff = np.asarray(coeff, np.uint8)
-        if self.use_pallas:
-            grouped = self._grouped_applier(coeff)
-            if grouped is not None:
-                return grouped.apply_words(jnp.asarray(words))
-            if shard_kernel_supported(coeff.shape[1], coeff.shape[0]):
-                return self._pallas_applier(coeff).apply_words(words)
+        applier = self._word_applier(coeff)
+        if applier is not None:
+            return applier.apply_words(jnp.asarray(words))
         mat = self._device_bitmatrix(coeff)
         by = words_to_bytes(jnp.asarray(words))
         return bytes_to_words(_apply_bitmatrix(mat, by[None])[0])
+
+    def operator(self, coeff: np.ndarray) -> tuple:
+        """``coeff`` prepared for :func:`apply_operator` inside a caller's
+        jitted program: ``(spec, operands)``, the spec static (the kernel
+        ``apply_words`` picks, and its geometry) and the operands device
+        arrays passed at run time, so every matrix of one spec shares one
+        compiled program whatever its coefficients (the grouped kernel in
+        its paired form: its column gather is an operand too)."""
+        coeff = np.asarray(coeff, np.uint8)
+        applier = self._word_applier(coeff)
+        if applier is not None:
+            return applier.operator()
+        return ("xla",), (self._device_bitmatrix(coeff),)
 
     def _device_raw_bitmatrix(self, BM: np.ndarray) -> jax.Array:
         from ceph_tpu.common.jaxutil import outside_trace
@@ -361,3 +381,15 @@ _NOT_GROUPABLE = object()
 @functools.cache
 def default_engine() -> BitplaneEngine:
     return BitplaneEngine()
+
+
+def apply_operator(op: tuple, words) -> jax.Array:
+    """(kin, N4) int32 lanes -> (mout, N4) int32 by the ``(spec,
+    operands)`` of :meth:`BitplaneEngine.operator`.  Traceable."""
+    from ceph_tpu.ec import pallas_kernels as pk
+
+    (kind, *_), operands = op
+    if kind != "xla":
+        return pk.apply_operator(op, words)
+    by = bitplane_apply(operands[0], pk.words_to_bytes(words)[None])[0]
+    return pk.bytes_to_words(by)

@@ -477,6 +477,43 @@ def _pallas_apply_grouped(bms, gath, *, tile, grp_rows, interpret=False):
     )(bms, gath)
 
 
+def apply_operator(op: tuple, words) -> jax.Array:
+    """(kin, N4) int32 -> (mout, N4) int32 by an applier's
+    ``operator()``: the kernel its static spec names, its operands passed
+    at run time.  Traceable (a caller's jit takes the spec as a static
+    argument and the operands as arguments); N4 pads to a LANE multiple
+    and back."""
+    (kind, *spec), operands = op
+    kin, n4 = words.shape
+    pad = (-n4) % LANE
+    if kind == "grouped":
+        grp, interpret = spec
+        bms, cols, rows = operands
+        if pad:
+            words = jnp.pad(words, ((0, 0), (0, pad)))
+        out = _pallas_apply_grouped(
+            bms, words[cols], tile=_pick_gtile(n4 + pad, cols.shape[1], grp),
+            grp_rows=grp, interpret=interpret)[rows]
+        return out[:, :n4] if pad else out
+    kblk, kpad, interpret = spec
+    (bm32,) = operands
+    mout = bm32.shape[0] // 32
+    if pad or kpad != kin:
+        words = jnp.pad(words, ((0, kpad - kin), (0, pad)))
+    # variant dispatch: alternate kernel formulations cover only the
+    # unblocked contraction (kblocks == 1); blocked matrices keep the
+    # production kernel
+    if _encode_variant and kblk == kin:
+        out = _pallas_apply_words_variant(
+            bm32, words, tile=_pick_tile(n4 + pad, mout),
+            variant=_encode_variant, interpret=interpret)
+    else:
+        out = _pallas_apply_words(
+            bm32, words, tile=_pick_tile(n4 + pad, mout), kblk=kblk,
+            interpret=interpret)
+    return out[:, :n4] if pad else out
+
+
 class PallasGroupedApply:
     """Sparse-grouped variant of PallasShardApply for repair operators.
 
@@ -492,6 +529,8 @@ class PallasGroupedApply:
             raise ValueError("matrix too dense for the grouped kernel")
         self.mout, self.kin = self.plan.mout, self.plan.kin
         self._bms_dev: jax.Array | None = None
+        self._cols_dev: jax.Array | None = None
+        self._rows_dev: jax.Array | None = None
         self.interpret = interpret
 
     def _bms_arg(self):
@@ -525,14 +564,20 @@ class PallasGroupedApply:
                 )
                 out = out[plan.gather_rows]
                 return out[:, :n4] if pad else out
-        gath = words[plan.cols]             # (G, cmax, N4)
-        tile = _pick_gtile(n4 + pad, plan.cmax, plan.GRP_ROWS)
-        out = _pallas_apply_grouped(
-            self._bms_arg(), gath, tile=tile,
-            grp_rows=plan.GRP_ROWS, interpret=self.interpret,
-        )
-        out = out[plan.gather_rows]
+        out = apply_operator(self.operator(), words)
         return out[:, :n4] if pad else out
+
+    def operator(self) -> tuple:
+        """(spec, operands) for :func:`apply_operator`: the paired
+        kernel, whose column gather and output row order are run-time
+        operands too, so every matrix of one group geometry shares one
+        compiled program (the fused kernel above selects its columns
+        statically: one program per matrix)."""
+        cols, self._cols_dev = _device_cached(self.plan.cols, self._cols_dev)
+        rows, self._rows_dev = _device_cached(
+            self.plan.gather_rows.astype(np.int32), self._rows_dev)
+        return (("grouped", self.plan.GRP_ROWS, self.interpret),
+                (self._bms_arg(), cols, rows))
 
     def __call__(self, data) -> jax.Array:
         data = jnp.asarray(data, jnp.uint8)
@@ -587,25 +632,12 @@ class PallasShardApply:
         kin, n4 = words.shape
         if kin != self.kin:
             raise ValueError(f"expected {self.kin} chunk rows, got {kin}")
-        pad = (-n4) % LANE
-        rpad = self.kpad - self.kin
-        if pad or rpad:
-            words = jnp.pad(words, ((0, rpad), (0, pad)))
-        # variant dispatch: alternate kernel formulations cover only the
-        # unblocked contraction (kblocks == 1); blocked matrices keep
-        # the production kernel
-        variant = _encode_variant
-        if variant and self.kblk == self.kin:
-            out = _pallas_apply_words_variant(
-                self._bm32_arg(), words, tile=_pick_tile(n4 + pad, self.mout),
-                variant=variant, interpret=self.interpret,
-            )
-            return out[:, :n4] if pad else out
-        out = _pallas_apply_words(
-            self._bm32_arg(), words, tile=_pick_tile(n4 + pad, self.mout),
-            kblk=self.kblk, interpret=self.interpret,
-        )
-        return out[:, :n4] if pad else out
+        return apply_operator(self.operator(), words)
+
+    def operator(self) -> tuple:
+        """(spec, operands) for :func:`apply_operator`."""
+        return (("dense", self.kblk, self.kpad, self.interpret),
+                (self._bm32_arg(),))
 
     def apply_bytes(self, data) -> jax.Array:
         """(k, N) uint8 byte streams -> (m, N) uint8 parity streams."""

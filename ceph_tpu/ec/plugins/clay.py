@@ -16,23 +16,43 @@ Behavioral mirror of reference src/erasure-code/clay/ErasureCodeClay.{h,cc}:
   helpers (repair_one_lost_chunk ErasureCodeClay.cc:462-646;
   get_repair_subchunks :366-380) — the regenerating-code bandwidth saving.
 
-TPU-first formulation: chunks live as (nodes, planes, sc_size) uint8
-arrays; the pairwise transforms are exact GF(2^8) table lookups vectorized
-over planes x bytes, and every plane of the same intersection score shares
-one erasure pattern, so their MDS decodes batch into a single bitplane-
-engine call (the device hot path) instead of the reference's per-plane
-scalar decode.
+Two formulations of the same code:
+
+- Host (``encode_chunks`` / ``decode_chunks`` / ``decode``, and
+  ``_encode_batch`` / ``_decode_host`` under them): chunks live as
+  (nodes, planes, sc_size) uint8 arrays; the pairwise transforms are
+  exact GF(2^8) table lookups vectorized over planes x bytes, and every
+  plane of one intersection score shares one erasure pattern, so their
+  MDS decodes batch into one engine call.
+- Device (``encode_chunks_device`` / ``decode_chunks_device`` /
+  ``repair_chunks_device``, and the ``*_batch`` entry points ECBackend
+  calls, which run them on host arrays): the whole schedule is
+  GF(2^8)-linear over sub-chunks and never mixes byte positions, so it
+  is one matrix over (chunk, plane) symbols.  Each pattern's matrix is
+  probed once per codec from the host schedule (identity payload along
+  the byte axis, as ``ec/repair_operator.py`` probes repair) and applied
+  by one jitted program with stripes x sub-chunk bytes on the lane axis,
+  through ``BitplaneEngine.operator`` (the sparse grouped kernel where it
+  pays, its operands passed at run time so one program serves every
+  pattern of a geometry).  Bit-identical to the host schedule by
+  construction; the tests hold both to ``ec/reference_clay.py``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Mapping, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ceph_tpu.common.cache import FIFOCache
 from ceph_tpu.ec.base import ErasureCode
+from ceph_tpu.ec.engine import apply_operator, default_engine
 from ceph_tpu.ec.gf import GF_INV_TABLE, GF_MUL_TABLE, gf_inv_matrix
 from ceph_tpu.ec.interface import SubChunkRanges
+from ceph_tpu.ec.pallas_kernels import bytes_to_words, words_to_bytes
 from ceph_tpu.ec.registry import ErasureCodePluginRegistry
 
 DEFAULT_K = 4
@@ -52,6 +72,57 @@ _ISA_TECHNIQUES = {"reed_sol_van": "isa_vandermonde", "cauchy": "isa_cauchy"}
 def _mul(coeff: int, data: np.ndarray) -> np.ndarray:
     """Constant-by-region GF(2^8) multiply (table row lookup)."""
     return GF_MUL_TABLE[coeff][data]
+
+
+def _apply_sub(op, chunks, sub: int):
+    """(n, B, C) uint8 chunks -> (r, B, C): the operator over (chunk,
+    plane) symbols.  Rows are symbols and lanes (word of a sub-chunk,
+    stripe): a batched 2-D transpose of int32 words, no uint8 relayout
+    beyond the byte/lane conversion the RS path runs too.  Sub-chunks of
+    a byte count that is not a whole number of words are zero-padded to
+    one and trimmed back (the operator never mixes byte positions)."""
+    n, b, c = chunks.shape
+    sc = c // sub
+    sc4 = -(-sc // 4) * 4
+    if sc4 != sc:
+        chunks = jnp.pad(chunks.reshape(n, b, sub, sc),
+                         ((0, 0), (0, 0), (0, 0), (0, sc4 - sc)))
+    q4 = sc4 // 4
+    w = bytes_to_words(chunks.reshape(n, b * sub * sc4))
+    w = jnp.transpose(w.reshape(n, b, sub * q4), (0, 2, 1))
+    out = apply_operator(op, w.reshape(n * sub, q4 * b))
+    r = out.shape[0] // sub
+    out = jnp.transpose(out.reshape(r, sub * q4, b), (0, 2, 1))
+    out = words_to_bytes(out.reshape(r, b * sub * q4))
+    if sc4 != sc:
+        out = out.reshape(r, b, sub, sc4)[..., :sc]
+    return out.reshape(r, b, c)
+
+
+_program = functools.partial(jax.jit, static_argnames=("spec", "sub"))
+
+
+@_program
+def _encode_program(operands, data, *, spec, sub):
+    """(B, k, C) data -> (B, k+m, C) chunks."""
+    parity = _apply_sub((spec, operands), jnp.transpose(data, (1, 0, 2)),
+                        sub)
+    return jnp.concatenate([data, jnp.transpose(parity, (1, 0, 2))], axis=1)
+
+
+@_program
+def _decode_program(operands, chunks, *, spec, sub):
+    """A tuple of (B, C) chunks -> (B, wanted, C)."""
+    out = _apply_sub((spec, operands), jnp.stack(chunks), sub)
+    return jnp.transpose(out, (1, 0, 2))
+
+
+@_program
+def _repair_program(operands, planes, *, spec, sub):
+    """(d, B, P*sc) helper repair planes -> the rebuilt (B, S*sc) chunk:
+    the operator's output rows are the lost chunk's S planes in order."""
+    out = _apply_sub((spec, operands), planes, sub)
+    return jnp.transpose(out, (1, 0, 2)).reshape(planes.shape[1], -1)
 
 
 class _PairwiseTransform:
@@ -124,6 +195,8 @@ class ErasureCodeClay(ErasureCode):
         self.sub_chunk_no = 0
         self.mds = None  # inner scalar MDS codec over k+nu data chunks
         self.pair: _PairwiseTransform | None = None
+        # pattern -> (matrix over (chunk, plane) symbols, what it reads)
+        self._probes: FIFOCache = FIFOCache(512)
         if profile is not None:
             self.init(profile)
 
@@ -195,6 +268,7 @@ class ErasureCodeClay(ErasureCode):
         self.mds = registry.factory(inner_plugin, mds_profile)
         pft_code = registry.factory(inner_plugin, pft_profile)
         self.pair = _PairwiseTransform(pft_code.generator[2:])
+        self._probes.clear()
 
     # -- geometry --------------------------------------------------------
     def get_chunk_count(self) -> int:
@@ -307,10 +381,120 @@ class ErasureCodeClay(ErasureCode):
             return self._encode_batch(data[None])[0]
         return self._encode_batch(data)
 
+    # -- device programs (the batched ECBackend entry points) -------------
     def encode_chunks_batch(self, data) -> np.ndarray:
-        return self._encode_batch(np.asarray(data, np.uint8))
+        """(B, k, C) -> (B, k+m, C) through the device program; host
+        arrays in and out."""
+        return np.asarray(self.encode_chunks_device(data))
 
-    encode_chunks_device = encode_chunks_batch
+    def decode_chunks_batch(
+        self, available: Mapping[int, np.ndarray], want_to_read: Sequence[int]
+    ) -> dict[int, np.ndarray]:
+        """Batched reconstruct through the device program: available
+        chunks are (B, C) arrays (the shape ECBackend's stripe-batched
+        reconstruct path supplies); host arrays out."""
+        want = [int(w) for w in want_to_read]
+        avail = {int(i): np.asarray(c, np.uint8) for i, c in available.items()}
+        missing = [w for w in want if w not in avail]
+        out = {w: avail[w] for w in want if w in avail}
+        if missing:
+            rebuilt = np.asarray(self.decode_chunks_device(avail, missing))
+            for slot, w in enumerate(missing):
+                out[w] = rebuilt[:, slot]
+        return out
+
+    def _probed(self, key: tuple, probe):
+        hit = self._probes.get(key)
+        if hit is None:
+            hit = probe()
+            self._probes.put(key, hit)
+        return hit
+
+    def _probe(self, avail: tuple | None, want: tuple):
+        """The matrix from the ``avail`` chunks' sub-chunks to the
+        ``want`` chunks' (``avail`` None: the encode, data to parity),
+        and the chunk ids it reads.  Runs the host schedule once on an
+        identity payload: byte column j*S + y of every chunk is 1 only at
+        chunk j's plane y, so output plane z of chunk w holds row (w, z)
+        of the matrix.  Chunks whose columns are all zero (a decode reads
+        only some of what it is offered) drop out."""
+        S = self.sub_chunk_no
+        ids = tuple(range(self.k)) if avail is None else avail
+        n_sym = len(ids) * S
+        eye = np.eye(n_sym, dtype=np.uint8).reshape(len(ids), 1, -1)
+        if avail is None:
+            out = self._encode_batch(eye.reshape(1, len(ids), -1))[0, self.k:]
+            used = list(range(len(ids)))
+        else:
+            got = self._decode_host(
+                {c: eye[j] for j, c in enumerate(ids)}, list(want))
+            out = np.stack([got[w][0] for w in want])
+        M = out.reshape(len(out) * S, n_sym)
+        if avail is not None:
+            used = [j for j in range(len(ids))
+                    if M[:, j * S:(j + 1) * S].any()]
+            M = M[:, [j * S + y for j in used for y in range(S)]]
+        return M, tuple(ids[j] for j in used)
+
+    def _check_chunk_size(self, C: int) -> None:
+        if C % self.sub_chunk_no:
+            raise ValueError(
+                f"chunk size {C} not a multiple of sub_chunk_no="
+                f"{self.sub_chunk_no}"
+            )
+
+    def encode_chunks_device(self, data):
+        """(B, k, C) uint8 -> (B, k+m, C) on the device: one jitted
+        program, bit-identical to the host schedule."""
+        data = jnp.asarray(data, jnp.uint8)
+        squeeze = data.ndim == 2
+        if squeeze:
+            data = data[None]
+        if data.shape[1] != self.k:
+            raise ValueError(f"expected k={self.k} data chunks, got "
+                             f"{data.shape[1]}")
+        self._check_chunk_size(data.shape[2])
+        parity = tuple(range(self.k, self.k + self.m))
+        M, _ = self._probed(("encode",), lambda: self._probe(None, parity))
+        spec, operands = default_engine().operator(M)
+        out = _encode_program(operands, data, spec=spec,
+                              sub=self.sub_chunk_no)
+        return out[0] if squeeze else out
+
+    def decode_chunks_device(self, available, want_to_read):
+        """Batched device reconstruct: ``available`` maps chunk id ->
+        (B, C) arrays; returns (B, len(want), C) on the device, from one
+        jitted program per erasure pattern (the layered decode as one
+        probed matrix), bit-identical to the host schedule."""
+        want = tuple(int(w) for w in want_to_read)
+        avail = tuple(sorted(int(i) for i in available))
+        M, reads = self._probed(("decode", avail, want),
+                                lambda: self._probe(avail, want))
+        chunks = tuple(jnp.asarray(available[i], jnp.uint8) for i in reads)
+        self._check_chunk_size(chunks[0].shape[1])
+        spec, operands = default_engine().operator(M)
+        return _decode_program(operands, chunks, spec=spec,
+                               sub=self.sub_chunk_no)
+
+    def repair_chunks_device(self, lost: int, helpers: tuple, planes):
+        """Rebuild chunk ``lost`` of B stripes from its d ``helpers``'
+        repair planes (repair_operator.clay_repair_operator's matrix):
+        ``planes`` is (d, B, P*sc) uint8, helper h's P repair sub-chunks
+        of each stripe in plane order.  Returns the (B, C) chunk on the
+        device."""
+        from ceph_tpu.ec.repair_operator import clay_repair_operator
+
+        def probe():
+            R, got, order = clay_repair_operator(self, lost, helpers)
+            if tuple(got) != tuple(helpers):
+                raise IOError(f"clay repair of {lost} reads {got}, not "
+                              f"{helpers}")
+            return R, len(order)
+
+        R, sub = self._probed(("repair", int(lost), tuple(helpers)), probe)
+        spec, operands = default_engine().operator(R)
+        return _repair_program(operands, jnp.asarray(planes, jnp.uint8),
+                               spec=spec, sub=sub)
 
     def _encode_batch(self, data: np.ndarray) -> np.ndarray:
         B, k, C = data.shape
@@ -353,14 +537,14 @@ class ErasureCodeClay(ErasureCode):
             int(i): np.asarray(c, np.uint8)[None]
             for i, c in available.items()
         }
-        out = self.decode_chunks_batch(batched, want_to_read)
+        out = self._decode_host(batched, want_to_read)
         return {w: chunk[0] for w, chunk in out.items()}
 
-    def decode_chunks_batch(
+    def _decode_host(
         self, available: Mapping[int, np.ndarray], want_to_read: Sequence[int]
     ) -> dict[int, np.ndarray]:
-        """Batched full decode: available chunks are (B, C) arrays (the
-        shape ECBackend's stripe-batched reconstruct path supplies)."""
+        """The layered decode on the host: available chunks are (B, C)
+        arrays."""
         avail = {int(i): np.asarray(c, np.uint8) for i, c in available.items()}
         want = [int(w) for w in want_to_read]
         if all(w in avail for w in want):

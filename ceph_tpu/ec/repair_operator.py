@@ -21,20 +21,23 @@ from __future__ import annotations
 import numpy as np
 
 
-def clay_repair_operator(ec, lost: int) -> tuple[np.ndarray, list[int], list[int]]:
+def clay_repair_operator(ec, lost: int, available=None
+                         ) -> tuple[np.ndarray, list[int], list[int]]:
     """Probe a clay codec's single-chunk repair into a matrix.
 
     Returns ``(R, helpers, planes)``:
-    - helpers: the d helper chunk ids, ascending (the order the device
-      layout concatenates them in);
+    - helpers: the d helper chunk ids ``minimum_to_decode`` picks from
+      ``available`` (default: every other chunk), ascending (the order
+      the device layout concatenates them in);
     - planes: the repair sub-chunk (plane) indices read from each helper;
     - R: (sub_chunk_no, d*len(planes)) GF(2^8) matrix with
       ``recovered_plane[z] = XOR_sym gf_mul(R[z, sym], helper_flat[sym])``
       where helper_flat stacks each helper's repair planes in order.
     """
     n = ec.get_chunk_count()
-    available = [i for i in range(n) if i != lost]
-    minimum = ec.minimum_to_decode([lost], available)
+    if available is None:
+        available = [i for i in range(n) if i != lost]
+    minimum = ec.minimum_to_decode([lost], sorted(int(a) for a in available))
     helpers = sorted(minimum)
     lost_node = ec._node_of(lost)
     planes = ec._repair_planes(lost_node)

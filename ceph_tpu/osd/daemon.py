@@ -54,6 +54,7 @@ from ceph_tpu.osd.ec_backend import (
     ECWriteDegraded,
     LocalShard,
     ShardReadError,
+    read_ranges,
 )
 from ceph_tpu.osd.codes import (
     EAGAIN_RC,
@@ -159,7 +160,8 @@ class DeadShard:
     async def _fail(self, *a, **kw):
         raise ShardReadError(f"shard {self.shard} has no osd")
 
-    write_shard = read_shard = get_attr = remove_shard = stat_shard = _fail
+    write_shard = read_shard = read_shard_ranges = get_attr = _fail
+    remove_shard = stat_shard = _fail
 
 
 class NetworkShard:
@@ -183,6 +185,13 @@ class NetworkShard:
 
     async def read_shard(self, oid, offset=0, length=None):
         return await self._sub("read", oid=oid, off=offset, len=length)
+
+    async def read_shard_ranges(self, oid, ranges, version=None):
+        """ONE sub-op for every (offset, length) range, the version
+        check folded in (Ceph's ECSubRead with its ``subchunks``)."""
+        return await self._sub("read_ranges", oid=oid,
+                               ranges=[[int(o), int(n)] for o, n in ranges],
+                               version=version)
 
     async def get_attr(self, oid, name):
         return await self._sub("getattr", oid=oid, name=name)
@@ -5287,6 +5296,9 @@ class OSDDaemon:
                 elif kind == "read":
                     value = self.store.read(cid, oid, int(d["off"]),
                                             d.get("len"))
+                elif kind == "read_ranges":
+                    value = read_ranges(self.store, cid, oid,
+                                        d["ranges"], d.get("version"))
                 elif kind == "getattr":
                     value = self.store.getattr(cid, oid, str(d["name"]))
                 elif kind == "getattrs":

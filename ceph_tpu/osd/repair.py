@@ -177,7 +177,8 @@ def _probe_plan(ec, lost_t: tuple, avail_t: tuple) -> RepairPlan:
             if is_clay:
                 from ceph_tpu.ec.repair_operator import \
                     clay_repair_operator
-                R, helpers, planes = clay_repair_operator(ec, lost_t[0])
+                R, helpers, planes = clay_repair_operator(
+                    ec, lost_t[0], avail_t)
                 if all(h in avail_set for h in helpers):
                     return RepairPlan("clay", tuple(helpers),
                                       tuple(planes), R,

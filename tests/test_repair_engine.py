@@ -294,6 +294,44 @@ def test_recover_batch_clay_reads_only_helper_subchunks():
     assert be.perf.value("ec_repair_read_bytes") == total
 
 
+class RangedCountingShard(CountingShard):
+    """CountingShard that also counts ranged sub-ops, per object."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.ranged: dict[str, int] = {}
+
+    async def read_shard_ranges(self, oid, ranges, version=None):
+        self.ranged[oid] = self.ranged.get(oid, 0) + 1
+        return await super().read_shard_ranges(oid, ranges, version)
+
+
+def test_recover_batch_clay_one_ranged_subop_per_helper():
+    """Each helper serves ONE ranged sub-op per object, whatever the
+    object's stripe count: every stripe's repair planes ride it."""
+    clear_plan_cache()
+    codec = ErasureCodePluginRegistry().factory(
+        "clay", {"k": "8", "m": "4", "d": "11"})
+    shards, stores = {}, {}
+    for i in range(codec.get_chunk_count()):
+        store = MemStore()
+        cid = CollectionId(1, 0, shard=i)
+        _run(store.queue_transactions(Transaction().create_collection(cid)))
+        stores[i] = (store, cid)
+        shards[i] = RangedCountingShard(store, cid, pool=1, shard=i)
+    be = ECBackend(codec, shards, stripe_unit=1024)
+    be._test_stores = stores
+    lost = [4]                     # node 4: four runs of planes per stripe
+    originals, true_shards = _seed_degraded(be, lost, nobj=3,
+                                            size=5 * 8 * 1024)
+    res = _run(be.recover_batch(list(originals), lost, {}))
+    assert set(res["recovered"]) == set(originals)
+    _assert_shards_identical(be, originals, true_shards, lost)
+    for s, sh in be.shards.items():
+        want = {} if s in lost else {n: 1 for n in originals}
+        assert sh.ranged == want, s
+
+
 def test_recover_batch_multi_failure_lrc_falls_back_to_rs():
     clear_plan_cache()
     be = make_backend("lrc", {"k": "12", "m": "4", "l": "4"})

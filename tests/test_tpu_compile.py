@@ -136,6 +136,39 @@ def test_grouped_paired_repair_kernel_compiles(one_chip, clay_repair):
     assert "tpu_custom_call" in _compiled_text(run, bms, gath)
 
 
+@pytest.mark.parametrize("program", ["encode", "decode", "repair"])
+def test_clay_device_programs_compile(one_chip, program):
+    """The CLAY k=8 m=4 d=11 device programs at the CLAY cell's shape (a
+    4 MiB object: 128 stripes of 4 KiB chunks, 64 B sub-chunks), each
+    with the grouped kernel's operands as arguments: the encode, the
+    layered decode of chunk 0 from chunks 1..8, and the sub-chunk repair
+    of chunk 0 from its 11 helpers' 16 repair planes."""
+    from ceph_tpu.ec.engine import BitplaneEngine
+    from ceph_tpu.ec.plugins import clay
+    from ceph_tpu.ec.registry import ErasureCodePluginRegistry
+    from ceph_tpu.ec.repair_operator import clay_repair_operator
+
+    ec = ErasureCodePluginRegistry().factory(
+        "clay", {"k": "8", "m": "4", "d": "11"})
+    if program == "repair":
+        coeff = clay_repair_operator(ec, 0)[0]
+        fn, sub = clay._repair_program, 16
+        args = (_shape((11, 128, 1024), jnp.uint8, one_chip),)
+    else:
+        coeff, _ = (ec._probe(None, (8, 9, 10, 11)) if program == "encode"
+                    else ec._probe(tuple(range(1, 9)), (0,)))
+        fn, sub = (clay._encode_program if program == "encode"
+                   else clay._decode_program), 64
+        args = ((_shape((128, 8, 4096), jnp.uint8, one_chip),)
+                if program == "encode" else
+                (tuple(_shape((128, 4096), jnp.uint8, one_chip)
+                       for _ in range(8)),))
+    spec, operands = BitplaneEngine(use_pallas=True).operator(coeff)
+    assert spec[0] == "grouped"
+    operands = tuple(_shape(a.shape, a.dtype, one_chip) for a in operands)
+    text = fn.lower(operands, *args, spec=spec,
+                    sub=sub).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_sharded_applier_compiles_on_four_chips(monkeypatch, topo):
